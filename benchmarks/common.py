@@ -10,6 +10,9 @@ import numpy as np
 jax.config.update("jax_enable_x64", True)  # exact large butterfly counts
 
 from repro.data.graphs import powerlaw_bipartite, random_bipartite  # noqa: E402
+from repro.launch.cache import enable_compilation_cache  # noqa: E402
+
+enable_compilation_cache()
 
 # KONECT-calibrated synthetic stand-ins (paper Table 1 datasets are not
 # shipped offline; sizes scaled to CPU-container budgets, heavy tails

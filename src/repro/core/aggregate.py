@@ -90,20 +90,25 @@ class Groups(NamedTuple):
 
 def aggregate_sort(w: Wedges):
     """Sort-based aggregation: one lexicographic ``lax.sort`` on
-    (x1, x2) threading the wedge payload (centers, edge slots) through
-    the sort, so no inverse permutation or unsort scatter is needed.
+    (x1, x2) carrying the lane permutation, which gathers the wedge
+    payload (centers, edge slots) into sorted order, so no inverse
+    permutation or unsort scatter is needed.
     Returns (Groups, sorted Wedges); ``d_per_wedge`` aligns with the
     *sorted* wedges (§Perf-3 iteration 2 — scatter targets are
     order-independent, so callers accumulate from the sorted view).
     """
     w_cap = w.x1.shape[0]
     # Invalid wedges carry x1 == x2 == n_pad sentinel -> sort to the end.
-    sx1, sx2, sy, scs, sss, sval = jax.lax.sort(
-        (w.x1, w.x2, w.y, w.center_slot, w.second_slot,
-         w.valid.astype(jnp.int32)),
-        num_keys=2,
+    # Only the keys and a lane permutation go through the sort: the TPU
+    # compiler's sort compile time grows with the operand count (six
+    # operands took minutes at 64K lanes), and the payload follows by
+    # gather.
+    sx1, sx2, perm = jax.lax.sort(
+        (w.x1, w.x2, jnp.arange(w_cap, dtype=jnp.int32)), num_keys=2
     )
-    sval = sval > 0
+    sy, scs, sss, sval = (
+        w.y[perm], w.center_slot[perm], w.second_slot[perm], w.valid[perm]
+    )
     prev_same = jnp.concatenate(
         [
             jnp.zeros((1,), jnp.bool_),

@@ -53,9 +53,15 @@ aggregated, combined, accumulated, and DISCARDED inside one program:
     ``pipeline.run_count_tiles`` fori_loop (tile-local sort/hash/
     histogram aggregation, same in-graph hash-overflow sort fallback).
     CPU/GPU get the O(tile) memory win with no interpret-mode overhead.
-  - ``"fused_pallas"`` — the ``kernels.wedge_fused`` Pallas kernel:
-    per grid tile, in-VMEM reconstruction + all-pairs match
-    aggregation + in-register combine + one-hot partial scatters.
+  - ``"fused_pallas"`` — ``pipeline.run_fused_pallas_program``: per
+    batch of kernel tiles, XLA recovers the wedges, the
+    ``kernels.wedge_fused`` Pallas kernel aggregates them (all-pairs
+    match on the MXU + in-register combine), and XLA scatters the
+    per-lane contributions. Vertices owning more wedges than the
+    kernel tile holds (``MAX_TILE_CAP``) get per-vertex XLA tiles
+    inside the same program, aggregated over a dense table keyed by the
+    wedge's far endpoint (no sort); the plan summary on the report
+    counts both kinds (``kernel:N,vertex:M``).
 
 Both are bitwise-identical to ``engine="xla"`` wherever counts fit the
 dtype; peak temp memory is O(tile) instead of O(W) (asserted by the
@@ -95,7 +101,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels import ops as _kops
 from ..testing import faults as _faults
 from . import pipeline as _pipeline
 from . import resilience as _res
@@ -387,10 +392,6 @@ def _plan_from_knobs(
     if engine in ("fused", "fused_pallas"):
         if budget is None:
             budget = auto_chunk_budget()
-        if engine == "fused_pallas":
-            # the kernel's in-VMEM aggregation is exact only up to its
-            # MAX_TILE_CAP tile — clamp the auto/default budget to it
-            budget = min(budget, _kops.MAX_TILE_CAP)
     else:
         if budget is None or int(wv_slots.sum()) <= budget:
             return None
@@ -471,7 +472,8 @@ def count_from_ranked(
             wv, rg.n_pad, aggregation == "batch_wa", batch_rows, batch_target
         )
         chunk_cap = max(128, ((chunk + 127) // 128) * 128)
-        out = _count_batch_device(
+        out = _pipeline.launch(
+            _count_batch_device,
             dg,
             jnp.asarray(bounds, jnp.int32),
             chunk_cap=chunk_cap,
@@ -493,10 +495,11 @@ def count_from_ranked(
         wv_slots=wv_slots,
     )
     if plan is not None:
-        return _pipeline.execute_count_plan(dg, plan, rg.offsets, wv_slots)
+        return _pipeline.execute_count_plan(dg, plan)
     w_total = int(wv_slots.sum())
     w_cap = max(128, ((w_total + 127) // 128) * 128)
-    out, _ok = _count_device(
+    out, _ok = _pipeline.launch(
+        _count_device,
         dg,
         w_cap=w_cap,
         aggregation=aggregation,

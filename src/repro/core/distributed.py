@@ -46,7 +46,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..testing import faults as _faults
 from . import checkpoint as _ckpt
@@ -117,13 +116,16 @@ def launch_device_worker(
     deadline_s: Optional[float] = None,
 ) -> str:
     """Run a Python worker payload against a forced ``devices``-wide
-    host platform, with bounded retry + exponential backoff and a
+    CPU host platform, with bounded retry + exponential backoff and a
     per-attempt timeout — the per-device dispatch path of the
     resilience layer.
 
-    The child gets ``XLA_FLAGS=--xla_force_host_platform_device_count``
-    and the repro ``src`` dir on ``PYTHONPATH``; extra ``env`` entries
-    overlay that. Each attempt asks the fault harness
+    This is a CPU simulation harness for tests, never a chip path: the
+    child is pinned to ``JAX_PLATFORMS=cpu`` (so it can never claim an
+    accelerator its parent holds) and gets
+    ``--xla_force_host_platform_device_count`` appended to any
+    ``XLA_FLAGS`` already set, plus the repro ``src`` dir on
+    ``PYTHONPATH``; extra ``env`` entries overlay that. Each attempt asks the fault harness
     (:func:`repro.testing.faults.worker_env`) whether an armed
     ``device_loss`` fault should kill or hang this launch — a
     ``times=1`` fault consumes itself on the first attempt, so the
@@ -144,9 +146,11 @@ def launch_device_worker(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     )
     base_env = dict(os.environ)
-    base_env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={int(devices)}"
-    )
+    base_env["JAX_PLATFORMS"] = "cpu"
+    base_env["XLA_FLAGS"] = " ".join(filter(None, [
+        os.environ.get("XLA_FLAGS", ""),
+        f"--xla_force_host_platform_device_count={int(devices)}",
+    ]))
     base_env["PYTHONPATH"] = src_root
     if env:
         base_env.update(env)
@@ -370,12 +374,12 @@ def distributed_count_fn(
         in_specs = (repl, sharded)
 
     out_specs = sharded if combine == "scatter" and mode != "global" else repl
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 

@@ -176,20 +176,28 @@ class BipartiteGraph:
     def accumulator_preflight(self, budget_bits: int = 63) -> int:
         """Worst-case butterfly bound vs. the accumulator budget.
 
-        Σ C(d, 2) over endpoint-pair groups with Σ d = W is maximized
-        (convexity) by one group holding all W wedges, so
-        ``C(min(w_u, w_v), 2)`` bounds the exact total. Computed in
-        arbitrary-precision host ints; raises
-        :class:`AccumulatorOverflowRisk` when the bound needs more
-        than ``budget_bits`` bits (default: the engines' two-limb
-        int32 accumulators, exact below 2^63). Returns the bound."""
-        w_u, w_v = self.wedge_totals()
-        w = min(w_u, w_v)
-        bound = w * (w - 1) // 2
+        Counting over the endpoint pairs of one side, the total is
+        ``Σ C(c, 2)`` over pairs with ``c`` common neighbours, where
+        ``Σ c = W`` (that side's wedges) and every ``c`` is at most the
+        side's second-largest degree ``d2`` (a pair's common neighbours
+        are bounded by its smaller degree). So the total is at most
+        ``W (min(W, d2) - 1) / 2`` for either side, and the smaller of
+        the two sides' bounds holds. Computed in arbitrary-precision
+        host ints; raises :class:`AccumulatorOverflowRisk` when the
+        bound needs more than ``budget_bits`` bits (default: the
+        engines' two-limb int32 accumulators, exact below 2^63).
+        Returns the bound."""
+        du, dv = self.degrees()
+        bounds = []
+        for w, deg in zip(self.wedge_totals(), (du, dv)):
+            d2 = int(np.sort(deg)[-2]) if deg.size >= 2 else 0
+            c = min(w, d2)
+            bounds.append(w * max(c - 1, 0) // 2)
+        bound = min(bounds)
         if bound >= (1 << int(budget_bits)):
             raise AccumulatorOverflowRisk(
-                f"worst-case butterfly bound C({w}, 2) = {bound} exceeds "
-                f"the {budget_bits}-bit accumulator budget; exact counts "
+                f"worst-case butterfly bound {bound} exceeds the "
+                f"{budget_bits}-bit accumulator budget; exact counts "
                 "cannot be guaranteed on any engine rung"
             )
         return bound

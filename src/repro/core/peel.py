@@ -183,6 +183,7 @@ from .pipeline import (
     drive_segments as _drive_segments,
     empty_hist as _empty_hist,
     init_loop_state as _init_state,
+    launch as _launch,
     masked_state as _masked_state,
     prefix_offsets as _prefix,
     stream_tiles as _stream_tiles,
@@ -667,10 +668,9 @@ def _peel_tips_device_run(
     # the 2x headroom keeps greedy tiles at least half full
     tile_cap = _pow2_pad(max(min(tb, max(lvl2, 1)), 2 * max_row))
     b0 = jnp.asarray(counts)
-    use_kernel = (
-        not _kops.interpret_default()
-        and b0.dtype == jnp.int32
-    )
+    # counts below INT32_MAX (guarded above) run the int32 kernel in any
+    # count dtype; off the compiled backend the reference serves
+    use_kernel = not _kops.interpret_default()
     state = _init_state(
         b0, n_side, decrease_key=decrease_key, peel_mode=peel_mode,
         lvl1=lvl1, lvl2=lvl2,
@@ -679,7 +679,8 @@ def _peel_tips_device_run(
     caps = {"cap1": cap1, "cap2": cap2}
 
     def run(st):
-        return _peel_tips_device(
+        return _launch(
+            _peel_tips_device,
             off_d,
             nbr_d,
             jnp.int32(base),
@@ -1710,10 +1711,9 @@ def _peel_wings_device_run(
         cap1 = cap2 = 128  # unused: the fused path has no buffers
     tile_cap = _pow2_pad(min(tb, max(lvl2, 1)))
     b0 = jnp.asarray(counts)
-    use_kernel = (
-        not _kops.interpret_default()
-        and b0.dtype == jnp.int32
-    )
+    # counts below INT32_MAX (guarded above) run the int32 kernel in any
+    # count dtype; off the compiled backend the reference serves
+    use_kernel = not _kops.interpret_default()
     state = _init_state(
         b0, m, decrease_key=decrease_key, peel_mode=peel_mode,
         lvl1=lvl1, lvl2=lvl2,
@@ -1735,7 +1735,8 @@ def _peel_wings_device_run(
     caps = {"cap1": cap1, "cap2": cap2}
 
     def run(st):
-        return _peel_wings_device(
+        return _launch(
+            _peel_wings_device,
             *args,
             st,
             aggregation=aggregation,

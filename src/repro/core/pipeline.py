@@ -63,6 +63,7 @@ forced-hash runs.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import json
@@ -110,9 +111,14 @@ __all__ = [
     "zero_counts",
     "count_tile_step",
     "run_count_tiles",
+    "lane_counts",
+    "vertex_tile_lanes",
+    "run_fused_pallas_program",
     "run_fused_pallas_tiles",
     "plan_strategies",
     "execute_count_plan",
+    "record_programs",
+    "launch",
     # execute: peeling substrate
     "I32_MAX",
     "LoopState",
@@ -306,6 +312,14 @@ class WedgePlan:
         return " ".join(parts)
 
 
+def _round_up(x: int, to: int) -> int:
+    return ((max(int(x), 1) + to - 1) // to) * to
+
+
+def _pow2_at_least(x: int, floor: int = 128) -> int:
+    return max(int(floor), 1 << (max(int(x), 1) - 1).bit_length())
+
+
 def _tile_pair_floor(rg: RankedGraph, wv_slots: np.ndarray) -> np.ndarray:
     """Per-vertex lower bound on distinct (x1, x2) endpoint pairs: the
     wedges of one directed slot (x1 -> y) all have distinct x2, so
@@ -318,6 +332,35 @@ def _tile_pair_floor(rg: RankedGraph, wv_slots: np.ndarray) -> np.ndarray:
             mx, rg.edge_src[:n_real].astype(np.int64), wv_slots[:n_real]
         )
     return mx
+
+
+def _kernel_split_bounds(wv: np.ndarray, kernel_cap: int, target: int):
+    """Vertex-aligned tiles for ``engine="fused_pallas"``: runs of
+    vertices owning at most ``kernel_cap`` wedges pack greedily into
+    kernel tiles of at most ``min(kernel_cap, target)`` wedges (a single
+    vertex above the target gets a solo tile); every heavier vertex
+    gets a solo ``"vertex"`` tile. Returns ``(bounds, is_kernel)`` —
+    vertex boundaries and one flag per tile."""
+    n = wv.shape[0]
+    heavy = wv > kernel_cap
+    woff = np.concatenate([[0], np.cumsum(wv)])
+    run_end = np.concatenate(
+        [np.flatnonzero(heavy[1:] != heavy[:-1]) + 1, [n]]
+    )
+    target = min(int(kernel_cap), int(target))
+    bounds, is_kernel = [0], []
+    b, r = 0, 0
+    while b < n:
+        while run_end[r] <= b:
+            r += 1
+        nxt = b + 1
+        if not heavy[b]:
+            nxt = int(np.searchsorted(woff, woff[b] + target, "right")) - 1
+            nxt = min(max(nxt, b + 1), int(run_end[r]))
+        bounds.append(nxt)
+        is_kernel.append(not heavy[b])
+        b = nxt
+    return np.asarray(bounds, np.int64), np.asarray(is_kernel, bool)
 
 
 def plan_count(
@@ -339,8 +382,14 @@ def plan_count(
 
     ``aggregation="auto"`` resolves sort-vs-hash per tile from the
     density estimate (see module docstring); any other value is applied
-    uniformly. Planning is deterministic pure-numpy on (graph, knobs) —
-    the property the plan tests pin down.
+    uniformly. ``engine="fused_pallas"`` splits the tiling: vertices
+    owning at most ``MAX_TILE_CAP`` wedges (the ``wedge_fused`` kernel's
+    exactness bound) go to ``"kernel"`` tiles of at most
+    ``min(MAX_TILE_CAP, budget)`` wedges; each heavier vertex gets a
+    solo ``"vertex"`` tile, aggregated in XLA over a dense table keyed
+    by its other endpoint (see :func:`run_fused_pallas_program`).
+    Planning is deterministic pure-numpy on (graph, knobs) — the
+    property the plan tests pin down.
     """
     if mode not in MODES:
         raise ValueError(f"mode must be {'|'.join(MODES)}, got {mode}")
@@ -351,18 +400,37 @@ def plan_count(
         )
     if wv_slots is None:
         wv_slots = host_wedge_counts(rg, direction)
-    bounds, chunk_cap = plan_wedge_chunks(
-        rg, direction, int(budget), wv_slots=wv_slots
-    )
     n_real = 2 * rg.m
     wv = np.zeros(rg.n_pad, dtype=np.int64)
     if n_real:
         np.add.at(
             wv, rg.edge_src[:n_real].astype(np.int64), wv_slots[:n_real]
         )
+    split = engine == "fused_pallas"
+    if split:
+        kernel_cap = _faults.capacity_override(
+            "fused_pallas.plan", _kops.MAX_TILE_CAP
+        )
+        bounds, is_kernel = _kernel_split_bounds(
+            wv, int(kernel_cap), int(budget)
+        )
+    else:
+        bounds, chunk_cap = plan_wedge_chunks(
+            rg, direction, int(budget), wv_slots=wv_slots
+        )
     voff = np.concatenate([[0], np.cumsum(wv)])
     tile_wedges = (voff[bounds[1:]] - voff[bounds[:-1]]).astype(np.int64)
-    if aggregation == "auto":
+    capacity = ()
+    if split:
+        tile_aggregation = tuple(
+            "kernel" if k else "vertex" for k in is_kernel
+        )
+        chunk_cap = _pow2_at_least(
+            int(tile_wedges[~is_kernel].max(initial=1))
+        )
+        k_max = int(tile_wedges[is_kernel].max(initial=1))
+        capacity = (("kernel_tile", _round_up(k_max, _kops.TC)),)
+    elif aggregation == "auto":
         mx = _tile_pair_floor(rg, wv_slots)
         moff = np.concatenate([[0], np.cumsum(mx)])
         pair_floor = np.maximum(moff[bounds[1:]] - moff[bounds[:-1]], 1)
@@ -383,7 +451,7 @@ def plan_count(
         tile_wedges=tuple(int(w) for w in tile_wedges),
         chunk_cap=int(chunk_cap),
         w_start=0,
-        capacity=(("chunk_cap", int(chunk_cap)),),
+        capacity=capacity + (("chunk_cap", int(chunk_cap)),),
         budget=int(budget),
         hash_bits=hash_bits,
         accumulator=AccumulatorSpec(
@@ -859,67 +927,157 @@ def run_count_tiles(
     return jax.lax.fori_loop(0, n_blocks, body, (acc0, jnp.array(True)))
 
 
-def run_fused_pallas_tiles(
+def lane_counts(dg: DeviceGraph, w: Wedges, dm1: jax.Array, c2: jax.Array,
+                mode: str, acc):
+    """Add per-wedge-lane contributions to the accumulators ``acc``
+    (Lemma 4.2): ``c2`` (C(d, 2) on each group's representative lane,
+    0 elsewhere) to the endpoints x1, x2 and the total, ``dm1`` (d - 1)
+    to the center y and the wedge's two edges. Invalid lanes carry the
+    out-of-range sentinels of ``wedges_at`` and zero contributions."""
+    if mode == "global":
+        return acc + jnp.sum(c2).astype(acc.dtype)
+
+    def vertex(bv):
+        return bv.at[w.x1].add(c2).at[w.x2].add(c2).at[w.y].add(dm1)
+
+    def edge(be):
+        return (be.at[dg.undirected_id[w.center_slot]].add(dm1)
+                .at[dg.undirected_id[w.second_slot]].add(dm1))
+
+    if mode == "vertex":
+        return vertex(acc)
+    if mode == "edge":
+        return edge(acc)
+    tot, bv, be = acc
+    return tot + jnp.sum(c2).astype(tot.dtype), vertex(bv), edge(be)
+
+
+KERNEL_BATCH = 16  # wedge_fused tiles per kernel launch
+
+
+def vertex_tile_lanes(w: Wedges, n_pad: int, direction: str, dtype):
+    """Per-lane ``(dm1, c2)`` of a tile holding ONE iterating vertex's
+    wedges: its endpoint-pair groups are keyed by the other endpoint
+    alone, so a dense ``n_pad`` table counts them (scatter-add) and a
+    scatter-min of lane ids marks each group's first lane — exact, and
+    no sort (whose TPU compile time grows steeply with size)."""
+    other = w.x2 if direction == "low" else w.x1  # sentinel n_pad: dropped
+    lane = jnp.arange(other.shape[0], dtype=jnp.int32)
+    cnt = jnp.zeros((n_pad,), jnp.int32).at[other].add(1)
+    first = jnp.full((n_pad,), other.shape[0], jnp.int32).at[other].min(lane)
+    safe = jnp.minimum(other, n_pad - 1)
+    d = jnp.where(w.valid, cnt[safe], 0)
+    rep = w.valid & (first[safe] == lane)
+    dm1 = jnp.where(w.valid, d - 1, 0).astype(dtype)
+    return dm1, jnp.where(rep, choose2(d, dtype), 0)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("tile_cap", "vertex_caps", "mode", "direction",
+                     "dtype"),
+)
+def run_fused_pallas_program(
     dg: DeviceGraph,
-    plan: WedgePlan,
-    rg_offsets: np.ndarray,
-    wv_slots: np.ndarray,
+    kernel_tiles: jax.Array,  # (n_batches, KERNEL_BATCH, 2) flat ranges
+    vertex_tiles: tuple,  # per capacity class: (n_c, 2) flat ranges
+    *,
+    tile_cap: int,
+    vertex_caps: tuple,  # lane buffer of each vertex-tile class
+    mode: str,
+    direction: str,
+    dtype,
 ):
-    """Dispatch the wedge_fused Pallas kernel over a plan's tiles:
-    host-planned vertex-aligned tile bounds in flat wedge-id space, one
-    kernel launch through ``kernels/ops.fused_count_tiles``. Every
-    kernel output — the global total and the per-vertex/per-edge
-    arrays — accumulates as two int32 limbs with carry, exact for
-    counts < 2^63; the limbs recombine into the plan dtype here (a
-    32-bit ``count_dtype`` keeps the low word, like every engine)."""
-    dtype = plan.accumulator.jnp_dtype()
-    mode = plan.accumulator.mode
-    tile_cap = max(
-        _kops.TC,
-        ((plan.chunk_cap + _kops.TC - 1) // _kops.TC) * _kops.TC,
-    )
+    """The ``engine="fused_pallas"`` program: a fori_loop over batches
+    of kernel tiles — each tile's wedges recovered in XLA
+    (``wedges_at``), aggregated by the ``wedge_fused`` kernel, and its
+    lanes scattered into the accumulators — then one fori_loop per
+    capacity class of vertex tiles (vertices owning more wedges than
+    the kernel tile holds, :func:`vertex_tile_lanes`). Live memory is
+    O(KERNEL_BATCH x tile_cap + max(vertex_caps) + n_pad); nothing of
+    size W is ever built."""
+    cnt = slot_wedge_counts(dg, direction)
+    w_off = wedge_offsets(cnt)
+    acc = zero_counts(dg, mode, dtype)
+
+    def recover(tb, cap):
+        lanes = tb[:, :1] + jnp.arange(cap, dtype=jnp.int32)[None, :]
+        valid = lanes < tb[:, 1:2]
+        return wedges_at(
+            dg, cnt, w_off, lanes.reshape(-1), valid.reshape(-1), direction
+        )
+
+    def kernel_batch(i, acc):
+        w = recover(kernel_tiles[i], tile_cap)
+        shape = (kernel_tiles.shape[1], tile_cap)
+        dm1, c2 = _kops.match_tiles(
+            jnp.where(w.valid, w.x1, -1).reshape(shape),
+            jnp.where(w.valid, w.x2, -2).reshape(shape),
+            use_pallas=True,
+        )
+        return lane_counts(
+            dg, w, dm1.reshape(-1).astype(dtype),
+            c2.reshape(-1).astype(dtype), mode, acc,
+        )
+
+    if kernel_tiles.shape[0]:
+        acc = jax.lax.fori_loop(0, kernel_tiles.shape[0], kernel_batch, acc)
+    for tiles, cap in zip(vertex_tiles, vertex_caps):
+
+        def vertex_tile(i, acc, tiles=tiles, cap=cap):
+            w = recover(tiles[i][None, :], cap)
+            dm1, c2 = vertex_tile_lanes(w, dg.n_pad, direction, dtype)
+            return lane_counts(dg, w, dm1, c2, mode, acc)
+
+        acc = jax.lax.fori_loop(0, tiles.shape[0], vertex_tile, acc)
+    return acc
+
+
+def run_fused_pallas_tiles(dg: DeviceGraph, plan: WedgePlan):
+    """Execute a ``engine="fused_pallas"`` plan. A kernel tile wider
+    than the kernel's exactness bound raises
+    :class:`~repro.core.resilience.CapacityOverflow` (the resilience
+    ladder then descends to ``engine="fused"``)."""
+    tile_cap = dict(plan.capacity).get("kernel_tile", 0)
     max_tile = _faults.capacity_override(
         "count.fused_pallas", _kops.MAX_TILE_CAP
     )
     if tile_cap > max_tile:
-        # typed (still a ValueError subclass): the resilience ladder in
-        # count_butterflies catches this rung and descends to 'fused'
         raise _res.CapacityOverflow(
-            f"engine='fused_pallas' tile_cap {tile_cap} exceeds the "
-            f"kernel's exactness bound {max_tile} (a single "
-            "vertex owns more wedges than the kernel tile can hold); "
-            "use engine='fused'"
+            f"engine='fused_pallas' kernel tile {tile_cap} exceeds the "
+            f"kernel's exactness bound {max_tile}; use engine='fused'"
         )
-    bounds = np.asarray(plan.bounds, np.int64)
-    w_off = np.concatenate([[0], np.cumsum(wv_slots)]).astype(np.int32)
-    off = rg_offsets.astype(np.int64)
-    tb = np.stack(
-        [w_off[off[bounds[:-1]]], w_off[off[bounds[1:]]]], axis=1
-    ).astype(np.int32)
-    tot, vert, edge = _kops.fused_count_tiles(
-        jnp.asarray(tb),
-        dg.offsets,
-        dg.neighbors,
-        dg.edge_src,
-        dg.undirected_id,
-        jnp.asarray(w_off),
-        tile_cap=tile_cap,
-        n_pad=dg.n_pad,
-        m=dg.m,
-        direction=plan.direction,
-        mode=mode,
-        use_pallas=True,
+    kinds = np.asarray(plan.tile_aggregation)
+    other = set(kinds.tolist()) - {"kernel", "vertex"}
+    if other:
+        raise ValueError(
+            f"engine='fused_pallas' runs kernel and vertex tiles only, "
+            f"got {sorted(other)}"
+        )
+    flat = plan.tile_flat_bounds()
+    ktiles = flat[kinds == "kernel"]
+    pad = -len(ktiles) % KERNEL_BATCH
+    ktiles = np.concatenate([ktiles, np.zeros((pad, 2), np.int64)])
+    vtiles = flat[kinds == "vertex"]
+    classes = np.asarray(
+        [_pow2_at_least(int(s)) for s in vtiles[:, 1] - vtiles[:, 0]],
+        np.int64,
     )
-    total = combine_limbs(tot[0], tot[1], dtype)
-    vert = combine_limbs(vert[..., 0], vert[..., 1], dtype)
-    edge = combine_limbs(edge[..., 0], edge[..., 1], dtype)
-    if mode == "global":
-        return total
-    if mode == "vertex":
-        return vert
-    if mode == "edge":
-        return edge
-    return total, vert, edge
+    caps = tuple(int(c) for c in np.unique(classes))
+    out = launch(
+        run_fused_pallas_program,
+        dg,
+        jnp.asarray(ktiles.reshape(-1, KERNEL_BATCH, 2), jnp.int32),
+        tuple(jnp.asarray(vtiles[classes == c], jnp.int32) for c in caps),
+        tile_cap=tile_cap or _kops.TC,
+        vertex_caps=caps,
+        mode=plan.accumulator.mode,
+        direction=plan.direction,
+        dtype=plan.accumulator.jnp_dtype(),
+    )
+    # value-level poison hook: the program's concrete output, outside
+    # any jit, so the sentinel can never leak into a compilation cache
+    return _faults.maybe_poison("count.fused_pallas", out)
 
 
 def plan_strategies(plan: WedgePlan) -> Optional[jax.Array]:
@@ -940,31 +1098,22 @@ def plan_strategies(plan: WedgePlan) -> Optional[jax.Array]:
     )
 
 
-def execute_count_plan(
-    dg: DeviceGraph,
-    plan: WedgePlan,
-    rg_offsets: Optional[np.ndarray] = None,
-    wv_slots: Optional[np.ndarray] = None,
-):
+def execute_count_plan(dg: DeviceGraph, plan: WedgePlan):
     """Execute a counting plan on its device graph and return the
     rank-space counts (a scalar / array / triple per the accumulator
-    mode). ``engine="fused_pallas"`` dispatches the Pallas tile kernel
-    (``rg_offsets``/``wv_slots`` are its host-side planning inputs);
-    everything else streams through :func:`run_count_tiles`."""
+    mode). ``engine="fused_pallas"`` dispatches the Pallas tile program
+    (:func:`run_fused_pallas_tiles`); everything else streams through
+    :func:`run_count_tiles`."""
     if plan.kind != "count":
         raise ValueError(f"not a counting plan: kind={plan.kind!r}")
     if plan.engine == "fused_pallas":
-        if rg_offsets is None or wv_slots is None:
-            raise ValueError(
-                "engine='fused_pallas' execution needs rg_offsets and "
-                "wv_slots (host planning inputs)"
-            )
-        return run_fused_pallas_tiles(dg, plan, rg_offsets, wv_slots)
+        return run_fused_pallas_tiles(dg, plan)
     strategies = plan_strategies(plan)
     uniform = (
         plan.tile_aggregation[0] if plan.tile_aggregation else "sort"
     )
-    out, _ok = run_count_tiles(
+    out, _ok = launch(
+        run_count_tiles,
         dg,
         jnp.asarray(plan.bounds, jnp.int32),
         strategies,
@@ -977,6 +1126,37 @@ def execute_count_plan(
         hash_bits=plan.hash_bits,
     )
     return out
+
+
+# ---------------------------------------------------------------------------
+# Execute layer: the device-program tap
+# ---------------------------------------------------------------------------
+
+_RECORDED: Optional[list] = None
+
+
+@contextlib.contextmanager
+def record_programs():
+    """Record every device program launched through :func:`launch`
+    while the block runs, as ``(program, args, kwargs)`` triples — so a
+    caller can lower and compile exactly what an entry point ran
+    (``program.lower(*args, **kwargs).compile().as_text()``) and see
+    which kernels it holds. Recording keeps the arguments alive until
+    the list is dropped."""
+    global _RECORDED
+    prev, _RECORDED = _RECORDED, []
+    try:
+        yield _RECORDED
+    finally:
+        _RECORDED = prev
+
+
+def launch(program, *args, **kwargs):
+    """Run a jitted device program, recording it when
+    :func:`record_programs` is active."""
+    if _RECORDED is not None:
+        _RECORDED.append((program, args, kwargs))
+    return program(*args, **kwargs)
 
 
 # ---------------------------------------------------------------------------

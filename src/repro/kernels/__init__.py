@@ -12,11 +12,12 @@ pure-jnp oracle in ``ref`` and a backend-aware dispatcher in ``ops``:
   - ``bucket_update.bucket_update_pallas`` — Julienne-style batched
     decrease-key: apply one round's support decrements and re-derive
     the masked min + geometric bucket occupancy in the same pass,
-  - ``wedge_fused.fused_count_tiles_pallas`` — zero-materialization
-    fused counting: per vertex-aligned tile, reconstruct the wedge
-    slice in VMEM, aggregate, combine, and emit partial counts — the
-    global wedge array is never materialized (per-vertex/per-edge
-    accumulators are 64-bit two-limb pairs).
+  - ``wedge_fused.match_tiles_pallas`` — the aggregation step of
+    zero-materialization fused counting: per vertex-aligned wedge tile,
+    match endpoint pairs on the MXU and emit each lane's d - 1 and its
+    group's C(d, 2) (the executor in ``core.pipeline`` recovers the
+    tiles and scatters the lanes, so the global wedge array is never
+    materialized).
 
 The counting engine (``repro.core.count`` with ``engine="pallas"`` /
 ``engine="fused_pallas"``) and the peeling engines (``repro.core.peel``
@@ -27,8 +28,8 @@ from .ops import (
     bucket_min,
     bucket_update,
     butterfly_combine,
-    fused_count_tiles,
     interpret_default,
+    match_tiles,
     wedge_histogram,
 )
 
@@ -36,7 +37,7 @@ __all__ = [
     "bucket_min",
     "bucket_update",
     "butterfly_combine",
-    "fused_count_tiles",
     "interpret_default",
+    "match_tiles",
     "wedge_histogram",
 ]

@@ -21,11 +21,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 import numpy as np
 
 __all__ = ["bucket_min_pallas", "TN"]
 
 TN = 2048
+_ROWS = TN // 128  # one block = (_ROWS, 128) lanes
 _INF = np.int32(np.iinfo(np.int32).max)
 
 
@@ -55,23 +57,21 @@ def bucket_min_pallas(
     n_pad = ((n + TN - 1) // TN) * TN
     if counts.dtype.itemsize > 4:
         counts = jnp.minimum(counts, jnp.asarray(_INF, counts.dtype))
-    cp = jnp.pad(counts.astype(jnp.int32), (0, n_pad - n))
-    ap = jnp.pad(alive.astype(jnp.int32), (0, n_pad - n))
-    grid = (n_pad // TN,)
-    out = pl.pallas_call(
-        _min_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((TN,), lambda k: (k,)),
-            pl.BlockSpec((TN,), lambda k: (k,)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda k: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("arbitrary",))
-        )
-        if not interpret
-        else None,
-        interpret=interpret,
-    )(cp, ap)
+    rows = n_pad // 128
+    cp = jnp.pad(counts.astype(jnp.int32), (0, n_pad - n)).reshape(rows, 128)
+    ap = jnp.pad(alive.astype(jnp.int32), (0, n_pad - n)).reshape(rows, 128)
+    block = pl.BlockSpec((_ROWS, 128), lambda k: (k, 0))
+    with jax.enable_x64(False):  # Mosaic lowers 32-bit index math only
+        out = pl.pallas_call(
+            _min_kernel,
+            grid=(n_pad // TN,),
+            in_specs=[block, block],
+            out_specs=pl.BlockSpec((1, 1), lambda k: (0, 0)),
+            out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)
+            ),
+            interpret=interpret,
+            name="bucket_min",
+        )(cp, ap)
     return out[0, 0]

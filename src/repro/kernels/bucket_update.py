@@ -18,14 +18,20 @@ re-derive everything the next round's extract-min needs —
      occupancy.
 
 Exactness contract: the decrement scatter is realized as one-hot MXU
-contractions over three 12-bit limbs of ``dec`` (lo/mid/hi), so every
-f32 column sum stays below ``MAX_UPDATE_CAP * 2^12 = 2^24`` — exact —
+contractions over four 8-bit limbs of ``dec`` with bf16 operands (every
+limb and every one-hot entry is exact in bf16) and f32 accumulation, so
+every column sum stays below ``MAX_UPDATE_CAP * 2^8 < 2^24`` — exact —
 for update batches of at most ``MAX_UPDATE_CAP`` (4096) entries and
 ``dec`` anywhere in [0, 2^31). The wrapper enforces the batch bound at
-trace time; callers with larger batches use the jnp reference
-(``ref.bucket_update_ref`` via ``ops.bucket_update``), which has no
-bound. ``idx`` entries equal to ``counts.shape[0]`` (the sentinel) hit
-no bucket; their ``dec`` must be 0.
+trace time; ``ops.bucket_update`` feeds larger batches through the
+kernel in ``MAX_UPDATE_CAP`` chunks. ``idx`` entries equal to
+``counts.shape[0]`` (the sentinel) hit no bucket; their ``dec`` must
+be 0.
+
+Layout: the count array travels as one ``(1, n)`` row cut into
+``(1, TN)`` lane blocks; the update batch as ``(k, 1)`` columns cut into
+``(RC, 1)`` row chunks, the grid's second (reduction) axis, so the
+one-hot panel ``(RC, TN)`` needs no in-kernel reshape.
 
 Dispatched via ``ops.bucket_update`` with the same backend-aware
 interpret default as every kernel here (compiled on TPU, interpreted in
@@ -41,6 +47,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 import numpy as np
 
 __all__ = [
@@ -54,6 +61,7 @@ __all__ = [
 ]
 
 TN = 512  # count-array tile (matches the one-hot panel width)
+RC = 512  # update-batch rows per grid step (the one-hot panel height)
 NUM_BUCKETS = 32  # geometric ranges for int32 counts: bit_length in [0, 31]
 MAX_UPDATE_CAP = 4096  # keeps every f32 limb contraction exact (< 2^24)
 _INF = np.int32(np.iinfo(np.int32).max)
@@ -86,54 +94,58 @@ def lowest_nonempty_bucket(hist: jax.Array) -> jax.Array:
 
 def _update_kernel(counts_ref, alive_ref, idx_ref, dec_ref,
                    out_ref, mn_ref, hist_ref):
-    k = pl.program_id(0)
+    t = pl.program_id(0)  # count tile
+    r = pl.program_id(1)  # update-batch row chunk
 
-    @pl.when(k == 0)
+    @pl.when((t == 0) & (r == 0))
     def _init():
         mn_ref[...] = jnp.full_like(mn_ref, _INF)
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
-    c = counts_ref[...]
-    alive = alive_ref[...] > 0
-    idx = idx_ref[...]
-    dec = dec_ref[...]
-    rows = idx.shape[0]
-    base = k * TN
+    @pl.when(r == 0)
+    def _load():
+        out_ref[...] = counts_ref[...]
 
-    # -- 1. decrement scatter: one-hot MXU contraction, 12-bit limbs --
-    cols = jax.lax.broadcasted_iota(jnp.int32, (rows, TN), 1) + base
-    match = idx[:, None] == cols
-    ones8 = jnp.ones((8, rows), jnp.float32)
-    delta = jnp.zeros((TN,), jnp.int32)
-    for shift in (0, 12, 24):
-        limb = (dec >> shift) & jnp.int32(0xFFF)
-        panel = jnp.where(match, limb.astype(jnp.float32)[:, None], 0.0)
+    # -- 1. decrement scatter: one-hot MXU contraction, 8-bit limbs ---
+    idx = idx_ref[...]  # (RC, 1)
+    dec = dec_ref[...]  # (RC, 1)
+    rows = idx.shape[0]
+    cols = jax.lax.broadcasted_iota(jnp.int32, (rows, TN), 1) + t * TN
+    match = idx == cols
+    ones8 = jnp.ones((8, rows), jnp.bfloat16)
+    delta = jnp.zeros((1, TN), jnp.int32)
+    for shift in (0, 8, 16, 24):
+        limb = ((dec >> shift) & jnp.int32(0xFF)).astype(jnp.float32)
+        panel = jnp.where(match, limb, jnp.float32(0)).astype(jnp.bfloat16)
         part = jax.lax.dot_general(
             ones8, panel, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # (8, TN); rows identical
-        delta = delta + (part[0].astype(jnp.int32) << shift)
-    new = c - delta
-    out_ref[...] = new
+        delta = delta + (part[0:1, :].astype(jnp.int32) << shift)
+    out_ref[...] = out_ref[...] - delta
 
-    # -- 2. masked min of the updated tile ----------------------------
-    part_mn = jnp.min(jnp.where(alive, new, _INF)).reshape(1, 1)
-    mn_ref[...] = jnp.minimum(mn_ref[...], part_mn)
-
-    # -- 3. bucket-range occupancy: bucket(v) = bit_length(max(v, 0)) --
-    v = jnp.maximum(new, 0)
-    bl = jnp.zeros((TN,), jnp.int32)
-    for j in range(31):
-        bl = bl + (v >= jnp.int32(1 << j)).astype(jnp.int32)
-    bcols = jax.lax.broadcasted_iota(jnp.int32, (TN, 128), 1)
-    onehot = jnp.where(
-        (bl[:, None] == bcols) & alive[:, None], 1.0, 0.0
-    )
-    part_h = jax.lax.dot_general(
-        jnp.ones((8, TN), jnp.float32), onehot, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (8, 128)
-    hist_ref[...] = hist_ref[...] + part_h[:1].astype(jnp.int32)
+    @pl.when(r == pl.num_programs(1) - 1)
+    def _finish():
+        new = out_ref[...]  # (1, TN)
+        alive = alive_ref[...] > 0
+        # -- 2. masked min of the updated tile ------------------------
+        part_mn = jnp.min(jnp.where(alive, new, _INF)).reshape(1, 1)
+        mn_ref[...] = jnp.minimum(mn_ref[...], part_mn)
+        # -- 3. occupancy: bucket(v) = bit_length(max(v, 0)) ----------
+        v = jnp.maximum(new, 0)
+        bl = jnp.zeros((1, TN), jnp.int32)
+        for j in range(31):
+            bl = bl + (v >= jnp.int32(1 << j)).astype(jnp.int32)
+        buckets = jax.lax.broadcasted_iota(jnp.int32, (128, TN), 0)
+        onehot = jnp.where(
+            (bl == buckets) & alive, jnp.float32(1), jnp.float32(0)
+        ).astype(jnp.bfloat16)  # (128, TN): bucket rows x count lanes
+        part_h = jax.lax.dot_general(
+            jnp.ones((8, TN), jnp.bfloat16), onehot,
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # (8, 128)
+        hist_ref[...] = hist_ref[...] + part_h[0:1, :].astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -151,52 +163,52 @@ def bucket_update_pallas(
     INT32_MAX when none alive); ``bucket_hist`` is the (32,) occupancy
     of the geometric ranges over alive entries. ``idx == counts.shape
     [0]`` is the drop sentinel. Update batches beyond MAX_UPDATE_CAP
-    raise (use the jnp reference via ``ops.bucket_update``).
+    raise (``ops.bucket_update`` feeds them through in chunks).
     """
     if idx.shape[0] > MAX_UPDATE_CAP:
         raise ValueError(
             f"bucket_update_pallas batch {idx.shape[0]} exceeds "
             f"MAX_UPDATE_CAP {MAX_UPDATE_CAP} — the f32 limb "
-            "contractions would lose exactness; use the jnp reference "
-            "(ops.bucket_update(use_pallas=False))"
+            "contractions would lose exactness; ops.bucket_update "
+            "feeds larger batches through in chunks"
         )
     n = counts.shape[0]
     n_pad = ((n + TN - 1) // TN) * TN
-    cp = jnp.pad(counts.astype(jnp.int32), (0, n_pad - n))
-    ap = jnp.pad(alive.astype(jnp.int32), (0, n_pad - n))
+    cp = jnp.pad(counts.astype(jnp.int32), (0, n_pad - n))[None, :]
+    ap = jnp.pad(alive.astype(jnp.int32), (0, n_pad - n))[None, :]
     k = idx.shape[0]
-    k_pad = ((k + 127) // 128) * 128
+    rc = min(RC, ((k + 127) // 128) * 128)
+    k_pad = ((k + rc - 1) // rc) * rc
     # padded update lanes target the padded count region (>= n): their
     # delta lands on lanes the wrapper slices off and alive masks out
     ip = jnp.pad(idx.astype(jnp.int32), (0, k_pad - k),
                  constant_values=n_pad)
-    ip = jnp.where((ip < 0) | (ip >= n), jnp.int32(n_pad), ip)
-    dp = jnp.pad(dec.astype(jnp.int32), (0, k_pad - k))
-    grid = (n_pad // TN,)
-    out, mn, hist = pl.pallas_call(
-        _update_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((TN,), lambda t: (t,)),
-            pl.BlockSpec((TN,), lambda t: (t,)),
-            pl.BlockSpec((k_pad,), lambda t: (0,)),
-            pl.BlockSpec((k_pad,), lambda t: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((TN,), lambda t: (t,)),
-            pl.BlockSpec((1, 1), lambda t: (0, 0)),
-            pl.BlockSpec((1, 128), lambda t: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-            jax.ShapeDtypeStruct((1, 128), jnp.int32),
-        ],
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("arbitrary",))
-        )
-        if not interpret
-        else None,
-        interpret=interpret,
-    )(cp, ap, ip, dp)
-    return out[:n], mn[0, 0], hist[0, :NUM_BUCKETS]
+    ip = jnp.where((ip < 0) | (ip >= n), jnp.int32(n_pad), ip)[:, None]
+    dp = jnp.pad(dec.astype(jnp.int32), (0, k_pad - k))[:, None]
+    with jax.enable_x64(False):  # Mosaic lowers 32-bit index math only
+        out, mn, hist = pl.pallas_call(
+            _update_kernel,
+            grid=(n_pad // TN, k_pad // rc),
+            in_specs=[
+                pl.BlockSpec((1, TN), lambda t, r: (0, t)),
+                pl.BlockSpec((1, TN), lambda t, r: (0, t)),
+                pl.BlockSpec((rc, 1), lambda t, r: (r, 0)),
+                pl.BlockSpec((rc, 1), lambda t, r: (r, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, TN), lambda t, r: (0, t)),
+                pl.BlockSpec((1, 1), lambda t, r: (0, 0)),
+                pl.BlockSpec((1, 128), lambda t, r: (0, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
+                jax.ShapeDtypeStruct((1, 1), jnp.int32),
+                jax.ShapeDtypeStruct((1, 128), jnp.int32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary")
+            ),
+            interpret=interpret,
+            name="bucket_update",
+        )(cp, ap, ip, dp)
+    return out[0, :n], mn[0, 0], hist[0, :NUM_BUCKETS]

@@ -9,7 +9,8 @@ group multiplicity ``d`` and a group-representative flag, emit
 
 plus per-tile partial sums of choose2 (the global count reduction) so
 the host-side total is a cheap O(grid) add. Elementwise VPU work tiled
-through VMEM; the reduction keeps a (1,1) accumulator block.
+through VMEM as lane-dense ``(TN // 128, 128)`` blocks; the reduction
+keeps a (1,1) accumulator block.
 
 Precision contract: C(d, 2) is computed exactly for the full int32
 ``d`` range (0 <= d < 2^31) with 16-bit-limb uint32 arithmetic — the
@@ -29,10 +30,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["butterfly_combine_pallas", "choose2_limbs", "TN"]
 
 TN = 1024
+_ROWS = TN // 128  # one block = (_ROWS, 128) lanes
 
 
 def choose2_limbs(d: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -60,6 +63,16 @@ def choose2_limbs(d: jax.Array) -> tuple[jax.Array, jax.Array]:
     return c2_lo.astype(jnp.int32), c2_hi.astype(jnp.int32)
 
 
+def _u32_as_f32(x: jax.Array) -> jax.Array:
+    """f32 value of an int32 array read as uint32, through int32 casts
+    only (Mosaic has no uint32 -> float32 conversion)."""
+    u = x.astype(jnp.uint32)
+    half = (u >> 1).astype(jnp.int32).astype(jnp.float32)
+    return half * jnp.float32(2.0) + (u & jnp.uint32(1)).astype(
+        jnp.int32
+    ).astype(jnp.float32)
+
+
 def _combine_kernel(d_ref, rep_ref, valid_ref, dm1_ref, lo_ref, hi_ref, tot_ref):
     k = pl.program_id(0)
     d = d_ref[...].astype(jnp.int32)
@@ -72,7 +85,7 @@ def _combine_kernel(d_ref, rep_ref, valid_ref, dm1_ref, lo_ref, hi_ref, tot_ref)
     lo_ref[...] = lo
     hi_ref[...] = hi
     part = (
-        jnp.sum(lo.astype(jnp.uint32).astype(jnp.float32))
+        jnp.sum(_u32_as_f32(lo))
         + jnp.sum(hi.astype(jnp.float32)) * jnp.float32(2.0**32)
     ).reshape(1, 1)
 
@@ -95,35 +108,31 @@ def butterfly_combine_pallas(
     (lo is the low word's bit pattern)."""
     n = d.shape[0]
     n_pad = ((n + TN - 1) // TN) * TN
-    dp = jnp.pad(d.astype(jnp.int32), (0, n_pad - n))
-    rp = jnp.pad(rep.astype(jnp.int32), (0, n_pad - n))
-    vp = jnp.pad(valid.astype(jnp.int32), (0, n_pad - n))
-    grid = (n_pad // TN,)
-    dm1, lo, hi, tot = pl.pallas_call(
-        _combine_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((TN,), lambda k: (k,)),
-            pl.BlockSpec((TN,), lambda k: (k,)),
-            pl.BlockSpec((TN,), lambda k: (k,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((TN,), lambda k: (k,)),
-            pl.BlockSpec((TN,), lambda k: (k,)),
-            pl.BlockSpec((TN,), lambda k: (k,)),
-            pl.BlockSpec((1, 1), lambda k: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        ],
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("arbitrary",))
-        )
-        if not interpret
-        else None,
-        interpret=interpret,
-    )(dp, rp, vp)
-    return dm1[:n], lo[:n], hi[:n], tot[0, 0]
+    rows = n_pad // 128
+
+    def lanes(x):
+        return jnp.pad(x.astype(jnp.int32), (0, n_pad - n)).reshape(rows, 128)
+
+    block = pl.BlockSpec((_ROWS, 128), lambda k: (k, 0))
+    plane = jax.ShapeDtypeStruct((rows, 128), jnp.int32)
+    with jax.enable_x64(False):  # Mosaic lowers 32-bit index math only
+        dm1, lo, hi, tot = pl.pallas_call(
+            _combine_kernel,
+            grid=(n_pad // TN,),
+            in_specs=[block, block, block],
+            out_specs=[
+                block, block, block, pl.BlockSpec((1, 1), lambda k: (0, 0)),
+            ],
+            out_shape=[
+                plane, plane, plane, jax.ShapeDtypeStruct((1, 1), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)
+            ),
+            interpret=interpret,
+            name="butterfly_combine",
+        )(lanes(d), lanes(rep), lanes(valid))
+    return (
+        dm1.reshape(-1)[:n], lo.reshape(-1)[:n], hi.reshape(-1)[:n],
+        tot[0, 0],
+    )

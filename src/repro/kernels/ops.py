@@ -22,6 +22,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 
 from ..testing import faults as _faults
 from . import ref as _ref
@@ -36,7 +37,7 @@ from .bucket_update import (
 )
 from .butterfly_combine import butterfly_combine_pallas
 from .wedge_count import wedge_histogram_pallas
-from .wedge_fused import MAX_TILE_CAP, TC, fused_count_tiles_pallas
+from .wedge_fused import MAX_TILE_CAP, TC, match_tiles_pallas
 
 __all__ = [
     "interpret_default",
@@ -45,7 +46,7 @@ __all__ = [
     "bucket_min",
     "bucket_state",
     "bucket_update",
-    "fused_count_tiles",
+    "match_tiles",
     # kernel-contract constants and pure helpers, re-exported so core/
     # consumes them through this dispatch module instead of importing
     # concrete kernel modules (the layering rule check_layering.py
@@ -129,55 +130,49 @@ def bucket_update(
     """Julienne-style batched decrease-key: apply the (idx, dec) update
     batch to ``counts`` and return ``(new_counts, min over alive,
     geometric-bucket occupancy)`` from the same pass (see
-    ``bucket_update``). The kernel path requires int32 counts and a
-    batch of at most MAX_UPDATE_CAP entries; callers outside that
-    contract (or off the compiled backend — the device peeling loops
-    decide at trace time) use the jnp reference.
+    ``bucket_update``). The kernel path computes in int32: callers keep
+    counts below INT32_MAX (the device peeling loops guard it) and get
+    the result back in their count dtype. A batch beyond MAX_UPDATE_CAP
+    goes through the kernel in MAX_UPDATE_CAP chunks, one pass each;
+    the last pass's min and occupancy describe the final counts.
     """
     _faults.maybe_oom("ops.bucket_update")
-    if use_pallas and idx.shape[0] <= MAX_UPDATE_CAP:
-        return bucket_update_pallas(
-            counts, alive, idx, dec, interpret=_resolve(interpret)
+    if not use_pallas:
+        return _ref.bucket_update_ref(counts, alive, idx, dec)
+    interp = _resolve(interpret)
+    k = idx.shape[0]
+    if k <= MAX_UPDATE_CAP:
+        new, mn, hist = bucket_update_pallas(
+            counts, alive, idx, dec, interpret=interp
         )
-    return _ref.bucket_update_ref(counts, alive, idx, dec)
+        return new.astype(counts.dtype), mn, hist
+    chunks = -(-k // MAX_UPDATE_CAP)
+    pad = chunks * MAX_UPDATE_CAP - k
+    idx_c = jnp.pad(idx.astype(jnp.int32), (0, pad),
+                    constant_values=counts.shape[0])
+    dec_c = jnp.pad(dec.astype(jnp.int32), (0, pad))
 
+    def step(c, chunk):
+        new, mn, hist = bucket_update_pallas(
+            c, alive, chunk[0], chunk[1], interpret=interp
+        )
+        return new, (mn, hist)
 
-def fused_count_tiles(
-    tile_bounds,
-    offsets,
-    neighbors,
-    edge_src,
-    undirected_id,
-    w_off,
-    *,
-    tile_cap: int,
-    n_pad: int,
-    m: int,
-    direction: str = "low",
-    mode: str = "all",
-    use_pallas: bool = False,
-    interpret: Optional[bool] = None,
-):
-    """Zero-materialization fused counting over vertex-aligned wedge
-    tiles (``engine="fused_pallas"`` hot path; see ``wedge_fused``).
-    Returns (total int32 limbs (2,), per_vertex limbs (n_pad, 2),
-    per_edge limbs (m, 2)) — all exact 64-bit counts as (lo, hi) pairs.
-    """
-    _faults.maybe_oom("ops.fused_count_tiles")
-    kw = dict(
-        tile_cap=tile_cap, n_pad=n_pad, m=m, direction=direction, mode=mode
+    new, (mns, hists) = jax.lax.scan(
+        step,
+        counts.astype(jnp.int32),
+        (idx_c.reshape(chunks, MAX_UPDATE_CAP),
+         dec_c.reshape(chunks, MAX_UPDATE_CAP)),
     )
+    return new.astype(counts.dtype), mns[-1], hists[-1]
+
+
+def match_tiles(ka, kb, use_pallas: bool = False,
+                interpret: Optional[bool] = None):
+    """In-tile endpoint-pair aggregation of ``(n_tiles, T)`` wedge key
+    lanes (``engine="fused_pallas"`` hot step; see ``wedge_fused``):
+    per lane ``(d - 1, C(d, 2) on group representatives)``."""
+    _faults.maybe_oom("ops.match_tiles")
     if use_pallas:
-        out = fused_count_tiles_pallas(
-            tile_bounds, offsets, neighbors, edge_src, undirected_id, w_off,
-            interpret=_resolve(interpret), **kw,
-        )
-    else:
-        out = _ref.fused_count_tiles_ref(
-            tile_bounds, offsets, neighbors, edge_src, undirected_id, w_off,
-            **kw,
-        )
-    # value-level poison hook: this wrapper runs outside any cached jit
-    # (the counting dispatcher calls it at host level), so planting the
-    # sentinel here can never leak into a compilation cache
-    return _faults.maybe_poison("ops.fused_count_tiles", out)
+        return match_tiles_pallas(ka, kb, interpret=_resolve(interpret))
+    return _ref.match_tiles_ref(ka, kb)

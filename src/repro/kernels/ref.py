@@ -17,7 +17,7 @@ __all__ = [
     "bucket_min_ref",
     "bucket_state_ref",
     "bucket_update_ref",
-    "fused_count_tiles_ref",
+    "match_tiles_ref",
 ]
 
 
@@ -138,97 +138,24 @@ def bucket_update_ref(
     return new, mn, hist
 
 
-def fused_count_tiles_ref(
-    tile_bounds: jax.Array,
-    offsets: jax.Array,
-    neighbors: jax.Array,
-    edge_src: jax.Array,
-    undirected_id: jax.Array,
-    w_off: jax.Array,
-    *,
-    tile_cap: int,
-    n_pad: int,
-    m: int,
-    direction: str = "low",
-    mode: str = "all",
-):
-    """Oracle for ``wedge_fused.fused_count_tiles_pallas`` — same
-    vertex-aligned tile semantics (reconstruct, aggregate in-tile,
-    combine, accumulate partials) expressed with plain jnp scatter-adds
-    instead of one-hot MXU panels. Bit-identical integer outputs: the
-    kernel's f32 contractions are exact by the MAX_TILE_CAP contract,
-    and the per-vertex/per-edge (lo, hi) limb accumulation mirrors the
-    kernel's per-tile uint32 carry chain exactly."""
-
-    def _limb_add(lo, hi, part):
-        """Accumulate a nonnegative int32 per-tile partial into (lo, hi)
-        uint32-style limbs — the kernel's carry chain."""
-        part_u = part.astype(jnp.uint32)
-        lo_u = lo.astype(jnp.uint32) + part_u
-        carry = (lo_u < part_u).astype(jnp.int32)
-        return lo_u.astype(jnp.int32), hi + carry
-
-    e_pad = int(neighbors.shape[0])
-    n_tiles = int(tile_bounds.shape[0])
-    tot = jnp.zeros((2,), jnp.int32)
-    vlo = jnp.zeros((n_pad,), jnp.int32)
-    vhi = jnp.zeros((n_pad,), jnp.int32)
-    elo = jnp.zeros((m,), jnp.int32)
-    ehi = jnp.zeros((m,), jnp.int32)
-    lid = jnp.arange(tile_cap, dtype=jnp.int32)
-    for t in range(n_tiles):
-        ws = tile_bounds[t, 0]
-        we = tile_bounds[t, 1]
-        wid = ws + lid
-        valid = wid < we
-        wc = jnp.minimum(wid, jnp.maximum(we - 1, 0))
-        e = jnp.searchsorted(w_off, wc, side="right").astype(jnp.int32) - 1
-        e = jnp.clip(e, 0, e_pad - 1)
-        j = wc - w_off[e]
-        cnt_e = w_off[e + 1] - w_off[e]
-        y = neighbors[e]
-        y_safe = jnp.minimum(y, n_pad - 1)
-        if direction == "low":
-            x1 = edge_src[e]
-            pos = offsets[y_safe + 1] - cnt_e + j
-            x2 = neighbors[jnp.clip(pos, 0, e_pad - 1)]
-        elif direction == "high":
-            x2 = edge_src[e]
-            pos = offsets[y_safe] + j
-            x1 = neighbors[jnp.clip(pos, 0, e_pad - 1)]
-        else:
-            raise ValueError(f"direction must be low|high, got {direction}")
-        pos = jnp.clip(pos, 0, e_pad - 1)
-        ka = jnp.where(valid, x1, -1)
-        kb = jnp.where(valid, x2, -2)
-        match = (ka[:, None] == ka[None, :]) & (kb[:, None] == kb[None, :])
-        d = jnp.sum(match, axis=1).astype(jnp.int32)
-        earlier = jnp.sum(
-            match & (lid[None, :] < lid[:, None]), axis=1
-        ).astype(jnp.int32)
-        rep = valid & (earlier == 0)
-        dm1 = jnp.where(valid, d - 1, 0)
-        c2 = jnp.where(rep, d * (d - 1) // 2, 0)
-        if mode in ("global", "all"):
-            part_u = jnp.sum(c2).astype(jnp.uint32)
-            lo_new = tot[0].astype(jnp.uint32) + part_u
-            carry = (lo_new < part_u).astype(jnp.int32)
-            tot = jnp.stack([lo_new.astype(jnp.int32), tot[1] + carry])
-        if mode in ("vertex", "all"):
-            oob = jnp.int32(n_pad)  # scatter drops out-of-bounds
-            part = jnp.zeros((n_pad,), jnp.int32)
-            part = part.at[jnp.where(rep, x1, oob)].add(c2)
-            part = part.at[jnp.where(rep, x2, oob)].add(c2)
-            part = part.at[jnp.where(valid, y, oob)].add(dm1)
-            vlo, vhi = _limb_add(vlo, vhi, part)
-        if mode in ("edge", "all"):
-            oob = jnp.int32(m)
-            part = jnp.zeros((m,), jnp.int32)
-            part = part.at[jnp.where(valid, undirected_id[e], oob)].add(dm1)
-            part = part.at[jnp.where(valid, undirected_id[pos], oob)].add(dm1)
-            elo, ehi = _limb_add(elo, ehi, part)
-    return (
-        tot,
-        jnp.stack([vlo, vhi], axis=-1),
-        jnp.stack([elo, ehi], axis=-1),
+def match_tiles_ref(ka: jax.Array, kb: jax.Array):
+    """Oracle for ``wedge_fused.match_tiles_pallas``: per-lane
+    ``(d - 1, C(d, 2) on group representatives)`` of ``(n_tiles, T)``
+    endpoint key lanes, from a dense all-pairs comparison per tile
+    instead of MXU panels. Lanes with ``ka < 0`` are invalid."""
+    ka = ka.astype(jnp.int32)
+    kb = kb.astype(jnp.int32)
+    lane = jnp.arange(ka.shape[1], dtype=jnp.int32)
+    match = (ka[:, :, None] == ka[:, None, :]) & (
+        kb[:, :, None] == kb[:, None, :]
     )
+    d = jnp.sum(match, axis=2, dtype=jnp.int32)
+    earlier = jnp.sum(
+        match & (lane[None, :] < lane[:, None])[None], axis=2,
+        dtype=jnp.int32,
+    )
+    valid = ka >= 0
+    rep = valid & (earlier == 0)
+    dm1 = jnp.where(valid, d - 1, 0)
+    c2 = jnp.where(rep, d * (d - 1) // 2, 0)
+    return dm1, c2

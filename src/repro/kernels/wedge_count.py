@@ -13,6 +13,10 @@ the hardware-adaptation story of DESIGN.md §2.
 
 Grid: (num_bucket_tiles, num_key_tiles); the key-tile dimension is the
 minormost (sequential) axis so each output tile accumulates in place.
+Keys enter as a ``(n, 1)`` column so each tile's ``(TK, 1)`` block
+broadcasts along the bucket lanes without an in-kernel reshape, and the
+one-hot operands are bf16 (0/1 is exact; the f32 accumulation is exact
+below 2^24 keys per bucket tile, far above ``TK``).
 
 Engine wiring: ``repro.core.aggregate`` routes the hash-slot and dense
 (x1, x2)-key histograms here when the counting engine runs with
@@ -28,7 +32,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-import numpy as np
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["wedge_histogram_pallas", "TK", "TB"]
 
@@ -39,13 +43,13 @@ TB = 512  # buckets per tile  (one-hot tile: 512x512 f32 = 1 MiB VMEM)
 def _hist_kernel(keys_ref, valid_ref, out_ref):
     k = pl.program_id(1)
     b0 = pl.program_id(0) * TB
-    keys = keys_ref[...].astype(jnp.int32)  # (TK,)
-    valid = valid_ref[...]  # (TK,) int32 0/1
+    keys = keys_ref[...]  # (TK, 1) int32
+    valid = valid_ref[...]  # (TK, 1) int32 0/1
     cols = jax.lax.broadcasted_iota(jnp.int32, (TK, TB), 1) + b0
     onehot = jnp.where(
-        (keys[:, None] == cols) & (valid[:, None] > 0), 1.0, 0.0
-    ).astype(jnp.float32)
-    ones = jnp.ones((8, TK), jnp.float32)  # MXU-friendly LHS
+        (keys == cols) & (valid > 0), jnp.float32(1), jnp.float32(0)
+    ).astype(jnp.bfloat16)  # typed: a bare 1.0 is float64 under x64
+    ones = jnp.ones((8, TK), jnp.bfloat16)  # MXU-friendly LHS
     part = jax.lax.dot_general(
         ones,
         onehot,
@@ -79,23 +83,23 @@ def wedge_histogram_pallas(
     n = keys.shape[0]
     n_pad = ((n + TK - 1) // TK) * TK
     b_pad = ((num_buckets + TB - 1) // TB) * TB
-    keys = jnp.pad(keys, (0, n_pad - n), constant_values=-1)
-    valid = jnp.pad(valid, (0, n_pad - n))
+    keys = jnp.pad(keys, (0, n_pad - n), constant_values=-1)[:, None]
+    valid = jnp.pad(valid, (0, n_pad - n))[:, None]
     grid = (b_pad // TB, n_pad // TK)
-    out = pl.pallas_call(
-        _hist_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((TK,), lambda b, k: (k,)),
-            pl.BlockSpec((TK,), lambda b, k: (k,)),
-        ],
-        out_specs=pl.BlockSpec((1, TB), lambda b, k: (0, b)),
-        out_shape=jax.ShapeDtypeStruct((1, b_pad), jnp.int32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "arbitrary"))
-        )
-        if not interpret
-        else None,
-        interpret=interpret,
-    )(keys, valid)
+    with jax.enable_x64(False):  # Mosaic lowers 32-bit index math only
+        out = pl.pallas_call(
+            _hist_kernel,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((TK, 1), lambda b, k: (k, 0)),
+                pl.BlockSpec((TK, 1), lambda b, k: (k, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, TB), lambda b, k: (0, b)),
+            out_shape=jax.ShapeDtypeStruct((1, b_pad), jnp.int32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")
+            ),
+            interpret=interpret,
+            name="wedge_count",
+        )(keys, valid)
     return out[0, :num_buckets]

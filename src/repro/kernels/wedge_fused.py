@@ -1,49 +1,44 @@
-"""Pallas TPU kernel: zero-materialization fused butterfly counting.
+"""Pallas TPU kernel: in-VMEM endpoint-pair aggregation of wedge tiles.
 
-One grid step = one *vertex-aligned* tile of the flat wedge space. The
-kernel never sees a materialized wedge array: per tile it
+The hot step of zero-materialization fused counting
+(``engine="fused_pallas"``). The executor (``core.pipeline``) cuts the
+flat wedge space into *vertex-aligned* tiles, recovers each tile's
+wedges in XLA (the ``wedges_at`` binary search and CSR gathers), and
+hands this kernel only the tile's two endpoint key lanes. Per tile the
+kernel
 
-  1. reconstructs its slice of flat wedge ids in VMEM — the same
-     binary-search recovery as ``wedges.wedges_at`` (upper_bound on the
-     wedge-prefix array, then two CSR gathers),
-  2. aggregates the tile's endpoint-pair groups in VMEM via an
-     all-pairs key-match contraction on the MXU (group multiplicity
-     ``d`` = row sum of the match matrix; the group representative is
-     the first occurrence = zero earlier matches),
-  3. applies the C(d, 2) combine in-register, and
-  4. emits partial global / per-vertex / per-edge contributions through
-     weighted one-hot MXU matmuls, accumulated across sequential grid
-     steps directly in the output blocks.
+  1. aggregates the endpoint-pair groups with an all-pairs key match on
+     the MXU: the group multiplicity ``d`` of a lane is the number of
+     lanes with the same ``(x1, x2)``, and the lane is its group's
+     representative when no earlier lane matches it;
+  2. applies the C(d, 2) combine in-register, emitting per lane
+     ``dm1 = d - 1`` (the wedge's center / edge contribution) and
+     ``c2 = C(d, 2)`` on representatives (the endpoints' contribution,
+     0 elsewhere).
 
-Peak live memory is O(tile): the six per-wedge vectors, the (tile, TC)
-match panel, and the (3·tile, TBV) scatter panel — nothing scales with
-the total wedge count W.
+Scattering those lanes into the per-vertex / per-edge accumulators, and
+summing ``c2`` into the global total, is XLA's job in the executor, so
+nothing here scales with n, m or the wedge total W: live memory is the
+tile's key lanes and one ``(TC, T)`` match panel.
 
 Tile-alignment invariant (shared with ``wedges.plan_wedge_chunks``):
 flat wedge ids follow CSR slot order, so all wedges produced by one
 iterating endpoint are contiguous, and every endpoint-pair group lives
 entirely inside its iterating endpoint's range. Tile boundaries are
-therefore cut only at vertex boundaries — no group ever spans a tile,
-per-tile aggregation is exact, and per-tile contributions add. This is
-also what bounds the in-tile multiplicity: ``d <= tile_cap``.
+therefore cut only at vertex boundaries: no group ever spans a tile,
+and per-tile aggregation is exact.
 
-Precision contract (all outputs exact):
-  - ``tile_cap <= MAX_TILE_CAP`` (4096). Then per-tile
-    Σ C(d, 2) <= C(tile_cap, 2) < 2^23 and every f32 matmul column sum
-    stays <= 2^24 - 1, i.e. exactly representable. Enforced at trace
-    time.
-  - the global total accumulates across tiles as two uint32-style int32
-    limbs with carry (exact for totals < 2^63);
-  - per-vertex / per-edge outputs accumulate the same way: two-limb
-    (lo, hi) int32 pairs with per-element carry across tiles (the
-    ``butterfly_combine`` widening applied to the scatter panels), so
-    counts >= 2^31 stay exact — recombine with
-    ``core.count._combine_limbs``.
+Layout: keys enter twice, as ``(n_tiles * T, 1)`` columns (blocked
+``(T, 1)``) and as ``(n_tiles, 1, T)`` rows (blocked ``(1, 1, T)``), so
+the match panel ``column == row`` is a plain broadcast; the per-lane
+outputs are lane-dense ``(1, 1, T)`` rows. Invalid lanes carry the keys
+``(-1, -2)``, which never equal a real vertex id.
 
-Off-TPU this runs in interpret mode like every kernel in this package
-(``kernels/ops`` backend dispatch); the in-kernel vector gathers and
-the full-CSR VMEM residency are sized for compiled-TPU validation on
-real hardware (ROADMAP open item).
+Precision contract (all outputs exact): the match panels are 0/1 in
+bf16 with f32 accumulation, so ``d`` and the earlier-match count are
+exact for any tile below 2^24 lanes; ``tile_cap <= MAX_TILE_CAP``
+(4096) keeps ``d * (d - 1)`` below 2^24 in int32. Enforced at trace
+time.
 """
 from __future__ import annotations
 
@@ -51,267 +46,100 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["fused_count_tiles_pallas", "MAX_TILE_CAP", "TC", "TBV"]
+__all__ = ["match_tiles_pallas", "MAX_TILE_CAP", "TC"]
 
-MAX_TILE_CAP = 4096  # keeps every f32 one-hot contraction exact (< 2^24)
-TC = 512  # match-panel column tile
-TBV = 512  # scatter-panel bucket tile (vertex and edge outputs)
+MAX_TILE_CAP = 4096  # keeps every in-tile count exact (see contract)
+TC = 128  # match-panel rows per step (a (TC, T) panel lives in VMEM)
 
-
-def _round_up(x: int, to: int) -> int:
-    return ((max(int(x), 1) + to - 1) // to) * to
-
-
-def _weighted_scatter(lo_ref, hi_ref, tgt, val, n_out):
-    """(lo, hi)[b] += Σ_i val[i] * [tgt[i] == b] via one-hot MXU panels.
-
-    ``tgt`` entries equal to ``n_out`` (the sentinel) match no bucket.
-    Each tile's partial sum is exact (``val`` < 2^23, every column sum
-    < 2^24 — module contract) and accumulates into the two-limb output
-    with a per-element uint32 carry, so per-bucket totals stay exact
-    across arbitrarily many grid steps (counts < 2^63).
-    """
-    rows = tgt.shape[0]
-    ones = jnp.ones((8, rows), jnp.float32)
-    val_f = val.astype(jnp.float32)
-    for bt in range(n_out // TBV):
-        cols = (
-            jax.lax.broadcasted_iota(jnp.int32, (rows, TBV), 1) + bt * TBV
-        )
-        panel = jnp.where(tgt[:, None] == cols, val_f[:, None], 0.0)
-        part = jax.lax.dot_general(
-            ones,
-            panel,
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (8, TBV); rows identical
-        sl = slice(bt * TBV, (bt + 1) * TBV)
-        part_u = part[0].astype(jnp.int32).astype(jnp.uint32)
-        lo_u = lo_ref[sl].astype(jnp.uint32) + part_u
-        carry = (lo_u < part_u).astype(jnp.int32)
-        lo_ref[sl] = lo_u.astype(jnp.int32)
-        hi_ref[sl] = hi_ref[sl] + carry
-
-
-def _make_kernel(T, e_pad, n_pad, n_out, m_out, bs_steps, direction, mode):
-    do_vertex = mode in ("vertex", "all")
-    do_edge = mode in ("edge", "all")
-    do_global = mode in ("global", "all")
-
-    def kernel(bounds_ref, off_ref, nbr_ref, src_ref, uid_ref, woff_ref,
-               tot_ref, vlo_ref, vhi_ref, elo_ref, ehi_ref):
-        t = pl.program_id(0)
-
-        @pl.when(t == 0)
-        def _init():
-            tot_ref[...] = jnp.zeros_like(tot_ref)
-            vlo_ref[...] = jnp.zeros_like(vlo_ref)
-            vhi_ref[...] = jnp.zeros_like(vhi_ref)
-            elo_ref[...] = jnp.zeros_like(elo_ref)
-            ehi_ref[...] = jnp.zeros_like(ehi_ref)
-
-        ws = bounds_ref[0, 0]
-        we = bounds_ref[0, 1]
-        woff = woff_ref[...]
-        nbr = nbr_ref[...]
-        src = src_ref[...]
-        off = off_ref[...]
-        uid = uid_ref[...]
-
-        # -- 1. in-VMEM wedge reconstruction (wedges_at recovery) -----
-        lid = jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0).reshape(T)
-        wid = ws + lid
-        valid = wid < we
-        wc = jnp.minimum(wid, jnp.maximum(we - 1, 0))
-
-        def bs_body(_, carry):
-            lo, hi = carry
-            mid = (lo + hi) >> 1
-            take = (lo < hi) & (woff[mid] <= wc)
-            return (
-                jnp.where(take, mid + 1, lo),
-                jnp.where((lo < hi) & ~take, mid, hi),
-            )
-
-        lo0 = jnp.zeros((T,), jnp.int32)
-        hi0 = jnp.full((T,), woff.shape[0], jnp.int32)
-        ub, _ = jax.lax.fori_loop(0, bs_steps, bs_body, (lo0, hi0))
-        e = jnp.clip(ub - 1, 0, e_pad - 1)
-        j = wc - woff[e]
-        cnt_e = woff[e + 1] - woff[e]
-        y = nbr[e]
-        y_safe = jnp.minimum(y, n_pad - 1)
-        if direction == "low":
-            x1 = src[e]
-            pos = off[y_safe + 1] - cnt_e + j
-            x2 = nbr[jnp.clip(pos, 0, e_pad - 1)]
-        else:
-            x2 = src[e]
-            pos = off[y_safe] + j
-            x1 = nbr[jnp.clip(pos, 0, e_pad - 1)]
-        pos = jnp.clip(pos, 0, e_pad - 1)
-
-        # -- 2. tile-local aggregation: all-pairs key match on MXU ----
-        # invalid lanes get a sentinel key that never equals a real
-        # (x1 in [0, n_pad)) key, so they only match each other — and
-        # their lanes are masked out of every contribution below.
-        ka = jnp.where(valid, x1, -1)
-        kb = jnp.where(valid, x2, -2)
-        ones_tc = jnp.ones((TC, 8), jnp.float32)
-        d8 = jnp.zeros((T, 8), jnp.float32)
-        lt8 = jnp.zeros((T, 8), jnp.float32)
-        row_id = lid
-        for ct in range(T // TC):
-            c0 = ct * TC
-            a_j = jax.lax.dynamic_slice(ka, (c0,), (TC,))
-            b_j = jax.lax.dynamic_slice(kb, (c0,), (TC,))
-            match = (ka[:, None] == a_j[None, :]) & (kb[:, None] == b_j[None, :])
-            match_f = match.astype(jnp.float32)
-            col_id = (
-                jax.lax.broadcasted_iota(jnp.int32, (T, TC), 1) + c0
-            )
-            lt_f = jnp.where(col_id < row_id[:, None], match_f, 0.0)
-            d8 += jax.lax.dot_general(
-                match_f, ones_tc, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            lt8 += jax.lax.dot_general(
-                lt_f, ones_tc, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-        d = d8[:, 0].astype(jnp.int32)  # d <= T <= MAX_TILE_CAP: exact
-        rep = valid & (lt8[:, 0].astype(jnp.int32) == 0)
-
-        # -- 3. in-register combine (exact int32: d*(d-1) < 2^24) -----
-        dm1 = jnp.where(valid, d - 1, 0)
-        c2 = jnp.where(rep, d * (d - 1) // 2, 0)
-
-        # -- 4. partial contributions -------------------------------
-        if do_global:
-            part_u = jnp.sum(c2).astype(jnp.uint32)
-            lo_u = tot_ref[0, 0].astype(jnp.uint32)
-            lo_new = lo_u + part_u
-            carry = (lo_new < part_u).astype(jnp.int32)
-            tot_ref[0, 0] = lo_new.astype(jnp.int32)
-            tot_ref[0, 1] = tot_ref[0, 1] + carry
-        if do_vertex:
-            sent = jnp.int32(n_out)
-            tgt = jnp.concatenate([
-                jnp.where(rep, x1, sent),
-                jnp.where(rep, x2, sent),
-                jnp.where(valid, y, sent),
-            ])
-            val = jnp.concatenate([c2, c2, dm1])
-            _weighted_scatter(vlo_ref, vhi_ref, tgt, val, n_out)
-        if do_edge:
-            sent = jnp.int32(m_out)
-            tgt = jnp.concatenate([
-                jnp.where(valid, uid[e], sent),
-                jnp.where(valid, uid[pos], sent),
-            ])
-            val = jnp.concatenate([dm1, dm1])
-            _weighted_scatter(elo_ref, ehi_ref, tgt, val, m_out)
-
-    return kernel
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("tile_cap", "n_pad", "m", "direction", "mode",
-                     "interpret"),
-)
-def fused_count_tiles_pallas(
-    tile_bounds: jax.Array,  # (n_tiles, 2) int32 per-tile [ws, we)
-    offsets: jax.Array,  # (n_pad + 1,) int32 CSR
-    neighbors: jax.Array,  # (e_pad,) int32
-    edge_src: jax.Array,  # (e_pad,) int32
-    undirected_id: jax.Array,  # (e_pad,) int32
-    w_off: jax.Array,  # (e_pad + 1,) int32 wedge prefix
-    *,
-    tile_cap: int,
-    n_pad: int,
-    m: int,
-    direction: str = "low",
-    mode: str = "all",
-    interpret: bool = True,
-):
-    """Fused tiled butterfly counting over vertex-aligned wedge tiles.
-
-    Returns ``(total_limbs int32 (2,), per_vertex int32 (n_pad, 2),
-    per_edge int32 (m, 2))`` — every output is (lo, hi) uint32-style
-    limb words of the exact 64-bit count (the per-vertex/per-edge
-    arrays stack the limbs on the last axis); recombine with
-    ``core.count._combine_limbs``. Modes not requested by ``mode``
-    come back as zeros.
-    """
-    if direction not in ("low", "high"):
-        raise ValueError(f"direction must be low|high, got {direction}")
-    if mode not in ("global", "vertex", "edge", "all"):
-        raise ValueError(f"bad mode {mode}")
-    if tile_cap % TC != 0:
-        raise ValueError(
-            f"tile_cap must be a multiple of TC={TC}, got {tile_cap} — "
-            "the match-panel column loop requires it (callers pad the "
-            "planned chunk_cap up; see core.count)"
-        )
-    if tile_cap > MAX_TILE_CAP:
-        raise ValueError(
-            f"tile_cap {tile_cap} exceeds MAX_TILE_CAP {MAX_TILE_CAP} — "
-            "the f32 one-hot contractions would lose exactness; use the "
-            "pure-XLA fused engine for larger tiles"
-        )
-    T = int(tile_cap)
-    e_pad = int(neighbors.shape[0])
-    n_tiles = int(tile_bounds.shape[0])
-    n_out = _round_up(n_pad, TBV)
-    m_out = _round_up(m, TBV)
-    bs_steps = max(1, int(np.ceil(np.log2(max(e_pad + 1, 2)))) + 1)
-    kernel = _make_kernel(
-        T, e_pad, n_pad, n_out, m_out, bs_steps, direction, mode
+def _bf16_01(mask):
+    """0/1 bf16 panel of a mask: select in f32 (a bf16 select would need
+    the mask relaid to bf16's packed tiling) with typed constants (under
+    x64 a bare 1.0 is float64, which Mosaic cannot convert)."""
+    return jnp.where(mask, jnp.float32(1), jnp.float32(0)).astype(
+        jnp.bfloat16
     )
-    full = lambda arr: pl.BlockSpec(arr.shape, lambda t: (0,))  # noqa: E731
-    tot, vlo, vhi, elo, ehi = pl.pallas_call(
-        kernel,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((1, 2), lambda t: (t, 0)),
-            full(offsets),
-            full(neighbors),
-            full(edge_src),
-            full(undirected_id),
-            full(w_off),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 2), lambda t: (0, 0)),
-            pl.BlockSpec((n_out,), lambda t: (0,)),
-            pl.BlockSpec((n_out,), lambda t: (0,)),
-            pl.BlockSpec((m_out,), lambda t: (0,)),
-            pl.BlockSpec((m_out,), lambda t: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, 2), jnp.int32),
-            jax.ShapeDtypeStruct((n_out,), jnp.int32),
-            jax.ShapeDtypeStruct((n_out,), jnp.int32),
-            jax.ShapeDtypeStruct((m_out,), jnp.int32),
-            jax.ShapeDtypeStruct((m_out,), jnp.int32),
-        ],
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("arbitrary",))
+
+
+def _match_kernel(acol_ref, bcol_ref, arow_ref, brow_ref, dm1_ref, c2_ref):
+    T = arow_ref.shape[-1]
+    arow = arow_ref[0]  # (1, T)
+    brow = brow_ref[0]
+    ones = jnp.ones((8, TC), jnp.bfloat16)
+
+    def body(c, carry):
+        d8, lt8 = carry
+        c0 = pl.multiple_of(c.astype(jnp.int32) * TC, TC)
+        acol = acol_ref[pl.ds(c0, TC), :]  # (TC, 1)
+        bcol = bcol_ref[pl.ds(c0, TC), :]
+        match = (acol == arow) & (bcol == brow)  # (TC, T): lane j vs i
+        j = jax.lax.broadcasted_iota(jnp.int32, (TC, T), 0) + c0
+        i = jax.lax.broadcasted_iota(jnp.int32, (TC, T), 1)
+        m = _bf16_01(match)
+        lt = _bf16_01(match & (j < i))
+        dims = (((1,), (0,)), ((), ()))
+        d8 = d8 + jax.lax.dot_general(
+            ones, m, dims, preferred_element_type=jnp.float32
         )
-        if not interpret
-        else None,
-        interpret=interpret,
-    )(
-        tile_bounds.astype(jnp.int32),
-        offsets.astype(jnp.int32),
-        neighbors.astype(jnp.int32),
-        edge_src.astype(jnp.int32),
-        undirected_id.astype(jnp.int32),
-        w_off.astype(jnp.int32),
-    )
-    vert = jnp.stack([vlo[:n_pad], vhi[:n_pad]], axis=-1)
-    edge = jnp.stack([elo[:m], ehi[:m]], axis=-1)
-    return tot[0], vert, edge
+        lt8 = lt8 + jax.lax.dot_general(
+            ones, lt, dims, preferred_element_type=jnp.float32
+        )
+        return d8, lt8
+
+    zero = jnp.zeros((8, T), jnp.float32)
+    d8, lt8 = jax.lax.fori_loop(0, T // TC, body, (zero, zero))
+    d = d8[0:1, :].astype(jnp.int32)  # rows identical; exact (< 2^24)
+    valid = arow >= 0
+    rep = valid & (lt8[0:1, :].astype(jnp.int32) == 0)
+    dm1_ref[0] = jnp.where(valid, d - 1, 0)
+    c2_ref[0] = jnp.where(rep, (d * (d - 1)) >> 1, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def match_tiles_pallas(ka: jax.Array, kb: jax.Array, interpret: bool = True):
+    """Per-lane ``(dm1, c2)`` for a batch of wedge tiles.
+
+    ``ka``/``kb`` are ``(n_tiles, T)`` int32 endpoint keys (x1, x2) of
+    each tile's lanes, ``(-1, -2)`` on invalid lanes. Returns two
+    ``(n_tiles, T)`` int32 arrays: ``d - 1`` on valid lanes and C(d, 2)
+    on each group's first lane (0 elsewhere).
+    """
+    n_tiles, T = ka.shape
+    if T % TC != 0:
+        raise ValueError(
+            f"tile width must be a multiple of TC={TC}, got {T} — the "
+            "match-panel loop requires it (callers pad the planned tile "
+            "cap up; see core.pipeline)"
+        )
+    if T > MAX_TILE_CAP:
+        raise ValueError(
+            f"tile width {T} exceeds MAX_TILE_CAP {MAX_TILE_CAP} — the "
+            "in-tile counts would lose exactness; plan such vertices "
+            "onto the XLA tiles of engine='fused'"
+        )
+    ka = ka.astype(jnp.int32)
+    kb = kb.astype(jnp.int32)
+    col = pl.BlockSpec((T, 1), lambda t: (t, 0))
+    row = pl.BlockSpec((1, 1, T), lambda t: (t, 0, 0))
+    lanes = jax.ShapeDtypeStruct((n_tiles, 1, T), jnp.int32)
+    with jax.enable_x64(False):  # Mosaic lowers 32-bit index math only
+        dm1, c2 = pl.pallas_call(
+            _match_kernel,
+            grid=(n_tiles,),
+            in_specs=[col, col, row, row],
+            out_specs=[row, row],
+            out_shape=[lanes, lanes],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",)
+            ),
+            interpret=interpret,
+            name="wedge_fused",
+        )(
+            ka.reshape(n_tiles * T, 1),
+            kb.reshape(n_tiles * T, 1),
+            ka.reshape(n_tiles, 1, T),
+            kb.reshape(n_tiles, 1, T),
+        )
+    return dm1.reshape(n_tiles, T), c2.reshape(n_tiles, T)

@@ -4,11 +4,7 @@ A function (not a module constant) so importing this module never
 touches jax device state — the dry-run must set XLA_FLAGS before any
 device initialization.
 
-Version compatibility: explicit Auto ``axis_types`` only exist from
-jax >= 0.5 (``jax.sharding.AxisType``); on older jax every axis is
-implicitly Auto, so the helpers simply omit the kwarg. ``abstract_mesh``
-papers over the ``AbstractMesh`` signature change ((shape, names) vs
-the old tuple-of-(name, size) form) the same way.
+Every mesh is built with explicit Auto ``axis_types``.
 """
 from __future__ import annotations
 
@@ -19,10 +15,7 @@ __all__ = [
     "make_test_mesh",
     "abstract_mesh",
     "available_devices",
-    "HAS_AXIS_TYPE",
 ]
-
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
 
 
 def available_devices() -> int:
@@ -35,9 +28,7 @@ def available_devices() -> int:
 
 
 def _axis_type_kwargs(n_axes: int) -> dict:
-    if HAS_AXIS_TYPE:
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -53,7 +44,4 @@ def make_test_mesh(shape=(1,), axes=("data",)):
 
 def abstract_mesh(shape, axes):
     """Device-free mesh for mesh-shape-only rule resolution."""
-    if HAS_AXIS_TYPE:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
-    # old signature: tuple of (axis_name, axis_size) pairs
-    return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))

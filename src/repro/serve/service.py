@@ -67,11 +67,16 @@ __all__ = [
 
 QUERY_KINDS = ("count", "peel_tips", "peel_tips_stored", "peel_wings")
 
-# service-side engine defaults: the fused engine is the fastest rung
-# that stays fast on a CPU host (fused_pallas runs interpret-mode
-# kernels off-TPU — callers on real accelerators ask for it per query)
-DEFAULT_COUNT_ENGINE = "fused"
 DEFAULT_PEEL_ENGINE = "host"
+
+
+def default_count_engine() -> str:
+    """The service's counting engine when a query names none, and the
+    engine of its resident peel inputs: ``fused_pallas`` on a TPU, where
+    its kernel compiles and nothing in it sorts (the sort-based engines
+    compile for minutes at the chip's auto tile budget); ``fused`` off
+    the TPU, where Pallas kernels run in interpret mode."""
+    return "fused_pallas" if jax.default_backend() == "tpu" else "fused"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -132,7 +137,7 @@ class Query:
                     f"mode must be {'|'.join(_count.MODES)}, "
                     f"got {self.mode!r}"
                 )
-            eng = self.engine or DEFAULT_COUNT_ENGINE
+            eng = self.engine or default_count_engine()
             if eng not in _count.ENGINES:
                 raise ValueError(
                     f"count engine must be {'|'.join(_count.ENGINES)}, "
@@ -158,7 +163,7 @@ class Query:
     def resolved_engine(self) -> str:
         if self.engine is not None:
             return self.engine
-        return (DEFAULT_COUNT_ENGINE if self.kind == "count"
+        return (default_count_engine() if self.kind == "count"
                 else DEFAULT_PEEL_ENGINE)
 
     def cache_key(self) -> tuple:
@@ -451,6 +456,7 @@ class ButterflyService:
                 rec.tip_side = 0 if w_u <= w_v else 1
                 r = _count.count_butterflies(
                     rec.graph, mode="vertex", order=rec.order,
+                    engine=default_count_engine(),
                     count_dtype=_count.default_count_dtype(),
                 )
                 rec.tip_counts = np.asarray(
@@ -460,6 +466,7 @@ class ButterflyService:
                 # forced off-default side: compute on demand, uncached
                 r = _count.count_butterflies(
                     rec.graph, mode="vertex", order=rec.order,
+                    engine=default_count_engine(),
                     count_dtype=_count.default_count_dtype(),
                 )
                 return side, np.asarray(r.per_u if side == 0 else r.per_v)
@@ -470,6 +477,7 @@ class ButterflyService:
             if rec.wing_counts is None:
                 r = _count.count_butterflies(
                     rec.graph, mode="edge", order=rec.order,
+                    engine=default_count_engine(),
                     count_dtype=_count.default_count_dtype(),
                 )
                 rec.wing_counts = np.asarray(r.per_edge)
@@ -501,6 +509,7 @@ class ButterflyService:
                     mode=q.mode,
                     engine=eng,
                     max_chunk=mc,
+                    count_dtype=_count.default_count_dtype(),
                 )
                 return jax.device_get(out)
 

@@ -5,18 +5,9 @@ the rest of the suite (smoke tests must see 1 device)."""
 import json
 import os
 
-import jax
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-# The subprocess payloads build meshes with explicit Auto axis_types;
-# on older jax (< 0.5, no jax.sharding.AxisType) they cannot even
-# import, so skip rather than fail the tier-1 run on container jax.
-requires_axis_type = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jax.sharding.AxisType unavailable in this jax version",
-)
 
 
 def run_sub(code: str, devices: int = 8) -> str:
@@ -29,7 +20,6 @@ def run_sub(code: str, devices: int = 8) -> str:
 
 
 @pytest.mark.slow
-@requires_axis_type
 def test_distributed_count_matches_oracle_8dev():
     code = """
 import numpy as np, jax

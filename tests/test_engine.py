@@ -154,8 +154,8 @@ def test_pallas_choose2_wide_multiplicities_stay_on_kernel():
     """Group multiplicities >= 2^16 used to trip an in-graph fallback
     to the exact count-dtype path; the widened two-limb combine kernel
     now computes them exactly on the kernel (PR 1 follow-up)."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.core.aggregate import Groups
     from repro.core.count import _group_choose2
@@ -171,7 +171,7 @@ def test_pallas_choose2_wide_multiplicities_stay_on_kernel():
             ok=jnp.asarray(True),
         )
 
-    with enable_x64():
+    with jax.enable_x64(True):
         big = 70_000  # C(big, 2) > int32 max -> needs the high limb
         huge = 1 << 20  # C(huge, 2) ~ 2^39
         g = groups_with([big, 3, huge, 9, 0], [True, True, True, False, False])

@@ -6,6 +6,9 @@ wedge_fused kernel vs its jnp oracle, the batch ``mode="all"``
 single-pass, ``max_chunk="auto"``, the distributed fused tile loop,
 and the O(tile)-not-O(W) temp-memory regression via compiled
 ``memory_analysis()``."""
+import dataclasses
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -18,15 +21,24 @@ from repro.core import (
     preprocess,
 )
 from repro.core.count import _count_device, _count_stream_device
+from repro.core.pipeline import (
+    KERNEL_BATCH,
+    execute_count_plan,
+    plan_count,
+    run_fused_pallas_program,
+)
 from repro.core.oracle import global_count, per_edge_counts, per_vertex_counts
 from repro.core.wedges import (
     auto_chunk_budget,
     device_graph,
     host_wedge_counts,
     plan_wedge_chunks,
+    wedges_at,
 )
 from repro.kernels import ops as kops
+from repro.kernels.ops import MAX_TILE_CAP
 from repro.kernels import ref as kref
+from repro.testing import faults
 
 
 def rand_graph(nu, nv, m, seed):
@@ -99,42 +111,81 @@ def test_fused_hash_overflow_falls_back_in_graph():
     assert np.array_equal(np.asarray(be), per_edge_counts(g))
 
 
+def _fused_pallas_plan(rg, direction, budget, mode="all"):
+    return plan_count(
+        rg, mode=mode, direction=direction, budget=budget,
+        engine="fused_pallas", wv_slots=host_wedge_counts(rg, direction),
+    )
+
+
 @pytest.mark.parametrize("direction", ["low", "high"])
 def test_fused_kernel_matches_ref_bitwise(direction):
     """wedge_fused Pallas kernel (interpret on CPU CI) vs its pure-jnp
-    oracle on real multi-tile plans, all modes."""
+    oracle on the key lanes of real multi-tile plans."""
     for seed in range(2):
         g = rand_graph(16, 12, 60, seed)
         rg = preprocess(g, make_order(g, "degree"), order_name="degree")
         dg = device_graph(rg)
+        plan = _fused_pallas_plan(rg, direction, 40)
+        assert plan.strategy_counts()["kernel"] >= 2  # genuinely multi-tile
         cnt = host_wedge_counts(rg, direction)
-        w_off = np.concatenate([[0], np.cumsum(cnt)]).astype(np.int32)
-        bounds, chunk_cap = plan_wedge_chunks(rg, direction, 40)
-        tile_cap = ((chunk_cap + 511) // 512) * 512
-        off = rg.offsets.astype(np.int64)
-        tb = np.stack(
-            [w_off[off[bounds[:-1]]], w_off[off[bounds[1:]]]], axis=1
-        ).astype(np.int32)
-        assert tb.shape[0] >= 2  # the grid is genuinely multi-tile
-        args = (jnp.asarray(tb), dg.offsets, dg.neighbors, dg.edge_src,
-                dg.undirected_id, jnp.asarray(w_off))
-        for mode in ("global", "vertex", "edge", "all"):
-            kw = dict(tile_cap=tile_cap, n_pad=dg.n_pad, m=dg.m,
-                      direction=direction, mode=mode)
-            got = kops.fused_count_tiles(*args, use_pallas=True, **kw)
-            want = kref.fused_count_tiles_ref(*args, **kw)
-            for a, b in zip(got, want):
-                assert np.array_equal(np.asarray(a), np.asarray(b)), (
-                    seed, direction, mode,
-                )
+        w_off = jnp.asarray(np.concatenate([[0], np.cumsum(cnt)]), jnp.int32)
+        tile_cap = dict(plan.capacity)["kernel_tile"]
+        flat = plan.tile_flat_bounds()
+        wid = flat[:, :1] + np.arange(tile_cap)[None, :]
+        valid = wid < flat[:, 1:2]
+        w = wedges_at(dg, None, w_off, jnp.asarray(wid.reshape(-1)),
+                      jnp.asarray(valid.reshape(-1)), direction)
+        ka = jnp.where(w.valid, w.x1, -1).reshape(wid.shape)
+        kb = jnp.where(w.valid, w.x2, -2).reshape(wid.shape)
+        got = kops.match_tiles(ka, kb, use_pallas=True)
+        want = kref.match_tiles_ref(ka, kb)
+        for a, b in zip(got, want):
+            assert np.array_equal(np.asarray(a), np.asarray(b)), (
+                seed, direction,
+            )
+
+
+@pytest.mark.parametrize("direction", ["low", "high"])
+@pytest.mark.parametrize("mode", ["global", "vertex", "edge", "all"])
+def test_fused_pallas_kernel_and_vertex_tiles_match_fused(direction, mode):
+    """The restructured fused_pallas program (interpret-mode kernel)
+    against engine="fused" bitwise, with a forced multi-tile plan that
+    mixes kernel tiles and per-vertex XLA tiles (a kernel cap below the
+    heaviest vertices)."""
+    g = rand_graph(30, 20, 260, 5)
+    rg = preprocess(g, make_order(g, "degree"), order_name="degree")
+    dg = device_graph(rg)
+    wv = host_wedge_counts(rg, direction)
+    with faults.inject("capacity_overflow", site="fused_pallas.plan",
+                       budget=40):
+        plan = _fused_pallas_plan(rg, direction, 200, mode=mode)
+    kinds = plan.strategy_counts()
+    assert kinds["kernel"] >= 2 and kinds["vertex"] >= 2, kinds
+    got = execute_count_plan(dg, plan)
+    want = count_from_ranked(
+        rg, mode=mode, engine="fused", cache_opt=direction == "high",
+        max_chunk=64,
+    )
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert int(wv.sum()) == plan.total_wedges
 
 
 def test_fused_pallas_rejects_oversized_tiles():
-    """A vertex owning more wedges than the kernel's exactness bound
-    must raise (pointing at engine='fused'), not silently truncate."""
-    # near-complete bipartite core: one iterating endpoint owns far
+    """The kernel refuses a tile wider than its exactness bound
+    (pointing at engine='fused'), and the fused_pallas planner never
+    hands it one: a vertex owning more than MAX_TILE_CAP wedges goes to
+    a per-vertex XLA tile of the same program."""
+    with pytest.raises(ValueError, match="fused"):
+        kops.match_tiles(
+            jnp.zeros((1, 2 * MAX_TILE_CAP), jnp.int32),
+            jnp.zeros((1, 2 * MAX_TILE_CAP), jnp.int32),
+            use_pallas=True,
+        )
+    # near-complete bipartite core: every iterating endpoint owns far
     # more than MAX_TILE_CAP wedges
-    rng = np.random.default_rng(0)
     nu, nv = 90, 90
     e = np.stack(
         [np.repeat(np.arange(nu), nv), np.tile(np.arange(nv), nu)], axis=1
@@ -146,12 +197,26 @@ def test_fused_pallas_rejects_oversized_tiles():
     per_vertex = np.zeros(rg.n_pad, np.int64)
     np.add.at(per_vertex, rg.edge_src[:n_real].astype(np.int64),
               wv[:n_real])
-    assert int(per_vertex.max()) > 4096  # the plan floor exceeds the cap
-    with pytest.raises(ValueError, match="fused"):
-        count_from_ranked(rg, mode="global", engine="fused_pallas")
-    # the pure-XLA fused engine handles the same plan fine
-    out = count_from_ranked(rg, mode="global", engine="fused")
+    assert int(per_vertex.max()) > MAX_TILE_CAP
+    plan = _fused_pallas_plan(rg, "low", 1 << 18, "global")
+    assert dict(plan.capacity)["kernel_tile"] <= MAX_TILE_CAP
+    assert plan.strategy_counts().get("vertex", 0) >= 1
+    out = count_from_ranked(rg, mode="global", engine="fused_pallas")
     assert int(out) == global_count(g)
+
+
+def test_fused_pallas_refuses_foreign_tile_kinds():
+    """A plan whose tiles are not kernel/vertex tiles (here: sort tiles
+    relabelled fused_pallas) is refused, never silently counted as
+    zero."""
+    g = rand_graph(16, 12, 60, 0)
+    rg = preprocess(g, make_order(g, "degree"), order_name="degree")
+    plan = plan_count(rg, mode="global", budget=40, engine="fused")
+    assert set(plan.tile_aggregation) == {"sort"}
+    with pytest.raises(ValueError, match="kernel and vertex tiles only"):
+        execute_count_plan(
+            device_graph(rg), dataclasses.replace(plan, engine="fused_pallas")
+        )
 
 
 @pytest.mark.parametrize("agg", ["batch", "batch_wa"])
@@ -173,16 +238,14 @@ def test_batch_mode_all_equals_single_modes(agg):
 
 
 def test_fused_pallas_wide_dtype_exact_no_warning():
-    """The kernel's per-vertex/per-edge accumulators are two-limb int32
-    pairs (like the combine kernel), so a 64-bit count_dtype is exact
-    end to end — the old int32-downgrade warning is gone."""
+    """The fused_pallas program accumulates in the count dtype, so a
+    64-bit count_dtype is exact end to end, with no int32-downgrade
+    warning."""
     import warnings as _warnings
-
-    from jax.experimental import enable_x64
 
     g = rand_graph(10, 8, 25, 2)
     rg = preprocess(g, make_order(g, "degree"), order_name="degree")
-    with enable_x64():
+    with jax.enable_x64(True):
         with _warnings.catch_warnings():
             _warnings.simplefilter("error")
             out = count_from_ranked(
@@ -197,40 +260,35 @@ def test_fused_pallas_wide_dtype_exact_no_warning():
 
 
 def test_fused_pallas_limb_accumulation_across_tiles():
-    """Per-vertex/per-edge limb pairs accumulate with carry across grid
-    steps: re-running the same tile R times multiplies every count by R
-    exactly (tile_bounds rows are independent accumulation steps), and
-    the kernel stays bitwise-equal to the jnp oracle."""
+    """Counts accumulate exactly across kernel tiles and launches:
+    re-running the same whole-graph tile R times (one per kernel batch
+    lane, across several batches) multiplies every count by R exactly,
+    in int64 under x64."""
     g = rand_graph(16, 12, 60, 4)
     rg = preprocess(g, make_order(g, "degree"), order_name="degree")
-    dg = device_graph(rg)
-    cnt = host_wedge_counts(rg, "low")
-    w_off = np.concatenate([[0], np.cumsum(cnt)]).astype(np.int32)
-    w_total = int(cnt.sum())
-    tile_cap = ((w_total + 511) // 512) * 512
-    R = 5
-    tb = np.repeat([[0, w_total]], R, axis=0).astype(np.int32)
-    args = (jnp.asarray(tb), dg.offsets, dg.neighbors, dg.edge_src,
-            dg.undirected_id, jnp.asarray(w_off))
-    kw = dict(tile_cap=tile_cap, n_pad=dg.n_pad, m=dg.m,
-              direction="low", mode="all")
-    got = kops.fused_count_tiles(*args, use_pallas=True, **kw)
-    want = kref.fused_count_tiles_ref(*args, **kw)
-    for a, b in zip(got, want):
-        assert np.array_equal(np.asarray(a), np.asarray(b))
-    _, vert, edge = got
-    vert = np.asarray(vert)
-    edge = np.asarray(edge)
-    v64 = vert[:, 0].astype(np.uint32).astype(np.int64) + (
-        vert[:, 1].astype(np.int64) << 32
-    )
-    e64 = edge[:, 0].astype(np.uint32).astype(np.int64) + (
-        edge[:, 1].astype(np.int64) << 32
-    )
+    w_total = int(host_wedge_counts(rg, "low").sum())
+    R = KERNEL_BATCH + 3  # two kernel batches, the second one ragged
+    with jax.enable_x64(True):
+        dg = device_graph(rg)
+        tiles = np.zeros((2 * KERNEL_BATCH, 2), np.int32)
+        tiles[:R] = (0, w_total)
+        out = run_fused_pallas_program(
+            dg,
+            jnp.asarray(tiles.reshape(2, KERNEL_BATCH, 2)),
+            (),
+            tile_cap=((w_total + 127) // 128) * 128,
+            vertex_caps=(),
+            mode="all",
+            direction="low",
+            dtype=jnp.int64,
+        )
+    total, vert, edge = (np.asarray(x) for x in out)
+    assert vert.dtype == np.int64
     pu, pv = per_vertex_counts(g)
-    assert np.array_equal(v64[rg.rank_of_u], R * pu)
-    assert np.array_equal(v64[rg.rank_of_v], R * pv)
-    assert np.array_equal(e64, R * per_edge_counts(g))
+    assert int(total) == R * global_count(g)
+    assert np.array_equal(vert[rg.rank_of_u], R * pu)
+    assert np.array_equal(vert[rg.rank_of_v], R * pv)
+    assert np.array_equal(edge, R * per_edge_counts(g))
 
 
 def test_auto_chunk_budget():

@@ -151,8 +151,8 @@ def test_bucket_update_sweep(n, k, seed):
 
 
 def test_bucket_update_rejects_oversized_batch():
-    """Batches beyond the f32 limb exactness bound must raise (callers
-    route to the jnp reference via ops.bucket_update)."""
+    """Batches beyond the f32 limb exactness bound must raise; the ops
+    dispatcher feeds them through the kernel in chunks instead."""
     from repro.kernels import ops
 
     n = 64
@@ -163,10 +163,34 @@ def test_bucket_update_rejects_oversized_batch():
     dec = jnp.ones((k,), jnp.int32)
     with pytest.raises(ValueError, match="MAX_UPDATE_CAP"):
         bucket_update_pallas(c, alive, idx, dec)
-    # the ops dispatcher transparently serves the reference instead
+    # the ops dispatcher chunks the batch through the kernel
     new, mn, hist = ops.bucket_update(c, alive, idx, dec, use_pallas=True)
     assert int(np.asarray(new)[0]) == -k
     assert int(mn) == -k
+
+
+def test_bucket_update_chunks_wide_counts_through_kernel():
+    """The ops dispatcher runs the kernel on any count dtype (values
+    below INT32_MAX) and on batches beyond MAX_UPDATE_CAP, chunk by
+    chunk: bitwise-equal to the jnp reference, result in the caller's
+    dtype."""
+    import jax
+
+    from repro.kernels import ops
+
+    rng = np.random.default_rng(3)
+    n, k = 700, 2 * MAX_UPDATE_CAP + 5
+    with jax.enable_x64(True):
+        c = jnp.asarray(rng.integers(0, 1 << 30, n), jnp.int64)
+        alive = jnp.asarray(rng.random(n) < 0.7)
+        idx = jnp.asarray(rng.integers(0, n + 1, k), jnp.int32)
+        dec = jnp.where(idx == n, 0, jnp.asarray(rng.integers(0, 1 << 12, k),
+                                                 jnp.int32))
+        got = ops.bucket_update(c, alive, idx, dec, use_pallas=True)
+        want = ref.bucket_update_ref(c, alive, idx, dec)
+        assert got[0].dtype == jnp.int64
+        for a, b in zip(got, want):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_bucket_min_all_dead():

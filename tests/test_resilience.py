@@ -32,6 +32,7 @@ from repro.core import (
     preprocess,
 )
 from repro.core.distributed import launch_device_worker
+from repro.core.oracle import global_count
 from repro.core.peel import peel_tips, peel_tips_stored, peel_wings
 from repro.core.resilience import (
     ExecutionReport,
@@ -160,7 +161,7 @@ def test_chaos_poisoned_tile_demotes_with_parity(name):
     """A sentinel-poisoned buffer violates the result invariants: the
     validator demotes the rung and the clean rung restores parity."""
     run, dev_site, _all = WORKLOADS[name]
-    poison_site = "ops.fused_count_tiles" if name == "count" else dev_site
+    poison_site = "count.fused_pallas" if name == "count" else dev_site
     clean, _ = run(GRAPH)
     with faults.inject("poison", site=poison_site):
         got, report = run(GRAPH)
@@ -214,7 +215,6 @@ def test_device_loss_targets_only_the_faulted_device():
 def test_device_loss_transient_retry_recovers_parity():
     """times=1 kills only the first attempt; the retry reruns the full
     jax worker and the counted total matches the in-process oracle."""
-    from repro.core.oracle import global_count
 
     code = (
         "import numpy as np\n"
@@ -335,7 +335,10 @@ def test_csr_roundtrip_and_duplicate_policies():
 def test_accumulator_preflight():
     g = rand_graph(40, 30, 400, 2)
     bound = g.accumulator_preflight()  # default 2^63 budget: fine
-    assert bound >= 0
+    assert global_count(g) <= bound
+    # the bound W (d2 - 1) / 2 of the tighter side, not C(W, 2)
+    w = min(g.wedge_totals())
+    assert bound < w * (w - 1) // 2
     with pytest.raises(AccumulatorOverflowRisk):
         g.accumulator_preflight(budget_bits=4)
     with pytest.raises(OverflowError):  # taxonomy compat

@@ -21,10 +21,11 @@ import os
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
-from repro.core import count_butterflies
+from repro.core import BipartiteGraph, count_butterflies
 from repro.core.approx import ApproxCount
 from repro.core.peel import peel_tips, peel_tips_stored, peel_wings
 from repro.core import resilience as res
@@ -39,6 +40,7 @@ from repro.serve import (
     Query,
     ResultCache,
 )
+from repro.serve.service import default_count_engine
 from repro.testing import faults
 
 RUN_FAULTS = os.environ.get("REPRO_FAULTS") == "1"
@@ -323,6 +325,37 @@ def test_count_query_parity_all_modes(svc):
             assert np.array_equal(r.result.per_edge, ref.per_edge)
         assert r.service.cache == "miss"
         assert r.execution.final_rung == "fused"
+
+
+def test_count_query_beyond_int32_is_exact():
+    """Under x64 the service counts in int64: the complete bipartite
+    graph K(2, 70000) has C(70000, 2) butterflies, beyond int32. (x64
+    is set globally: ``jax.enable_x64`` is thread-local, and the
+    service counts on its worker threads.)"""
+    b = 70_000
+    g = BipartiteGraph(2, b, np.stack(
+        [np.repeat(np.arange(2), b), np.tile(np.arange(b), 2)], axis=1))
+    x64 = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", True)
+    try:
+        with ButterflyService(workers=1) as service:
+            service.register("wide", g)
+            r = service.query(Query(graph="wide"))
+    finally:
+        jax.config.update("jax_enable_x64", x64)
+    assert int(r.result.total) == b * (b - 1) // 2
+    assert int(r.result.total) > np.iinfo(np.int32).max
+
+
+def test_default_count_engine_follows_backend(monkeypatch):
+    """fused off the TPU (interpret-mode kernels are slow); fused_pallas
+    on it, where the sort-based engines compile for minutes."""
+    assert default_count_engine() == "fused"
+    assert Query(graph="g").resolved_engine() == "fused"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert default_count_engine() == "fused_pallas"
+    assert Query(graph="g").resolved_engine() == "fused_pallas"
+    assert Query(graph="g", kind="peel_tips").resolved_engine() == "host"
 
 
 def test_peel_query_parity_all_kinds(svc):
