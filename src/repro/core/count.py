@@ -368,6 +368,7 @@ def _resolve_chunk_budget(max_chunk) -> Optional[int]:
     return int(max_chunk)
 
 
+@_pipeline.traced("plan")
 def _plan_from_knobs(
     rg: RankedGraph,
     *,
@@ -456,7 +457,8 @@ def count_from_ranked(
         # to sort — bitwise-identical, both strategies are exact
         aggregation = "sort"
     dg = device_graph(rg)
-    wv_slots = host_wedge_counts(rg, direction)
+    with _pipeline.span("plan"):
+        wv_slots = host_wedge_counts(rg, direction)
     if aggregation in ("batch", "batch_wa"):
         if engine != "xla":
             raise ValueError(
@@ -464,14 +466,16 @@ def count_from_ranked(
                 "not route through the Pallas or fused engines; use "
                 "engine='xla'"
             )
-        # per-vertex wedge counts (by iterating endpoint)
-        src = rg.edge_src[: 2 * rg.m]
-        wv = np.zeros(rg.n_pad, dtype=np.int64)
-        np.add.at(wv, src, wv_slots[: 2 * rg.m])
-        bounds, chunk = _batch_bounds(
-            wv, rg.n_pad, aggregation == "batch_wa", batch_rows, batch_target
-        )
-        chunk_cap = max(128, ((chunk + 127) // 128) * 128)
+        with _pipeline.span("plan"):
+            # per-vertex wedge counts (by iterating endpoint)
+            src = rg.edge_src[: 2 * rg.m]
+            wv = np.zeros(rg.n_pad, dtype=np.int64)
+            np.add.at(wv, src, wv_slots[: 2 * rg.m])
+            bounds, chunk = _batch_bounds(
+                wv, rg.n_pad, aggregation == "batch_wa", batch_rows,
+                batch_target,
+            )
+            chunk_cap = max(128, ((chunk + 127) // 128) * 128)
         out = _pipeline.launch(
             _count_batch_device,
             dg,
@@ -601,6 +605,7 @@ def interpret_counts(
     )
 
 
+@_pipeline.traced("count_butterflies")
 def count_butterflies(
     g: BipartiteGraph,
     *,
@@ -663,7 +668,7 @@ def count_butterflies(
                 engine=eng,
                 max_chunk=mc,
             )
-            return jax.device_get(out)
+            return _pipeline.fetch(out)
 
         return _res.Rung(eng, run)
 

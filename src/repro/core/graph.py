@@ -20,6 +20,7 @@ from typing import Optional
 import numpy as np
 
 from .resilience import AccumulatorOverflowRisk, GraphValidationError
+from .tap import traced
 
 __all__ = [
     "BipartiteGraph",
@@ -256,6 +257,7 @@ class RankedGraph:
         return (self.offsets[1:] - self.offsets[:-1]).astype(np.int32)
 
 
+@traced("preprocess")
 def preprocess(
     g: BipartiteGraph,
     order: np.ndarray,

@@ -182,12 +182,16 @@ from .pipeline import (
     device_round_loop as _device_round_loop,
     drive_segments as _drive_segments,
     empty_hist as _empty_hist,
+    fetch as _fetch,
     init_loop_state as _init_state,
     launch as _launch,
     masked_state as _masked_state,
     prefix_offsets as _prefix,
+    scope as _scope,
+    span as _span,
     stream_tiles as _stream_tiles,
     tile_apply as _fused_tile_apply,
+    traced as _traced,
 )
 from .wedges import (
     Wedges,
@@ -260,6 +264,7 @@ def _pow2_pad(x: int, floor: int = 128) -> int:
     return c
 
 
+@_traced("preprocess")
 def _csr(g: BipartiteGraph):
     """Global-id CSR (U ids then V ids), neighbors ascending."""
     n = g.n
@@ -309,6 +314,7 @@ def _stored_wedge_csr(g: BipartiteGraph, side: int):
     return woff, w_u2
 
 
+@_traced("plan")
 def _level2_totals(off: np.ndarray, nbr: np.ndarray, base: int,
                    n_side: int) -> np.ndarray:
     """Per-vertex 2-hop expansion totals: w2[u] = Σ_{v in N(u)} deg(v).
@@ -326,6 +332,7 @@ def _level2_totals(off: np.ndarray, nbr: np.ndarray, base: int,
     return w2
 
 
+@_scope("subtract")
 def _subtract_tile(
     u1: jax.Array,
     u2: jax.Array,
@@ -489,9 +496,10 @@ def _peel_tips_device(
 
     def _tiles(b, alive, roff, recover):
         def tile_fn(bt, wid, tvalid):
-            u1, u2 = recover(wid)
-            u2c = jnp.clip(u2, 0, n_side - 1)
-            tv = tvalid & (u2 >= 0) & (u2 < n_side) & alive[u2c]
+            with _scope("recover"):
+                u1, u2 = recover(wid)
+                u2c = jnp.clip(u2, 0, n_side - 1)
+                tv = tvalid & (u2 >= 0) & (u2 < n_side) & alive[u2c]
             return _subtract_tile(
                 u1.astype(jnp.int32), u2c.astype(jnp.int32), tv, bt,
                 alive, aggregation=aggregation, n_side=n_side,
@@ -504,6 +512,7 @@ def _peel_tips_device(
             decrease_key=decrease_key, want_hist=want_hist,
         )
 
+    @_scope("recover")
     def expand(args):
         b, alive, _alive_prev, peel = args
         if stored:
@@ -624,49 +633,53 @@ def _peel_tips_device_run(
         note.append("device engine unavailable: empty side or counts "
                     "beyond int32")
         return None
-    budget = _I32_MAX if max_frontier is None else int(max_frontier)
-    tb = _DEFAULT_TILE_TARGET if tile_budget is None else int(tile_budget)
-    if budget_shrinks:
-        budget = max(128, budget >> budget_shrinks)
-        tb = max(1, tb >> budget_shrinks)
-    if stored:
-        woff, w_u2 = csr
-        w_total = int(woff[-1])
-        if w_total >= _I32_MAX:
-            note.append("device engine unavailable: stored wedge total "
-                        "beyond int32 indexing")
-            return None
-        rows = np.diff(woff)
-        work1 = np.zeros(n_side, np.int32)
-        work2 = rows.astype(np.int32)
-        lvl1, lvl2 = 0, w_total
-        max_row = int(rows.max(initial=0))
-        cap1 = 128  # unused by the stored loop
-        cap2 = _pow2_pad(min(w_total, budget))
-        off_d = jnp.asarray(woff, jnp.int32)
-        nbr_d = jnp.asarray(w_u2 if w_total else np.zeros(1), jnp.int32)
-    else:
-        off, nbr = csr
-        deg = np.diff(off)
-        lvl1 = int(deg[base : base + n_side].sum())  # == m
-        if w2 is None:
-            w2 = _level2_totals(off, nbr, base, n_side)
-        lvl2 = int(w2.sum())
-        if lvl2 >= _I32_MAX or 2 * g.m >= _I32_MAX:
-            note.append("device engine unavailable: expansion totals "
-                        "beyond int32 indexing")
-            return None
-        work1 = deg[base : base + n_side].astype(np.int32)
-        work2 = w2.astype(np.int32)
-        max_row = int(w2.max(initial=0))
-        cap1 = _pow2_pad(min(lvl1, budget))
-        cap2 = _pow2_pad(min(lvl2, budget))
-        off_d = jnp.asarray(off, jnp.int32)
-        nbr_d = jnp.asarray(nbr if nbr.size else np.zeros(1), jnp.int32)
-    # fused tiles must fit the largest single-vertex expansion (the
-    # alignment floor, like plan_wedge_chunks' single-vertex chunks);
-    # the 2x headroom keeps greedy tiles at least half full
-    tile_cap = _pow2_pad(max(min(tb, max(lvl2, 1)), 2 * max_row))
+    with _span("plan"):  # buffer capacities and the tile shape
+        budget = _I32_MAX if max_frontier is None else int(max_frontier)
+        tb = (_DEFAULT_TILE_TARGET if tile_budget is None
+              else int(tile_budget))
+        if budget_shrinks:
+            budget = max(128, budget >> budget_shrinks)
+            tb = max(1, tb >> budget_shrinks)
+        if stored:
+            woff, w_u2 = csr
+            w_total = int(woff[-1])
+            if w_total >= _I32_MAX:
+                note.append("device engine unavailable: stored wedge total "
+                            "beyond int32 indexing")
+                return None
+            rows = np.diff(woff)
+            work1 = np.zeros(n_side, np.int32)
+            work2 = rows.astype(np.int32)
+            lvl1, lvl2 = 0, w_total
+            max_row = int(rows.max(initial=0))
+            cap1 = 128  # unused by the stored loop
+            cap2 = _pow2_pad(min(w_total, budget))
+            off_d = jnp.asarray(woff, jnp.int32)
+            nbr_d = jnp.asarray(w_u2 if w_total else np.zeros(1),
+                                jnp.int32)
+        else:
+            off, nbr = csr
+            deg = np.diff(off)
+            lvl1 = int(deg[base : base + n_side].sum())  # == m
+            if w2 is None:
+                w2 = _level2_totals(off, nbr, base, n_side)
+            lvl2 = int(w2.sum())
+            if lvl2 >= _I32_MAX or 2 * g.m >= _I32_MAX:
+                note.append("device engine unavailable: expansion totals "
+                            "beyond int32 indexing")
+                return None
+            work1 = deg[base : base + n_side].astype(np.int32)
+            work2 = w2.astype(np.int32)
+            max_row = int(w2.max(initial=0))
+            cap1 = _pow2_pad(min(lvl1, budget))
+            cap2 = _pow2_pad(min(lvl2, budget))
+            off_d = jnp.asarray(off, jnp.int32)
+            nbr_d = jnp.asarray(nbr if nbr.size else np.zeros(1),
+                                jnp.int32)
+        # fused tiles must fit the largest single-vertex expansion (the
+        # alignment floor, like plan_wedge_chunks' single-vertex chunks);
+        # the 2x headroom keeps greedy tiles at least half full
+        tile_cap = _pow2_pad(max(min(tb, max(lvl2, 1)), 2 * max_row))
     b0 = jnp.asarray(counts)
     # counts below INT32_MAX (guarded above) run the int32 kernel in any
     # count dtype; off the compiled backend the reference serves
@@ -1011,7 +1024,7 @@ def _peel_tips_host(g, counts, side, aggregation, hash_bits, subtract,
     kappa = 0
     acct = _RoundAccounting(peel_mode)
     while alive.any():
-        cnt_host = np.asarray(jax.device_get(b_dev))
+        cnt_host = np.asarray(_fetch(b_dev))
         cur = np.where(alive, cnt_host, np.iinfo(cnt_host.dtype).max)
         mn = int(cur.min())
         kappa = max(kappa, mn)
@@ -1043,6 +1056,7 @@ def _peel_tips_host(g, counts, side, aggregation, hash_bits, subtract,
                       sub_rounds=acct.sub_rounds)
 
 
+@_traced("peel_tips")
 def peel_tips(
     g: BipartiteGraph,
     counts: Optional[np.ndarray] = None,
@@ -1204,6 +1218,7 @@ def peel_tips(
     return policy.attach(out, report)
 
 
+@_traced("peel_tips_stored")
 def peel_tips_stored(
     g: BipartiteGraph,
     counts: Optional[np.ndarray] = None,
@@ -1342,7 +1357,7 @@ def _peel_tips_stored_host(counts, side, n_side, aggregation, hash_bits,
     kappa = 0
     acct = _RoundAccounting(peel_mode)
     while alive.any():
-        cnt_host = np.asarray(jax.device_get(b_dev))
+        cnt_host = np.asarray(_fetch(b_dev))
         cur = np.where(alive, cnt_host, np.iinfo(cnt_host.dtype).max)
         mn = int(cur.min())
         kappa = max(kappa, mn)
@@ -1374,6 +1389,7 @@ def _peel_tips_stored_host(counts, side, n_side, aggregation, hash_bits,
 # ---------------------------------------------------------------------------
 
 
+@_scope("subtract")
 def _subtract_edge_groups(
     tgt3: jax.Array,
     valid3: jax.Array,
@@ -1483,6 +1499,7 @@ def _peel_wings_device(
     deg = off[1:] - off[:-1]
     want_hist = peel_mode == "range" and decrease_key == "bucket"
 
+    @_scope("recover")
     def expand(args):
         b, alive, alive_prev, peel = args
 
@@ -1771,6 +1788,7 @@ def _peel_wings_device_run(
     )
 
 
+@_traced("peel_wings")
 def peel_wings(
     g: BipartiteGraph,
     counts: Optional[np.ndarray] = None,
@@ -1942,11 +1960,11 @@ def _peel_wings_host(g, counts, off, nbr, uid, peel_mode) -> PeelResult:
             mn_dev = _kops.bucket_min(
                 b_dev, jnp.asarray(alive), use_pallas=pallas_min
             )
-            mn_np, cnt_host = jax.device_get((mn_dev, b_dev))
+            mn_np, cnt_host = _fetch((mn_dev, b_dev))
             cnt_host = np.asarray(cnt_host)
             mn = int(mn_np)
         else:
-            cnt_host = np.asarray(jax.device_get(b_dev))
+            cnt_host = np.asarray(_fetch(b_dev))
             mn = int(
                 np.where(alive, cnt_host, np.iinfo(cnt_host.dtype).max).min()
             )

@@ -63,7 +63,6 @@ forced-hash runs.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 import json
@@ -78,6 +77,15 @@ from ..testing import faults as _faults
 from . import resilience as _res
 from .aggregate import Groups, aggregate_dense, aggregate_hash, aggregate_sort
 from .graph import RankedGraph
+from .tap import (
+    DEVICE_SCOPES,
+    fetch,
+    launch,
+    record_programs,
+    scope,
+    span,
+    traced,
+)
 from .wedges import (
     DeviceGraph,
     Wedges,
@@ -117,6 +125,12 @@ __all__ = [
     "run_fused_pallas_tiles",
     "plan_strategies",
     "execute_count_plan",
+    # the tap (core/tap.py): spans, syncs, device scopes, programs
+    "DEVICE_SCOPES",
+    "scope",
+    "span",
+    "traced",
+    "fetch",
     "record_programs",
     "launch",
     # execute: peeling substrate
@@ -363,6 +377,7 @@ def _kernel_split_bounds(wv: np.ndarray, kernel_cap: int, target: int):
     return np.asarray(bounds, np.int64), np.asarray(is_kernel, bool)
 
 
+@traced("plan")
 def plan_count(
     rg: RankedGraph,
     *,
@@ -512,6 +527,7 @@ def peel_tile_bounds(
     return bounds, tile_wedges
 
 
+@traced("plan")
 def plan_peel(
     kind: str,
     *,
@@ -995,11 +1011,14 @@ def run_fused_pallas_program(
     capacity class of vertex tiles (vertices owning more wedges than
     the kernel tile holds, :func:`vertex_tile_lanes`). Live memory is
     O(KERNEL_BATCH x tile_cap + max(vertex_caps) + n_pad); nothing of
-    size W is ever built."""
-    cnt = slot_wedge_counts(dg, direction)
-    w_off = wedge_offsets(cnt)
-    acc = zero_counts(dg, mode, dtype)
+    size W is ever built. Its phases are named ``offsets``, ``recover``,
+    ``match`` and ``accumulate`` (:data:`DEVICE_SCOPES`)."""
+    with scope("offsets"):
+        cnt = slot_wedge_counts(dg, direction)
+        w_off = wedge_offsets(cnt)
+        acc = zero_counts(dg, mode, dtype)
 
+    @scope("recover")
     def recover(tb, cap):
         lanes = tb[:, :1] + jnp.arange(cap, dtype=jnp.int32)[None, :]
         valid = lanes < tb[:, 1:2]
@@ -1010,15 +1029,17 @@ def run_fused_pallas_program(
     def kernel_batch(i, acc):
         w = recover(kernel_tiles[i], tile_cap)
         shape = (kernel_tiles.shape[1], tile_cap)
-        dm1, c2 = _kops.match_tiles(
-            jnp.where(w.valid, w.x1, -1).reshape(shape),
-            jnp.where(w.valid, w.x2, -2).reshape(shape),
-            use_pallas=True,
-        )
-        return lane_counts(
-            dg, w, dm1.reshape(-1).astype(dtype),
-            c2.reshape(-1).astype(dtype), mode, acc,
-        )
+        with scope("match"):
+            dm1, c2 = _kops.match_tiles(
+                jnp.where(w.valid, w.x1, -1).reshape(shape),
+                jnp.where(w.valid, w.x2, -2).reshape(shape),
+                use_pallas=True,
+            )
+        with scope("accumulate"):
+            return lane_counts(
+                dg, w, dm1.reshape(-1).astype(dtype),
+                c2.reshape(-1).astype(dtype), mode, acc,
+            )
 
     if kernel_tiles.shape[0]:
         acc = jax.lax.fori_loop(0, kernel_tiles.shape[0], kernel_batch, acc)
@@ -1026,8 +1047,10 @@ def run_fused_pallas_program(
 
         def vertex_tile(i, acc, tiles=tiles, cap=cap):
             w = recover(tiles[i][None, :], cap)
-            dm1, c2 = vertex_tile_lanes(w, dg.n_pad, direction, dtype)
-            return lane_counts(dg, w, dm1, c2, mode, acc)
+            with scope("match"):
+                dm1, c2 = vertex_tile_lanes(w, dg.n_pad, direction, dtype)
+            with scope("accumulate"):
+                return lane_counts(dg, w, dm1, c2, mode, acc)
 
         acc = jax.lax.fori_loop(0, tiles.shape[0], vertex_tile, acc)
     return acc
@@ -1054,16 +1077,17 @@ def run_fused_pallas_tiles(dg: DeviceGraph, plan: WedgePlan):
             f"engine='fused_pallas' runs kernel and vertex tiles only, "
             f"got {sorted(other)}"
         )
-    flat = plan.tile_flat_bounds()
-    ktiles = flat[kinds == "kernel"]
-    pad = -len(ktiles) % KERNEL_BATCH
-    ktiles = np.concatenate([ktiles, np.zeros((pad, 2), np.int64)])
-    vtiles = flat[kinds == "vertex"]
-    classes = np.asarray(
-        [_pow2_at_least(int(s)) for s in vtiles[:, 1] - vtiles[:, 0]],
-        np.int64,
-    )
-    caps = tuple(int(c) for c in np.unique(classes))
+    with span("plan"):
+        flat = plan.tile_flat_bounds()
+        ktiles = flat[kinds == "kernel"]
+        pad = -len(ktiles) % KERNEL_BATCH
+        ktiles = np.concatenate([ktiles, np.zeros((pad, 2), np.int64)])
+        vtiles = flat[kinds == "vertex"]
+        classes = np.asarray(
+            [_pow2_at_least(int(s)) for s in vtiles[:, 1] - vtiles[:, 0]],
+            np.int64,
+        )
+        caps = tuple(int(c) for c in np.unique(classes))
     out = launch(
         run_fused_pallas_program,
         dg,
@@ -1129,37 +1153,6 @@ def execute_count_plan(dg: DeviceGraph, plan: WedgePlan):
 
 
 # ---------------------------------------------------------------------------
-# Execute layer: the device-program tap
-# ---------------------------------------------------------------------------
-
-_RECORDED: Optional[list] = None
-
-
-@contextlib.contextmanager
-def record_programs():
-    """Record every device program launched through :func:`launch`
-    while the block runs, as ``(program, args, kwargs)`` triples — so a
-    caller can lower and compile exactly what an entry point ran
-    (``program.lower(*args, **kwargs).compile().as_text()``) and see
-    which kernels it holds. Recording keeps the arguments alive until
-    the list is dropped."""
-    global _RECORDED
-    prev, _RECORDED = _RECORDED, []
-    try:
-        yield _RECORDED
-    finally:
-        _RECORDED = prev
-
-
-def launch(program, *args, **kwargs):
-    """Run a jitted device program, recording it when
-    :func:`record_programs` is active."""
-    if _RECORDED is not None:
-        _RECORDED.append((program, args, kwargs))
-    return program(*args, **kwargs)
-
-
-# ---------------------------------------------------------------------------
 # Execute layer: the peeling round-loop substrate
 # ---------------------------------------------------------------------------
 
@@ -1221,9 +1214,10 @@ def apply_decrements(b, alive, tgt, dec, decrease_key, use_kernel,
     ``want_hist`` — see :func:`empty_hist`).
     """
     if decrease_key == "bucket":
-        nb, mn, hist = _kops.bucket_update(
-            b, alive, tgt, dec, use_pallas=use_kernel
-        )
+        with scope("bucket_update"):
+            nb, mn, hist = _kops.bucket_update(
+                b, alive, tgt, dec, use_pallas=use_kernel
+            )
         if not want_hist:
             # discarded before it reaches the loop carry -> XLA DCEs
             # the reference path's histogram under exact mode (measured:
@@ -1275,16 +1269,18 @@ def stream_tiles(b, alive, roff, tile_fn, *, tile_cap: int, aligned: bool,
     """
     total = roff[-1]
 
+    @scope("recover")
     def tcond(c):
         return c[1] < total
 
     def tbody(c):
         bt, ts, _mn, _h = c
-        if aligned:
-            te = aligned_tile_end(roff, ts, tile_cap)
-        else:
-            te = jnp.minimum(ts + jnp.int32(tile_cap), total)
-        wid = ts + jnp.arange(tile_cap, dtype=jnp.int32)
+        with scope("recover"):
+            if aligned:
+                te = aligned_tile_end(roff, ts, tile_cap)
+            else:
+                te = jnp.minimum(ts + jnp.int32(tile_cap), total)
+            wid = ts + jnp.arange(tile_cap, dtype=jnp.int32)
         out_b, mn, h = tile_fn(bt, wid, wid < te)
         return out_b, te, mn, h
 
@@ -1294,12 +1290,13 @@ def stream_tiles(b, alive, roff, tile_fn, *, tile_cap: int, aligned: bool,
     )
     if decrease_key == "bucket":
         # zero-tile rounds still need the post-peel carried state
-        mn, hist = jax.lax.cond(
-            total > 0,
-            lambda _: (mn, hist),
-            lambda _: masked_state(b, alive, want_hist),
-            None,
-        )
+        with scope("select"):
+            mn, hist = jax.lax.cond(
+                total > 0,
+                lambda _: (mn, hist),
+                lambda _: masked_state(b, alive, want_hist),
+                None,
+            )
     return b, mn, hist
 
 
@@ -1330,6 +1327,7 @@ def device_round_loop(state: LoopState, expand, work1, work2, *,
     dtype = state.b.dtype
     want_hist = peel_mode == "range" and decrease_key == "bucket"
 
+    @scope("select")
     def cond(st):
         go = jnp.any(st.alive) & ~st.overflow
         if adaptive:
@@ -1342,37 +1340,41 @@ def device_round_loop(state: LoopState, expand, work1, work2, *,
         return go
 
     def body(st):
-        if decrease_key == "bucket":
-            mn = st.mn
-        else:
-            mn = _kops.bucket_min(st.b, st.alive, use_pallas=True)
-        kappa = jnp.maximum(st.kappa, mn)
-        rounds, hi = st.rounds, st.hi
-        if peel_mode == "range":
-            new_bucket = mn >= hi
-            k_sel = (
-                _kops.lowest_nonempty_bucket(st.hist)
-                if want_hist
-                else _kops.bit_length(mn)
-            )
-            hi = jnp.where(new_bucket, _kops.bucket_upper_bound(k_sel), hi)
-            rounds = rounds + new_bucket.astype(jnp.int32)
-        else:
-            rounds = rounds + 1
-        subr = st.subr + 1
-        peel = st.alive & (st.b <= kappa.astype(dtype))
-        out = jnp.where(peel, kappa.astype(dtype), st.out)
-        alive_prev = st.alive
-        alive = st.alive & ~peel
-        # explicit dtype: under x64 jnp.sum promotes to int64 and the
-        # scatter into the int32 sizes buffer would downcast-warn
-        sizes = st.sizes.at[rounds - 1].add(jnp.sum(peel, dtype=jnp.int32))
-        rem1, rem2 = st.rem1, st.rem2
-        if adaptive:
-            rem1 = rem1 - jnp.sum(jnp.where(peel, work1, 0),
-                                  dtype=jnp.int32)
-            rem2 = rem2 - jnp.sum(jnp.where(peel, work2, 0),
-                                  dtype=jnp.int32)
+        with scope("select"):  # extract-min, κ, accounting, peel set
+            if decrease_key == "bucket":
+                mn = st.mn
+            else:
+                mn = _kops.bucket_min(st.b, st.alive, use_pallas=True)
+            kappa = jnp.maximum(st.kappa, mn)
+            rounds, hi = st.rounds, st.hi
+            if peel_mode == "range":
+                new_bucket = mn >= hi
+                k_sel = (
+                    _kops.lowest_nonempty_bucket(st.hist)
+                    if want_hist
+                    else _kops.bit_length(mn)
+                )
+                hi = jnp.where(new_bucket, _kops.bucket_upper_bound(k_sel),
+                               hi)
+                rounds = rounds + new_bucket.astype(jnp.int32)
+            else:
+                rounds = rounds + 1
+            subr = st.subr + 1
+            peel = st.alive & (st.b <= kappa.astype(dtype))
+            out = jnp.where(peel, kappa.astype(dtype), st.out)
+            alive_prev = st.alive
+            alive = st.alive & ~peel
+            # explicit dtype: under x64 jnp.sum promotes to int64 and the
+            # scatter into the int32 sizes buffer would downcast-warn
+            sizes = st.sizes.at[rounds - 1].add(
+                jnp.sum(peel, dtype=jnp.int32))
+            rem1, rem2 = st.rem1, st.rem2
+            if adaptive:
+                rem1 = rem1 - jnp.sum(jnp.where(peel, work1, 0),
+                                      dtype=jnp.int32)
+                rem2 = rem2 - jnp.sum(jnp.where(peel, work2, 0),
+                                      dtype=jnp.int32)
+            any_alive = jnp.any(alive)
 
         def _last_round(args):
             # nothing left alive: the subtract would be a masked no-op
@@ -1381,12 +1383,13 @@ def device_round_loop(state: LoopState, expand, work1, work2, *,
                     empty_hist(want_hist))
 
         b, ovf_i, mn_next, hist_next = jax.lax.cond(
-            jnp.any(alive), expand, _last_round,
-            (st.b, alive, alive_prev, peel),
+            any_alive, expand, _last_round, (st.b, alive, alive_prev, peel),
         )
+        with scope("select"):
+            overflow = st.overflow | ovf_i
         return LoopState(
             b, alive, out, kappa, rounds, subr, sizes,
-            st.overflow | ovf_i, mn_next, hist_next, hi, rem1, rem2,
+            overflow, mn_next, hist_next, hi, rem1, rem2,
         )
 
     return jax.lax.while_loop(cond, body, state)
@@ -1401,7 +1404,7 @@ def drive_segments(run, state: LoopState, adaptive: bool, update_caps):
     final host-side :class:`LoopState`, or None when the in-graph
     overflow latch fired (callers fall back to the host engine)."""
     while True:
-        host = jax.device_get(run(state))
+        host = fetch(run(state))
         if bool(host.overflow):
             return None
         if not adaptive or not host.alive.any():

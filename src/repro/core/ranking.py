@@ -23,6 +23,7 @@ from typing import Callable, Dict
 import numpy as np
 
 from .graph import BipartiteGraph
+from .tap import fetch, traced
 
 __all__ = ["make_order", "RANKINGS", "wedges_processed"]
 
@@ -178,7 +179,7 @@ def approx_complement_degeneracy_order_device(g: BipartiteGraph) -> np.ndarray:
     deg, alive, round_of, _ = jax.lax.while_loop(
         cond, body, (deg, alive, round_of, jnp.int32(0))
     )
-    rounds = np.asarray(jax.device_get(round_of))
+    rounds = np.asarray(fetch(round_of))
     return np.lexsort((np.arange(n), rounds))
 
 
@@ -193,6 +194,7 @@ RANKINGS: Dict[str, Callable[[BipartiteGraph], np.ndarray]] = {
 }
 
 
+@traced("rank")
 def make_order(g: BipartiteGraph, name: str) -> np.ndarray:
     try:
         fn = RANKINGS[name]
