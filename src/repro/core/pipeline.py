@@ -121,6 +121,7 @@ __all__ = [
     "run_count_tiles",
     "lane_counts",
     "vertex_tile_lanes",
+    "narrow_partials",
     "run_fused_pallas_program",
     "run_fused_pallas_tiles",
     "plan_strategies",
@@ -217,7 +218,10 @@ class WedgePlan:
 
     ``capacity`` is a tuple of ``(name, value)`` segments: every
     statically-planned buffer the executor allocates (tile caps,
-    frontier caps), recorded so a plan documents its memory envelope.
+    frontier caps), recorded so a plan documents its memory envelope,
+    and for ``engine="fused_pallas"`` the graph's largest degree
+    (``max_degree``), which bounds a vertex tile's partial sums
+    (:func:`narrow_partials`).
     """
 
     kind: str  # count | peel_tips | peel_tips_stored | peel_wings
@@ -444,7 +448,10 @@ def plan_count(
             int(tile_wedges[~is_kernel].max(initial=1))
         )
         k_max = int(tile_wedges[is_kernel].max(initial=1))
-        capacity = (("kernel_tile", _round_up(k_max, _kops.TC)),)
+        capacity = (
+            ("kernel_tile", _round_up(k_max, _kops.TC)),
+            ("max_degree", int(np.diff(rg.offsets).max(initial=0))),
+        )
     elif aggregation == "auto":
         mx = _tile_pair_floor(rg, wv_slots)
         moff = np.concatenate([[0], np.cumsum(mx)])
@@ -954,7 +961,10 @@ def lane_counts(dg: DeviceGraph, w: Wedges, dm1: jax.Array, c2: jax.Array,
         return acc + jnp.sum(c2).astype(acc.dtype)
 
     def vertex(bv):
-        return bv.at[w.x1].add(c2).at[w.x2].add(c2).at[w.y].add(dm1)
+        for x in (w.x1, w.x2):  # None: the caller adds that endpoint
+            if x is not None:
+                bv = bv.at[x].add(c2)
+        return bv.at[w.y].add(dm1)
 
     def edge(be):
         return (be.at[dg.undirected_id[w.center_slot]].add(dm1)
@@ -969,6 +979,50 @@ def lane_counts(dg: DeviceGraph, w: Wedges, dm1: jax.Array, c2: jax.Array,
 
 
 KERNEL_BATCH = 16  # wedge_fused tiles per kernel launch
+
+# Within one kernel tile a vertex or an edge takes at most
+# tile_cap * (tile_cap - 1): each lane adds it at most d - 1 <= tile_cap
+# - 1 (as the center or an edge of the wedge) or (d - 1) / 2 (as an
+# endpoint, C(d, 2) over the group's d lanes). A batch of the widest
+# tiles therefore fits an int32 partial.
+assert KERNEL_BATCH * _kops.MAX_TILE_CAP * (_kops.MAX_TILE_CAP - 1) <= I32_MAX
+
+
+def narrow_partials(dtype, mode: str, tile_cap: int,
+                    max_degree: Optional[int]) -> bool:
+    """Whether :func:`run_fused_pallas_program` may sum each kernel
+    batch and vertex tile into int32 partials before adding them to
+    64-bit integer accumulators — exact when no partial can reach 2^31.
+    A kernel batch: see the bound above. A vertex tile (one iterating
+    vertex ``it``, degrees at most ``max_degree`` = D): the other
+    endpoint takes one group's C(d, 2) <= C(D, 2), the center y and the
+    edge (it, y) at most D - 1 lanes of d - 1 <= D - 1 each, the edge
+    (y, other) one lane; ``it`` itself takes an int64 sum. D * D < 2^31
+    covers them all. The total is always summed in the accumulator's
+    dtype; ``mode="global"`` has nothing to scatter."""
+    dt = np.dtype(dtype)
+    return (
+        mode != "global"
+        and dt.kind in "iu"
+        and dt.itemsize == 8
+        and KERNEL_BATCH * tile_cap * (tile_cap - 1) <= I32_MAX
+        and max_degree is not None
+        and max_degree * max_degree <= I32_MAX
+    )
+
+
+def _partials(dg: DeviceGraph, mode: str, dtype):
+    """Zeroed int32 partials of the accumulators; the total stays in
+    ``dtype`` (it sums every group, which no static bound holds)."""
+    part = zero_counts(dg, mode, jnp.int32)
+    return (jnp.zeros((), dtype),) + part[1:] if mode == "all" else part
+
+
+def _widen(acc, part):
+    """Add the partials densely into the accumulators, once."""
+    return jax.tree_util.tree_map(
+        lambda a, p: a + p.astype(a.dtype), acc, part
+    )
 
 
 def vertex_tile_lanes(w: Wedges, n_pad: int, direction: str, dtype):
@@ -991,7 +1045,7 @@ def vertex_tile_lanes(w: Wedges, n_pad: int, direction: str, dtype):
 @functools.partial(
     jax.jit,
     static_argnames=("tile_cap", "vertex_caps", "mode", "direction",
-                     "dtype"),
+                     "dtype", "narrow"),
 )
 def run_fused_pallas_program(
     dg: DeviceGraph,
@@ -1003,6 +1057,7 @@ def run_fused_pallas_program(
     mode: str,
     direction: str,
     dtype,
+    narrow: bool = False,
 ):
     """The ``engine="fused_pallas"`` program: a fori_loop over batches
     of kernel tiles — each tile's wedges recovered in XLA
@@ -1012,7 +1067,13 @@ def run_fused_pallas_program(
     the kernel tile holds, :func:`vertex_tile_lanes`). Live memory is
     O(KERNEL_BATCH x tile_cap + max(vertex_caps) + n_pad); nothing of
     size W is ever built. Its phases are named ``offsets``, ``recover``,
-    ``match`` and ``accumulate`` (:data:`DEVICE_SCOPES`)."""
+    ``match`` and ``accumulate`` (:data:`DEVICE_SCOPES`).
+
+    ``narrow`` (where :func:`narrow_partials` allows it) scatters each
+    batch or vertex tile into int32 partials and adds them densely into
+    the 64-bit accumulators: a TPU has no 64-bit integer unit, so a
+    64-bit scatter-add is emulated and costs several times a 32-bit one.
+    A vertex tile's iterating endpoint takes one sum of its lanes."""
     with scope("offsets"):
         cnt = slot_wedge_counts(dg, direction)
         w_off = wedge_offsets(cnt)
@@ -1036,6 +1097,11 @@ def run_fused_pallas_program(
                 use_pallas=True,
             )
         with scope("accumulate"):
+            if narrow:  # the kernel's lanes are int32 already
+                return _widen(acc, lane_counts(
+                    dg, w, dm1.reshape(-1), c2.reshape(-1), mode,
+                    _partials(dg, mode, dtype),
+                ))
             return lane_counts(
                 dg, w, dm1.reshape(-1).astype(dtype),
                 c2.reshape(-1).astype(dtype), mode, acc,
@@ -1043,14 +1109,34 @@ def run_fused_pallas_program(
 
     if kernel_tiles.shape[0]:
         acc = jax.lax.fori_loop(0, kernel_tiles.shape[0], kernel_batch, acc)
+    it = "x1" if direction == "low" else "x2"  # a vertex tile's one vertex
     for tiles, cap in zip(vertex_tiles, vertex_caps):
 
         def vertex_tile(i, acc, tiles=tiles, cap=cap):
             w = recover(tiles[i][None, :], cap)
             with scope("match"):
-                dm1, c2 = vertex_tile_lanes(w, dg.n_pad, direction, dtype)
+                dm1, c2 = vertex_tile_lanes(
+                    w, dg.n_pad, direction, jnp.int32 if narrow else dtype
+                )
             with scope("accumulate"):
-                return lane_counts(dg, w, dm1, c2, mode, acc)
+                if not narrow:
+                    return lane_counts(dg, w, dm1, c2, mode, acc)
+                acc = _widen(acc, lane_counts(
+                    dg, w._replace(**{it: None}), dm1, c2, mode,
+                    _partials(dg, mode, dtype),
+                ))
+                if mode == "edge":
+                    return acc
+                # lane 0 holds the vertex unless the tile is empty (its
+                # sentinel then drops a sum of 0)
+                own = jnp.sum(c2, dtype=dtype)
+
+                def add_own(bv):
+                    return bv.at[getattr(w, it)[0]].add(own)
+
+                if mode == "vertex":
+                    return add_own(acc)
+                return acc[0], add_own(acc[1]), acc[2]
 
         acc = jax.lax.fori_loop(0, tiles.shape[0], vertex_tile, acc)
     return acc
@@ -1088,16 +1174,21 @@ def run_fused_pallas_tiles(dg: DeviceGraph, plan: WedgePlan):
             np.int64,
         )
         caps = tuple(int(c) for c in np.unique(classes))
+        tile_cap = tile_cap or _kops.TC
+        acc = plan.accumulator
+        narrow = narrow_partials(acc.dtype, acc.mode, tile_cap,
+                                 dict(plan.capacity).get("max_degree"))
     out = launch(
         run_fused_pallas_program,
         dg,
         jnp.asarray(ktiles.reshape(-1, KERNEL_BATCH, 2), jnp.int32),
         tuple(jnp.asarray(vtiles[classes == c], jnp.int32) for c in caps),
-        tile_cap=tile_cap or _kops.TC,
+        tile_cap=tile_cap,
         vertex_caps=caps,
-        mode=plan.accumulator.mode,
+        mode=acc.mode,
         direction=plan.direction,
-        dtype=plan.accumulator.jnp_dtype(),
+        dtype=acc.jnp_dtype(),
+        narrow=narrow,
     )
     # value-level poison hook: the program's concrete output, outside
     # any jit, so the sentinel can never leak into a compilation cache

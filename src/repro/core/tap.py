@@ -50,7 +50,8 @@ __all__ = [
 # counts, their prefix, the zeroed accumulators), ``recover`` (each
 # lane's wedge from the offsets), ``match`` (endpoint-pair groups: the
 # wedge_fused kernel or a vertex tile's dense table), ``accumulate``
-# (the per-lane scatter-adds); peeling (device_round_loop,
+# (the per-lane scatter-adds, and the widening of their int32 partials
+# into 64-bit counts); peeling (device_round_loop,
 # stream_tiles) — ``select`` (extract-min, bucket choice, the peel
 # set), ``recover`` (the frontier's level-1 and level-2 searches),
 # ``subtract`` (aggregating a tile's decrements), ``bucket_update``

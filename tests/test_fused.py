@@ -22,9 +22,12 @@ from repro.core import (
 )
 from repro.core.count import _count_device, _count_stream_device
 from repro.core.pipeline import (
+    I32_MAX,
     KERNEL_BATCH,
     execute_count_plan,
+    narrow_partials,
     plan_count,
+    record_programs,
     run_fused_pallas_program,
 )
 from repro.core.oracle import global_count, per_edge_counts, per_vertex_counts
@@ -289,6 +292,170 @@ def test_fused_pallas_limb_accumulation_across_tiles():
     assert np.array_equal(vert[rg.rank_of_u], R * pu)
     assert np.array_equal(vert[rg.rank_of_v], R * pv)
     assert np.array_equal(edge, R * per_edge_counts(g))
+
+
+def _launched(fn):
+    """Run ``fn`` and return the one fused_pallas program it launched,
+    as ``(program, args, kwargs)``."""
+    with record_programs() as progs:
+        out = fn()
+    [(prog, args, kw)] = [p for p in progs
+                          if p[0] is run_fused_pallas_program]
+    return out, prog, args, kw
+
+
+def _accumulate_scatters(prog, args, kw):
+    """Operand dtypes of the scatter-adds under the ``accumulate``
+    scope of a fused_pallas program's jaxpr."""
+    def eqns(jaxpr):
+        for e in jaxpr.eqns:
+            yield e
+            for v in e.params.values():
+                for sub in v if isinstance(v, (tuple, list)) else (v,):
+                    if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                        yield from eqns(sub.jaxpr)
+                    elif isinstance(sub, jax.extend.core.Jaxpr):
+                        yield from eqns(sub)
+
+    jx = jax.make_jaxpr(lambda *a: prog(*a, **kw))(*args)
+    return [str(e.invars[0].aval.dtype) for e in eqns(jx.jaxpr)
+            if e.primitive.name == "scatter-add"
+            and "accumulate" in str(e.source_info.name_stack)]
+
+
+@pytest.mark.parametrize("direction", ["low", "high"])
+@pytest.mark.parametrize("mode", ["vertex", "edge", "all"])
+def test_fused_pallas_narrow_partials_bitwise(direction, mode):
+    """int64 counts summed through int32 partials (a kernel batch or a
+    vertex tile at a time) equal the per-lane int64 scatter-adds bit
+    for bit and the oracle, on a plan mixing kernel and vertex tiles."""
+    g = rand_graph(30, 20, 260, 5)
+    rg = preprocess(g, make_order(g, "degree"), order_name="degree")
+    with jax.enable_x64(True):
+        dg = device_graph(rg)
+        with faults.inject("capacity_overflow", site="fused_pallas.plan",
+                           budget=40):
+            plan = plan_count(
+                rg, mode=mode, direction=direction, budget=200,
+                engine="fused_pallas", dtype="int64",
+                wv_slots=host_wedge_counts(rg, direction),
+            )
+        kinds = plan.strategy_counts()
+        assert kinds["kernel"] >= 2 and kinds["vertex"] >= 2, kinds
+        got, prog, args, kw = _launched(lambda: execute_count_plan(dg, plan))
+        assert kw["narrow"] and kw["vertex_caps"]
+        assert "int32" in _accumulate_scatters(prog, args, kw)
+        wide = prog(*args, **dict(kw, narrow=False))
+    got, wide = jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(wide)
+    for a, b in zip(got, wide):
+        assert a.dtype == b.dtype == np.int64
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    pu, pv = per_vertex_counts(g)
+    want = {"vertex": [pu, pv], "edge": [per_edge_counts(g)],
+            "all": [pu, pv, per_edge_counts(g)]}[mode]
+    bv = np.asarray(got[-2] if mode == "all" else got[0])
+    have = [bv[rg.rank_of_u], bv[rg.rank_of_v]] if mode != "edge" else []
+    if mode != "vertex":
+        have.append(np.asarray(got[-1]))
+    if mode == "all":
+        assert int(got[0]) == global_count(g)
+    for a, b in zip(have, want):
+        assert np.array_equal(a, b)
+
+
+def test_fused_pallas_narrow_partials_past_int32():
+    """Job counts past 2^31 stay exact through int32 partials while each
+    batch stays within its bound: K(2, 4000) (one hub iterates, one
+    group of 4000 wedges, C(4000, 2) per tile) run R times, as one
+    kernel batch of whole-graph tiles and a vertex-tile class."""
+    n = 4000
+    e = np.stack([np.repeat([0, 1], n), np.tile(np.arange(n), 2)], axis=1)
+    g = BipartiteGraph(2, n, e)
+    rg = preprocess(g, make_order(g, "degree"), order_name="degree")
+    w_total = int(host_wedge_counts(rg, "low").sum())
+    assert w_total == n
+    n_vertex = 260
+    reps = KERNEL_BATCH + n_vertex
+    with jax.enable_x64(True):
+        out = run_fused_pallas_program(
+            device_graph(rg),
+            jnp.asarray(np.tile([0, w_total], (1, KERNEL_BATCH, 1)),
+                        jnp.int32),
+            (jnp.asarray(np.tile([0, w_total], (n_vertex, 1)), jnp.int32),),
+            tile_cap=MAX_TILE_CAP,
+            vertex_caps=(MAX_TILE_CAP,),
+            mode="all",
+            direction="low",
+            dtype=jnp.int64,
+            narrow=True,
+        )
+    total, vert, edge = (np.asarray(x) for x in out)
+    pu, pv = per_vertex_counts(g)
+    assert KERNEL_BATCH * pu.max() <= I32_MAX < int(total)
+    assert int(total) == reps * global_count(g)
+    assert vert.max() > I32_MAX
+    assert np.array_equal(vert[rg.rank_of_u], reps * pu)
+    assert np.array_equal(vert[rg.rank_of_v], reps * pv)
+    assert np.array_equal(edge, reps * per_edge_counts(g))
+
+
+def test_narrow_partials_bound():
+    """int32 partials only for 64-bit integer accumulators, a per-vertex
+    or per-edge mode, kernel batches within the bound (which holds at
+    MAX_TILE_CAP), and a largest degree D with D * D < 2^31."""
+    assert KERNEL_BATCH * MAX_TILE_CAP * (MAX_TILE_CAP - 1) <= I32_MAX
+    assert narrow_partials("int64", "all", MAX_TILE_CAP, 46340)
+    assert narrow_partials("uint64", "vertex", 128, 1)
+    assert not narrow_partials("int64", "all", MAX_TILE_CAP, 46341)
+    assert not narrow_partials("int64", "edge", 4 * MAX_TILE_CAP, 2)
+    assert not narrow_partials("int64", "global", 128, 2)
+    assert not narrow_partials("int64", "all", 128, None)
+    for dtype in ("int32", "float32", "float64"):
+        assert not narrow_partials(dtype, "all", 128, 2)
+
+
+@pytest.mark.parametrize("dtype,mode", [
+    ("int32", "all"), ("float32", "all"), ("float64", "vertex"),
+    ("int64", "global"),
+])
+def test_fused_pallas_program_unchanged_without_narrowing(dtype, mode):
+    """Accumulators that do not narrow launch ``narrow=False``: every
+    scatter-add under ``accumulate`` is in the accumulator dtype, as
+    before int32 partials existed."""
+    g = rand_graph(30, 20, 260, 5)
+    rg = preprocess(g, make_order(g, "degree"), order_name="degree")
+    with jax.enable_x64(True):
+        out, prog, args, kw = _launched(lambda: count_from_ranked(
+            rg, mode=mode, engine="fused_pallas", count_dtype=dtype,
+        ))
+        assert kw["narrow"] is False
+        assert set(_accumulate_scatters(prog, args, kw)) <= {dtype}
+    total = np.asarray(jax.tree_util.tree_leaves(out)[0])
+    if mode != "vertex":
+        assert total == global_count(g)
+
+
+@pytest.mark.parametrize("degree", [46340, 46341])
+def test_fused_pallas_narrows_up_to_degree_46340(degree):
+    """K(2, D) counted in int64: the plan records the largest degree D,
+    which narrows at 46340 and not at 46341; both are exact."""
+    e = np.stack([np.repeat([0, 1], degree),
+                  np.tile(np.arange(degree), 2)], axis=1)
+    g = BipartiteGraph(2, degree, e)
+    rg = preprocess(g, make_order(g, "degree"), order_name="degree")
+    with jax.enable_x64(True):
+        out, prog, args, kw = _launched(lambda: count_from_ranked(
+            rg, mode="all", engine="fused_pallas", count_dtype=jnp.int64,
+        ))
+        assert kw["narrow"] == (degree == 46340)
+        if not kw["narrow"]:
+            assert set(_accumulate_scatters(prog, args, kw)) == {"int64"}
+    total, vert, edge = (np.asarray(x) for x in out)
+    c2 = degree * (degree - 1) // 2
+    assert int(total) == c2
+    assert np.array_equal(vert[rg.rank_of_u], [c2, c2])
+    assert (vert[rg.rank_of_v] == degree - 1).all()
+    assert (edge == degree - 1).all()
 
 
 def test_auto_chunk_budget():
