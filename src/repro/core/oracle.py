@@ -13,6 +13,7 @@ __all__ = [
     "global_count",
     "per_vertex_counts",
     "per_edge_counts",
+    "wing_numbers",
 ]
 
 
@@ -53,3 +54,47 @@ def per_edge_counts(g: BipartiteGraph) -> np.ndarray:
         nbrs = nbrs[nbrs != u]
         out[i] = int((mu[u, nbrs] - 1).sum())
     return out
+
+
+def wing_numbers(g: BipartiteGraph) -> tuple[np.ndarray, np.ndarray]:
+    """Wing number of every edge (aligned with ``g.edges``) and the
+    least alive count at the start of each sub-round.
+
+    Counts start from :func:`per_edge_counts`. Each sub-round takes
+    κ = max(κ, least alive count) and peels every alive edge at or below
+    κ. Every butterfly whose four edges were alive before the sub-round
+    and that holds a peeled edge is found once, by its canonical
+    ``(u1 < u2, v1 < v2)`` key, and each of its surviving edges loses
+    one. The exact rounds are the sub-rounds; a range round opens at
+    the first sub-round whose least count reaches ``2 ** bit_length`` of
+    the one that opened the round before.
+    """
+    counts = per_edge_counts(g)
+    eid = np.full((g.n_u, g.n_v), -1, dtype=np.int64)
+    eid[g.edges[:, 0], g.edges[:, 1]] = np.arange(g.m)
+    alive = np.ones(g.m, dtype=bool)
+    wing = np.zeros(g.m, dtype=np.int64)
+    mins = []
+    kappa = 0
+    while alive.any():
+        mins.append(int(counts[alive].min()))
+        kappa = max(kappa, mins[-1])
+        peel = alive & (counts <= kappa)
+        wing[peel] = kappa
+        live = (eid >= 0) & alive[np.maximum(eid, 0)]  # alive before
+        keys = []
+        for u1, v1 in g.edges[peel]:
+            u2s = np.flatnonzero(live[:, v1])
+            u2s = u2s[u2s != u1]
+            rows, v2 = np.nonzero(live[u2s] & live[u1])
+            keep = v2 != v1
+            u2, v2 = u2s[rows[keep]], v2[keep]
+            lo_u, hi_u = np.minimum(u1, u2), np.maximum(u1, u2)
+            lo_v, hi_v = np.minimum(v1, v2), np.maximum(v1, v2)
+            keys.append(np.stack([lo_u, hi_u, lo_v, hi_v], axis=1))
+        alive &= ~peel
+        found = np.unique(np.concatenate(keys), axis=0)
+        for ui, vi in ((0, 2), (0, 3), (1, 2), (1, 3)):
+            x = eid[found[:, ui], found[:, vi]]
+            np.subtract.at(counts, x, alive[x])
+    return wing, np.asarray(mins, dtype=np.int64)

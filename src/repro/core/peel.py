@@ -1515,14 +1515,15 @@ def _peel_wings_device(
             pos2c = jnp.clip(pos2, 0, nbr_max)
             v2 = nbr[pos2c]
             e_small = uid[pos2c]
-            # membership: (other, v2) must be an edge — binary
-            # search v2 inside N(other)
-            lo = off[oth]
-            hi = off[oth + 1]
-            p = _lower_bound_ragged(nbr, lo, hi, v2)
-            pc = jnp.clip(p, 0, nbr_max)
-            hit = (p < hi) & (nbr[pc] == v2)
-            e_other = uid[pc]
+            with _scope("member"):
+                # membership: (other, v2) must be an edge — binary
+                # search v2 inside N(other)
+                lo = off[oth]
+                hi = off[oth + 1]
+                p = _lower_bound_ragged(nbr, lo, hi, v2)
+                pc = jnp.clip(p, 0, nbr_max)
+                hit = (p < hi) & (nbr[pc] == v2)
+                e_other = uid[pc]
             # c = (u1, v2), d = (u2, v2): map small/other back
             c_edge = jnp.where(si, e_small, e_other)
             d_edge = jnp.where(si, e_other, e_small)
@@ -1633,6 +1634,7 @@ def _peel_wings_device(
     )
 
 
+@_traced("preprocess")
 def _wing_work_totals(g: BipartiteGraph, off: np.ndarray, nbr: np.ndarray):
     """Per-edge wing expansion totals over the graph CSR: for each
     edge ``a = (u1, v1)``, ``l1[a] = deg(v1)`` (level-1 candidates)
@@ -1693,61 +1695,62 @@ def _peel_wings_device_run(
     if 2 * m >= _I32_MAX:
         note.append("device engine unavailable: edge slots beyond int32")
         return None
-    eu, ev, l1, l2 = (
-        _wing_work_totals(g, off, nbr) if w_totals is None else w_totals
-    )
-    lvl1 = int(l1.sum())
-    lvl2 = int(l2.sum())
-    if lvl1 >= _I32_MAX or lvl2 >= _I32_MAX:
-        note.append("device engine unavailable: expansion totals beyond "
-                    "int32 indexing")
-        return None
-    if subtract == "fused":
-        # the fused recovery reads in-row neighbor-degree prefixes;
-        # every row total must stay int32-addressable (the materialize
-        # path never touches these arrays, so it skips the build and
-        # the guard)
-        nbr_ds, uid_ds, degs_ds, cumdeg = degree_sorted_csr(off, nbr, uid)
-        if cumdeg.size and int(
-            (cumdeg + degs_ds).max(initial=0)
-        ) >= _I32_MAX:
-            note.append("device engine unavailable: degree-sorted "
-                        "prefixes beyond int32 indexing")
+    with _span("plan"):  # the degree-sorted CSR, capacities, the seed state
+        eu, ev, l1, l2 = (
+            _wing_work_totals(g, off, nbr) if w_totals is None else w_totals
+        )
+        lvl1 = int(l1.sum())
+        lvl2 = int(l2.sum())
+        if lvl1 >= _I32_MAX or lvl2 >= _I32_MAX:
+            note.append("device engine unavailable: expansion totals beyond "
+                        "int32 indexing")
             return None
-    else:
-        nbr_ds = uid_ds = degs_ds = cumdeg = np.zeros(0, np.int64)
-    budget = _I32_MAX if max_frontier is None else int(max_frontier)
-    tb = _DEFAULT_TILE_TARGET if tile_budget is None else int(tile_budget)
-    if budget_shrinks:
-        budget = max(128, budget >> budget_shrinks)
-        tb = max(1, tb >> budget_shrinks)
-    if subtract == "materialize":
-        cap1 = _pow2_pad(min(lvl1, budget))
-        cap2 = _pow2_pad(min(lvl2, budget))
-    else:
-        cap1 = cap2 = 128  # unused: the fused path has no buffers
-    tile_cap = _pow2_pad(min(tb, max(lvl2, 1)))
-    b0 = jnp.asarray(counts)
-    # counts below INT32_MAX (guarded above) run the int32 kernel in any
-    # count dtype; off the compiled backend the reference serves
-    use_kernel = not _kops.interpret_default()
-    state = _init_state(
-        b0, m, decrease_key=decrease_key, peel_mode=peel_mode,
-        lvl1=lvl1, lvl2=lvl2,
-    )
-    args = (
-        jnp.asarray(off, jnp.int32),
-        jnp.asarray(nbr if nbr.size else np.zeros(1), jnp.int32),
-        jnp.asarray(uid if uid.size else np.zeros(1), jnp.int32),
-        jnp.asarray(eu, jnp.int32),
-        jnp.asarray(ev, jnp.int32),
-        jnp.asarray(nbr_ds if nbr_ds.size else np.zeros(1), jnp.int32),
-        jnp.asarray(uid_ds if uid_ds.size else np.zeros(1), jnp.int32),
-        jnp.asarray(degs_ds if degs_ds.size else np.zeros(1), jnp.int32),
-        jnp.asarray(cumdeg if cumdeg.size else np.zeros(1), jnp.int32),
-        jnp.asarray(l1.astype(np.int32)),
-        jnp.asarray(l2.astype(np.int32)),
-    )
+        if subtract == "fused":
+            # the fused recovery reads in-row neighbor-degree prefixes;
+            # every row total must stay int32-addressable (the materialize
+            # path never touches these arrays, so it skips the build and
+            # the guard)
+            nbr_ds, uid_ds, degs_ds, cumdeg = degree_sorted_csr(off, nbr, uid)
+            if cumdeg.size and int(
+                (cumdeg + degs_ds).max(initial=0)
+            ) >= _I32_MAX:
+                note.append("device engine unavailable: degree-sorted "
+                            "prefixes beyond int32 indexing")
+                return None
+        else:
+            nbr_ds = uid_ds = degs_ds = cumdeg = np.zeros(0, np.int64)
+        budget = _I32_MAX if max_frontier is None else int(max_frontier)
+        tb = _DEFAULT_TILE_TARGET if tile_budget is None else int(tile_budget)
+        if budget_shrinks:
+            budget = max(128, budget >> budget_shrinks)
+            tb = max(1, tb >> budget_shrinks)
+        if subtract == "materialize":
+            cap1 = _pow2_pad(min(lvl1, budget))
+            cap2 = _pow2_pad(min(lvl2, budget))
+        else:
+            cap1 = cap2 = 128  # unused: the fused path has no buffers
+        tile_cap = _pow2_pad(min(tb, max(lvl2, 1)))
+        b0 = jnp.asarray(counts)
+        # counts below INT32_MAX (guarded above) run the int32 kernel in any
+        # count dtype; off the compiled backend the reference serves
+        use_kernel = not _kops.interpret_default()
+        state = _init_state(
+            b0, m, decrease_key=decrease_key, peel_mode=peel_mode,
+            lvl1=lvl1, lvl2=lvl2,
+        )
+        args = (
+            jnp.asarray(off, jnp.int32),
+            jnp.asarray(nbr if nbr.size else np.zeros(1), jnp.int32),
+            jnp.asarray(uid if uid.size else np.zeros(1), jnp.int32),
+            jnp.asarray(eu, jnp.int32),
+            jnp.asarray(ev, jnp.int32),
+            jnp.asarray(nbr_ds if nbr_ds.size else np.zeros(1), jnp.int32),
+            jnp.asarray(uid_ds if uid_ds.size else np.zeros(1), jnp.int32),
+            jnp.asarray(degs_ds if degs_ds.size else np.zeros(1), jnp.int32),
+            jnp.asarray(cumdeg if cumdeg.size else np.zeros(1), jnp.int32),
+            jnp.asarray(l1.astype(np.int32)),
+            jnp.asarray(l2.astype(np.int32)),
+        )
     adaptive = capacity_schedule == "adaptive"
     caps = {"cap1": cap1, "cap2": cap2}
 

@@ -54,11 +54,11 @@ __all__ = [
 # into 64-bit counts); peeling (device_round_loop,
 # stream_tiles) — ``select`` (extract-min, bucket choice, the peel
 # set), ``recover`` (the frontier's level-1 and level-2 searches),
-# ``subtract`` (aggregating a tile's decrements), ``bucket_update``
-# (applying them).
+# ``member`` (a wing triple's edge-membership search), ``subtract``
+# (aggregating a tile's decrements), ``bucket_update`` (applying them).
 DEVICE_SCOPES = (
     "offsets", "recover", "match", "accumulate",
-    "select", "subtract", "bucket_update",
+    "select", "subtract", "bucket_update", "member",
 )
 
 SPAN_PREFIX = "repro."

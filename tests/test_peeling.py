@@ -7,8 +7,10 @@ import pytest
 
 from repro.core import BipartiteGraph
 from repro.core.fibheap import BucketStructure, FibHeap
-from repro.core.oracle import per_edge_counts, per_vertex_counts
+from repro.core.oracle import per_edge_counts, per_vertex_counts, wing_numbers
 from repro.core.peel import peel_tips, peel_tips_stored, peel_wings
+from repro.core.pipeline import record_programs
+from repro.data.graphs import powerlaw_bipartite
 
 
 def rand_graph(nu, nv, m, seed):
@@ -89,6 +91,68 @@ def test_wing_decomposition(seed):
     g = rand_graph(9, 8, 28, seed)
     got = peel_wings(g)
     assert np.array_equal(got.numbers, oracle_wing(g))
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_wing_numbers_oracle_matches_recompute(seed):
+    """``oracle.wing_numbers`` (each butterfly found once per sub-round)
+    agrees with the recompute-from-scratch oracle, sub-round for
+    sub-round, on the tiny graphs."""
+    g = rand_graph(9, 8, 28, seed)
+    got, mins = wing_numbers(g)
+    assert np.array_equal(got, oracle_wing(g))
+    assert len(mins) == peel_wings(g).sub_rounds
+
+
+def _range_rounds(mins):
+    """Bucket rounds of a sub-round trajectory: a round opens where the
+    least alive count reaches the bound its last opening set."""
+    rounds, hi = 0, 0
+    for mn in mins:
+        if mn >= hi:
+            rounds, hi = rounds + 1, 1 << int(mn).bit_length()
+    return rounds
+
+
+# (Chung-Lu graph, peel_mode, tile_budget): about 0.8-2.5 K edges, whose
+# first sub-round alone streams more triples than a tile holds
+WING_CELL_CASES = {
+    "cl1100-range": ((200, 150, 1500, 2.1, 1), "range", None),
+    "cl1100-exact": ((200, 150, 1500, 2.1, 1), "exact", None),
+    "cl800-range-tile128": ((150, 120, 1200, 2.0, 3), "range", 128),
+    "cl2500-range": ((400, 300, 3000, 2.2, 4), "range", None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WING_CELL_CASES))
+def test_wings_cell_call_matches_oracle(case):
+    """The benchmark cell's call — device engine, range or exact peel,
+    int64 per-edge counts from ``fused_pallas`` — against
+    ``oracle.wing_numbers``: the device rung answers with no retry,
+    launches only the wing loop and the count, and crosses tiles."""
+    (nu, nv, m, alpha, seed), mode, budget = WING_CELL_CASES[case]
+    g = powerlaw_bipartite(nu, nv, m, alpha, alpha, seed)
+    want, mins = wing_numbers(g)
+    with jax.enable_x64(True), record_programs() as programs:
+        got = peel_wings(g, engine="device", peel_mode=mode,
+                         tile_budget=budget,
+                         count_kwargs={"engine": "fused_pallas"})
+    names = sorted({p.__name__ for p, _a, _k in programs})
+    assert names == ["_peel_wings_device", "run_fused_pallas_program"]
+    rep = got.report
+    assert rep.requested == rep.final_rung == "device"
+    assert all(a.outcome == "ok" and not a.retries and not a.budget_shrinks
+               for a in rep.attempts)
+    assert got.numbers.dtype == np.int64
+    assert np.array_equal(got.numbers, want)
+    assert got.sub_rounds == len(mins)
+    assert got.rounds == (len(mins) if mode == "exact"
+                          else _range_rounds(mins))
+    (_p, args, kw), = [p for p in programs
+                       if p[0].__name__ == "_peel_wings_device"]
+    assert kw["tile_cap"] == (1024 if budget is None else 128)
+    first = per_edge_counts(g) <= mins[0]  # the first sub-round's edges
+    assert np.asarray(args[10])[first].sum() > kw["tile_cap"]  # work2
 
 
 # -- device-resident peeling engine (PR 2) ------------------------------
@@ -449,7 +513,7 @@ def test_wings_device_no_per_round_sync(monkeypatch):
     d = peel_wings(g, counts=counts, engine="device")
     assert len(calls) == 1
     assert d.rounds >= 2  # the loop really ran multiple rounds
-    assert np.array_equal(d.numbers, oracle_wing(g))
+    assert np.array_equal(d.numbers, wing_numbers(g)[0])
 
 
 def test_wings_adaptive_schedule_parity():
@@ -459,6 +523,7 @@ def test_wings_adaptive_schedule_parity():
     d = peel_wings(g, engine="device", capacity_schedule="adaptive")
     assert np.array_equal(h.numbers, d.numbers)
     assert h.rounds == d.rounds
+    assert np.array_equal(d.numbers, wing_numbers(g)[0])
 
 
 def test_peel_knob_validation():

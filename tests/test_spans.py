@@ -1,6 +1,7 @@
 """The program's tap (``core/tap.py``): host spans on the profiler's
 clock, one ``repro.fetch`` span per host sync, and the named device
 scopes the compiled programs carry."""
+import contextlib
 import glob
 import re
 
@@ -10,7 +11,7 @@ import numpy as np
 import pytest
 
 from repro.core import BipartiteGraph, count_butterflies
-from repro.core.peel import peel_tips
+from repro.core.peel import peel_tips, peel_wings
 from repro.core.pipeline import (
     DEVICE_SCOPES,
     launch,
@@ -31,6 +32,9 @@ CALLS = {
     "count_butterflies": lambda g: count_butterflies(
         g, mode="all", engine="fused_pallas"),
     "peel_tips": lambda g: peel_tips(
+        g, engine="device", peel_mode="range",
+        count_kwargs={"engine": "fused_pallas"}),
+    "peel_wings": lambda g: peel_wings(
         g, engine="device", peel_mode="range",
         count_kwargs={"engine": "fused_pallas"}),
 }
@@ -146,3 +150,41 @@ def test_scope_names_only_device_scopes():
     text = jax.jit(lambda x: scope("recover")(jnp.cumsum)(x) * 2).lower(
         jnp.arange(8)).as_text(debug_info=True)
     assert "recover" in text
+
+
+def _compiled_wing_loop(g, drop=()):
+    """The compiled text of the wing program a device wing call
+    launches, traced with the scopes in ``drop`` left out."""
+    import repro.core.peel as pm
+
+    real = pm._scope
+
+    def scoped(name):
+        return contextlib.nullcontext() if name in drop else real(name)
+
+    with record_programs() as programs:
+        CALLS["peel_wings"](g)
+    (program, args, kwargs), = [p for p in programs
+                                if p[0].__name__ == "_peel_wings_device"]
+    pm._scope = scoped
+    try:
+        jax.clear_caches()
+        return program.lower(*args, **kwargs).compile().as_text()
+    finally:
+        pm._scope = real
+        jax.clear_caches()
+
+
+def test_member_scope_changes_only_op_names():
+    """The ``member`` scope renames the wing loop's membership search
+    and changes nothing else of the compiled program."""
+    g = _graph()
+    with_member = _compiled_wing_loop(g)
+    without = _compiled_wing_loop(g, drop=("member",))
+    assert "/member/" in with_member and "/member/" not in without
+
+    def strip(text):  # the code: no source tables, no metadata
+        code = text[re.search(r"^(%|ENTRY)", text, re.M).start():]
+        return re.sub(r",? metadata=\{[^}]*\}", "", code)
+
+    assert strip(with_member) == strip(without)
