@@ -63,17 +63,19 @@ Further device-engine knobs:
 
   - ``decrease_key="bucket"|"scatter"`` — "scatter" is the PR 2
     one-scatter-per-round subtract plus a separate ``bucket_min``
-    reduction at the top of the next round. "bucket" (default) routes
-    each aggregated update batch through ``kernels.ops.bucket_update``,
-    the Julienne-style batched decrease-key: the decrements, the next
-    round's masked min, and the O(log n) geometric-bucket occupancy all
-    come out of ONE pass over the count array — the separate per-round
-    extract-min reduction disappears (the carried min seeds κ). Both
-    produce bitwise-identical numbers (integer scatter sums commute).
-    The Pallas kernel runs compiled on TPU; elsewhere the dispatcher
-    serves the jnp reference (off-TPU the per-round kernel interpreter
-    would dominate, the same policy as ``peel_wings``'s host
-    extract-min).
+    reduction at the top of the next round. "bucket" (default) is the
+    Julienne-style batched decrease-key (``pipeline.apply_decrements``):
+    a sub-round's decrements are exact int32 scatter-adds into the
+    counts, carried as int32 through its tile stream, except its last
+    update chunk of at most ``MAX_UPDATE_CAP`` lanes, which goes through
+    ``kernels.ops.bucket_update``; that ONE kernel pass a sub-round
+    yields the next round's masked min and the O(log n) geometric-bucket
+    occupancy of the final counts — the separate per-round extract-min
+    reduction disappears (the carried min seeds κ). Both produce
+    bitwise-identical numbers (integer scatter sums commute). The
+    Pallas kernel runs compiled on TPU; elsewhere the dispatcher serves
+    the jnp reference (off-TPU the per-round kernel interpreter would
+    dominate, the same policy as ``peel_wings``'s host extract-min).
   - ``capacity_schedule="fixed"|"adaptive"`` — "fixed" plans every
     frontier capacity once from round-0 worst-case totals (one
     ``device_get`` per decomposition). "adaptive" shrinks the planned
@@ -110,9 +112,9 @@ Every decomposition and engine supports ``peel_mode="exact"|"range"``:
     Peeling of Bipartite Networks", Lakhotia et al. 2021): each round
     selects the **lowest non-empty geometric bucket** ``[2^(k-1), 2^k)``
     and processes it to completion. Under ``decrease_key="bucket"`` the
-    selection consumes the O(log n) occupancy histogram that the
-    ``bucket_update`` decrease-key pass already produces every round
-    (previously computed and dead-code-eliminated); under
+    selection consumes the O(log n) occupancy histogram that each
+    sub-round's one ``bucket_update`` pass already produces (previously
+    computed and dead-code-eliminated); under
     ``"scatter"`` (and on the host engine) the bucket is derived from
     the masked min's bit length — the two selections provably agree,
     because the min inhabits the lowest non-empty range. Final
@@ -346,13 +348,15 @@ def _subtract_tile(
     decrease_key: str = "scatter",
     use_kernel: bool = False,
     want_hist: bool = False,
+    last=True,
 ):
     """Aggregate one tile of (u1, u2) frontier wedge pairs and subtract
     C(d, 2) from B[u2] — the peeling side of the shared fused tile
     machinery (``count._fused_tile_apply``: tile-local sort/hash with
     the in-graph hash-overflow sort fallback). Returns
     ``(b, min, hist)`` (min/hist meaningful under
-    ``decrease_key="bucket"`` only; hist only when ``want_hist``).
+    ``decrease_key="bucket"`` on the sub-round's ``last`` tile only;
+    hist only when ``want_hist``).
     """
     sent = jnp.int32(n_side)
     w = Wedges(
@@ -366,10 +370,13 @@ def _subtract_tile(
 
     def consume(_wv, groups):
         d = groups.d.astype(b.dtype)
-        dec = jnp.where(groups.valid, d * (d - 1) // 2, 0)
+        # C(d, 2) halved before the product: exact in int32 counts
+        # wherever C(d, 2) itself is (it never exceeds B[u2])
+        c2 = jnp.where(d % 2 == 0, d // 2 * (d - 1), (d - 1) // 2 * d)
+        dec = jnp.where(groups.valid, c2, 0)
         tgt = jnp.where(groups.valid, groups.x2, sent)
         return _apply_decrements(b, alive, tgt, dec, decrease_key,
-                                 use_kernel, want_hist)
+                                 use_kernel, want_hist, last)
 
     out, _ok = _fused_tile_apply(w, aggregation, consume, "xla", hash_bits)
     return out
@@ -495,7 +502,7 @@ def _peel_tips_device(
     want_hist = peel_mode == "range" and decrease_key == "bucket"
 
     def _tiles(b, alive, roff, recover):
-        def tile_fn(bt, wid, tvalid):
+        def tile_fn(bt, wid, tvalid, last):
             with _scope("recover"):
                 u1, u2 = recover(wid)
                 u2c = jnp.clip(u2, 0, n_side - 1)
@@ -504,7 +511,7 @@ def _peel_tips_device(
                 u1.astype(jnp.int32), u2c.astype(jnp.int32), tv, bt,
                 alive, aggregation=aggregation, n_side=n_side,
                 hash_bits=hash_bits, decrease_key=decrease_key,
-                use_kernel=use_kernel, want_hist=want_hist,
+                use_kernel=use_kernel, want_hist=want_hist, last=last,
             )
 
         return _stream_tiles(
@@ -1094,11 +1101,12 @@ def peel_tips(
     ``tile_budget`` sizes the tiles (default: a small 1024 target —
     peeling pays the tile shape every round — floored by the largest
     single-vertex expansion). ``decrease_key="bucket"`` (default)
-    routes device-engine updates through the Julienne-style batched
-    ``bucket_update`` pass (decrements + next round's extract-min in
-    one sweep); ``"scatter"`` keeps the PR 2 scatter + per-round
-    ``bucket_min``. ``capacity_schedule="adaptive"`` shrinks the
-    device engine's planned buffers geometrically as the graph empties
+    applies device-engine updates as int32 scatter-adds closed by one
+    Julienne-style ``bucket_update`` pass a sub-round (its last
+    decrements + next round's extract-min in one sweep); ``"scatter"``
+    keeps the PR 2 scatter + per-round ``bucket_min``.
+    ``capacity_schedule="adaptive"`` shrinks the device engine's
+    planned buffers geometrically as the graph empties
     (O(log cap) extra host syncs); ``"fixed"`` keeps the one-sync
     guarantee. ``peel_mode="range"`` switches to bucket-range rounds
     (process the whole lowest non-empty geometric bucket per round,
@@ -1402,6 +1410,7 @@ def _subtract_edge_groups(
     decrease_key: str = "scatter",
     use_kernel: bool = False,
     want_hist: bool = False,
+    last=True,
 ):
     """Aggregate one tile of butterfly edge ids and subtract the group
     multiplicities — the wing-side consumer of the shared fused tile
@@ -1409,7 +1418,8 @@ def _subtract_edge_groups(
     to three still-present edges; grouping by edge id turns the raw
     triple scatter into one subtract per distinct edge (same integer
     sums, so bitwise-equal to the host engine's raw scatter), with the
-    in-graph hash-overflow sort fallback. Returns ``(b, min, hist)``.
+    in-graph hash-overflow sort fallback. Returns ``(b, min, hist)``
+    (min/hist meaningful on the sub-round's ``last`` tile only).
     """
     sent = jnp.int32(m)
     key = jnp.where(valid3, tgt3, sent)
@@ -1426,7 +1436,7 @@ def _subtract_edge_groups(
         dec = jnp.where(groups.valid, groups.d.astype(b.dtype), 0)
         tgt = jnp.where(groups.valid, groups.x1, sent)
         return _apply_decrements(b, alive, tgt, dec, decrease_key,
-                                 use_kernel, want_hist)
+                                 use_kernel, want_hist, last)
 
     out, _ok = _fused_tile_apply(w, aggregation, consume, "xla", hash_bits)
     return out
@@ -1508,10 +1518,11 @@ def _peel_wings_device(
             return alive_prev[xc] & (~peel[xc] | (x > a))
 
         def _locate_and_subtract(bt, a2, v1_2, b_2, oth, si, kp, pos2,
-                                 tvalid):
+                                 tvalid, last=True):
             """Membership-check one tile of (edge, u2, v2-slot) triples
             and subtract the located butterflies' edge contributions.
-            ``pos2`` are absolute CSR slots inside N(small)."""
+            ``pos2`` are absolute CSR slots inside N(small); ``last``
+            marks the sub-round's final tile."""
             pos2c = jnp.clip(pos2, 0, nbr_max)
             v2 = nbr[pos2c]
             e_small = uid[pos2c]
@@ -1541,7 +1552,7 @@ def _peel_wings_device(
                 tgt3.astype(jnp.int32), ok3, bt, alive,
                 aggregation=aggregation, m=m, hash_bits=hash_bits,
                 decrease_key=decrease_key, use_kernel=use_kernel,
-                want_hist=want_hist,
+                want_hist=want_hist, last=last,
             )
 
         if subtract == "fused":
@@ -1550,7 +1561,7 @@ def _peel_wings_device(
             # masked prefix — no level-1/level-2 buffers exist at all
             roff_tri = _prefix(jnp.where(peel, work2, 0))
 
-            def tile_fn(bt, wid, tvalid):
+            def tile_fn(bt, wid, tvalid, last):
                 a2, tp = ragged_slots_at(
                     roff_tri, jnp.zeros((m,), jnp.int32), wid
                 )
@@ -1589,7 +1600,7 @@ def _peel_wings_device(
                 oth = jnp.where(si, u2, u1)
                 pos2 = off[small] + jnp.clip(i, 0, jnp.maximum(deg[small] - 1, 0))
                 return _locate_and_subtract(
-                    bt, a2, v1_2, b_2, oth, si, kp, pos2, tvalid
+                    bt, a2, v1_2, b_2, oth, si, kp, pos2, tvalid, last
                 )
 
             b_new, mn2, h2 = _stream_tiles(

@@ -1293,30 +1293,55 @@ def masked_state(b: jax.Array, alive: jax.Array, want_hist: bool):
 
 
 def apply_decrements(b, alive, tgt, dec, decrease_key, use_kernel,
-                     want_hist=False):
+                     want_hist=False, last=True):
     """Apply one aggregated update batch to the count array.
 
     ``"scatter"``: the one-scatter subtract (min placeholder — the
     round loop runs its own ``bucket_min``). ``"bucket"``: the
-    Julienne-style batched decrease-key (``kernels.ops.bucket_update``)
-    — decrements, the next round's masked min, and (when ``want_hist``,
-    i.e. range mode) the geometric-bucket occupancy, all in one pass.
-    Returns ``(new_counts, min, hist)`` (hist zero-length unless
-    ``want_hist`` — see :func:`empty_hist`).
+    Julienne-style batched decrease-key — decrements, the next round's
+    masked min, and (when ``want_hist``, i.e. range mode) the
+    geometric-bucket occupancy. Only the pass the round loop consumes
+    runs ``kernels.ops.bucket_update``: ``last`` (a traced bool, or
+    True for a sub-round's only batch) marks the sub-round's final
+    batch, whose last ``MAX_UPDATE_CAP``-lane chunk goes through the
+    kernel, so its min and occupancy describe the sub-round's final
+    counts. Every other batch, and every earlier chunk of the final
+    one, is an exact int32 scatter-add (the counts stay below
+    INT32_MAX: the device loops guard it). Returns ``(new_counts, min,
+    hist)``; min and hist are placeholders unless ``last`` (hist
+    zero-length unless ``want_hist`` — see :func:`empty_hist`).
     """
-    if decrease_key == "bucket":
-        with scope("bucket_update"):
-            nb, mn, hist = _kops.bucket_update(
-                b, alive, tgt, dec, use_pallas=use_kernel
+    if decrease_key != "bucket":
+        return b.at[tgt].add(-dec), jnp.int32(I32_MAX), empty_hist(want_hist)
+    with scope("bucket_update"):
+        tgt = tgt.astype(jnp.int32)
+        dec = dec.astype(jnp.int32)
+        cap = _kops.MAX_UPDATE_CAP
+        head = (tgt.shape[0] - 1) // cap * cap  # lanes before the last chunk
+
+        def passed(c):
+            if head:
+                c = c.at[tgt[:head]].add(-dec[:head])
+            nc, mn, hist = _kops.bucket_update(
+                c, alive, tgt[head:], dec[head:], use_pallas=use_kernel
             )
-        if not want_hist:
-            # discarded before it reaches the loop carry -> XLA DCEs
-            # the reference path's histogram under exact mode (measured:
-            # bucket ~= scatter wall time on CPU); the kernel path
-            # computes it in-register for free either way
-            hist = empty_hist(False)
+            if not want_hist:
+                # dropped before it reaches the loop carry -> XLA DCEs
+                # the reference path's histogram under exact mode; the
+                # kernel computes it in-register either way
+                hist = empty_hist(False)
+            return nc, mn, hist
+
+        def scattered(c):
+            return (c.at[tgt].add(-dec), jnp.int32(I32_MAX),
+                    empty_hist(want_hist))
+
+        c32 = b.astype(jnp.int32)
+        if last is True:
+            nb, mn, hist = passed(c32)
+        else:
+            nb, mn, hist = jax.lax.cond(last, passed, scattered, c32)
         return nb.astype(b.dtype), mn, hist
-    return b.at[tgt].add(-dec), jnp.int32(I32_MAX), empty_hist(want_hist)
 
 
 def init_loop_state(b0: jax.Array, n_out: int, *, decrease_key: str,
@@ -1350,15 +1375,23 @@ def stream_tiles(b, alive, roff, tile_fn, *, tile_cap: int, aligned: bool,
                  decrease_key: str, want_hist: bool):
     """Stream the flat per-round id space ``[0, roff[-1])`` through
     fixed-shape tiles — the fused-subtract while_loop shared by every
-    decomposition. ``tile_fn(b, wid, tvalid) -> (b, mn, hist)``
-    recovers and subtracts one tile. ``aligned`` cuts tile boundaries
-    at segment boundaries (``aligned_tile_end`` — required when the
-    consumer's per-group C(d, 2) must not split); unaligned tiles
-    advance by the full ``tile_cap`` (linear subtracts split exactly).
-    Returns ``(b, mn, hist)`` with the zero-frontier carried state
-    re-derived via :func:`masked_state`.
+    decomposition. ``tile_fn(b, wid, tvalid, last) -> (b, mn, hist)``
+    recovers and subtracts one tile; ``last`` is the traced flag of
+    the sub-round's final tile, the one whose min and occupancy the
+    stream returns (:func:`apply_decrements`). ``aligned`` cuts tile
+    boundaries at segment boundaries (``aligned_tile_end`` — required
+    when the consumer's per-group C(d, 2) must not split); unaligned
+    tiles advance by the full ``tile_cap`` (linear subtracts split
+    exactly). Under ``decrease_key="bucket"`` the stream carries the
+    counts as int32 and casts them back once at its end. Returns
+    ``(b, mn, hist)`` with the zero-frontier carried state re-derived
+    via :func:`masked_state`.
     """
     total = roff[-1]
+    dtype = b.dtype
+    if decrease_key == "bucket":
+        with scope("bucket_update"):
+            b = b.astype(jnp.int32)
 
     @scope("recover")
     def tcond(c):
@@ -1372,7 +1405,7 @@ def stream_tiles(b, alive, roff, tile_fn, *, tile_cap: int, aligned: bool,
             else:
                 te = jnp.minimum(ts + jnp.int32(tile_cap), total)
             wid = ts + jnp.arange(tile_cap, dtype=jnp.int32)
-        out_b, mn, h = tile_fn(bt, wid, wid < te)
+        out_b, mn, h = tile_fn(bt, wid, wid < te, te >= total)
         return out_b, te, mn, h
 
     b, _, mn, hist = jax.lax.while_loop(
@@ -1380,6 +1413,8 @@ def stream_tiles(b, alive, roff, tile_fn, *, tile_cap: int, aligned: bool,
         (b, jnp.int32(0), jnp.int32(I32_MAX), empty_hist(want_hist)),
     )
     if decrease_key == "bucket":
+        with scope("bucket_update"):
+            b = b.astype(dtype)
         # zero-tile rounds still need the post-peel carried state
         with scope("select"):
             mn, hist = jax.lax.cond(

@@ -23,8 +23,9 @@ limb and every one-hot entry is exact in bf16) and f32 accumulation, so
 every column sum stays below ``MAX_UPDATE_CAP * 2^8 < 2^24`` — exact —
 for update batches of at most ``MAX_UPDATE_CAP`` (4096) entries and
 ``dec`` anywhere in [0, 2^31). The wrapper enforces the batch bound at
-trace time; ``ops.bucket_update`` feeds larger batches through the
-kernel in ``MAX_UPDATE_CAP`` chunks. ``idx`` entries equal to
+trace time; the peeling loops (``core.pipeline.apply_decrements``)
+scatter-add the lanes of a wider batch before its last
+``MAX_UPDATE_CAP`` chunk and pass only that chunk. ``idx`` entries equal to
 ``counts.shape[0]`` (the sentinel) hit no bucket; their ``dec`` must
 be 0.
 
@@ -36,9 +37,12 @@ one-hot panel ``(RC, TN)`` needs no in-kernel reshape.
 Dispatched via ``ops.bucket_update`` with the same backend-aware
 interpret default as every kernel here (compiled on TPU, interpreted in
 CI). The device peeling engines (``core.peel`` ``decrease_key=
-"bucket"``) call it once per frontier tile inside the jitted round
-loop; off-TPU they route to the reference (the interpreter would
-dominate the round, same policy as ``peel_wings``'s host extract-min).
+"bucket"``) run it once per sub-round that streams any update, on the
+last chunk of its last tile: the round loop reads only that pass's
+min and occupancy, so every earlier tile and chunk is an exact int32
+scatter-add into the counts. Off-TPU they route that pass to the
+reference (the interpreter would dominate the round, same policy as
+``peel_wings``'s host extract-min).
 """
 from __future__ import annotations
 
@@ -163,14 +167,15 @@ def bucket_update_pallas(
     INT32_MAX when none alive); ``bucket_hist`` is the (32,) occupancy
     of the geometric ranges over alive entries. ``idx == counts.shape
     [0]`` is the drop sentinel. Update batches beyond MAX_UPDATE_CAP
-    raise (``ops.bucket_update`` feeds them through in chunks).
+    raise (``core.pipeline.apply_decrements`` scatter-adds all but the
+    last chunk of such a batch).
     """
     if idx.shape[0] > MAX_UPDATE_CAP:
         raise ValueError(
             f"bucket_update_pallas batch {idx.shape[0]} exceeds "
             f"MAX_UPDATE_CAP {MAX_UPDATE_CAP} — the f32 limb "
-            "contractions would lose exactness; ops.bucket_update "
-            "feeds larger batches through in chunks"
+            "contractions would lose exactness; apply_decrements "
+            "scatter-adds all but the last chunk of a larger batch"
         )
     n = counts.shape[0]
     n_pad = ((n + TN - 1) // TN) * TN

@@ -22,7 +22,6 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
 from ..testing import faults as _faults
 from . import ref as _ref
@@ -112,9 +111,10 @@ def bucket_state(counts, alive):
     """Masked extract-min plus geometric-bucket occupancy with no
     decrease-key batch: ``(min, bucket_hist)``. Always the jnp
     reference — inside the peeling round loops the same pair comes out
-    of the ``bucket_update`` kernel pass for free; this standalone form
-    only seeds the carried state before round 0 and re-derives it on
-    zero-frontier rounds, both off the per-tile hot path.
+    of each sub-round's one ``bucket_update`` kernel pass, on its last
+    update chunk; this standalone form only seeds the carried state
+    before round 0 and re-derives it on zero-frontier rounds, both off
+    the per-tile hot path.
     """
     return _ref.bucket_state_ref(counts, alive)
 
@@ -132,39 +132,18 @@ def bucket_update(
     geometric-bucket occupancy)`` from the same pass (see
     ``bucket_update``). The kernel path computes in int32: callers keep
     counts below INT32_MAX (the device peeling loops guard it) and get
-    the result back in their count dtype. A batch beyond MAX_UPDATE_CAP
-    goes through the kernel in MAX_UPDATE_CAP chunks, one pass each;
-    the last pass's min and occupancy describe the final counts.
+    the result back in their count dtype. One call is one kernel pass
+    of at most MAX_UPDATE_CAP lanes (a wider batch raises): the peeling
+    loops (``core.pipeline.apply_decrements``) scatter-add every other
+    decrement of a sub-round and pass only its last chunk here.
     """
     _faults.maybe_oom("ops.bucket_update")
     if not use_pallas:
         return _ref.bucket_update_ref(counts, alive, idx, dec)
-    interp = _resolve(interpret)
-    k = idx.shape[0]
-    if k <= MAX_UPDATE_CAP:
-        new, mn, hist = bucket_update_pallas(
-            counts, alive, idx, dec, interpret=interp
-        )
-        return new.astype(counts.dtype), mn, hist
-    chunks = -(-k // MAX_UPDATE_CAP)
-    pad = chunks * MAX_UPDATE_CAP - k
-    idx_c = jnp.pad(idx.astype(jnp.int32), (0, pad),
-                    constant_values=counts.shape[0])
-    dec_c = jnp.pad(dec.astype(jnp.int32), (0, pad))
-
-    def step(c, chunk):
-        new, mn, hist = bucket_update_pallas(
-            c, alive, chunk[0], chunk[1], interpret=interp
-        )
-        return new, (mn, hist)
-
-    new, (mns, hists) = jax.lax.scan(
-        step,
-        counts.astype(jnp.int32),
-        (idx_c.reshape(chunks, MAX_UPDATE_CAP),
-         dec_c.reshape(chunks, MAX_UPDATE_CAP)),
+    new, mn, hist = bucket_update_pallas(
+        counts, alive, idx, dec, interpret=_resolve(interpret)
     )
-    return new.astype(counts.dtype), mns[-1], hists[-1]
+    return new.astype(counts.dtype), mn, hist
 
 
 def match_tiles(ka, kb, use_pallas: bool = False,

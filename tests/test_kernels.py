@@ -151,8 +151,11 @@ def test_bucket_update_sweep(n, k, seed):
 
 
 def test_bucket_update_rejects_oversized_batch():
-    """Batches beyond the f32 limb exactness bound must raise; the ops
-    dispatcher feeds them through the kernel in chunks instead."""
+    """Batches beyond the f32 limb exactness bound must raise, in the
+    kernel and in the ops dispatcher (one call is one pass); the
+    peeling loops' ``apply_decrements`` scatter-adds every chunk of
+    such a batch but the last and passes that one to the kernel."""
+    from repro.core.pipeline import apply_decrements
     from repro.kernels import ops
 
     n = 64
@@ -163,20 +166,25 @@ def test_bucket_update_rejects_oversized_batch():
     dec = jnp.ones((k,), jnp.int32)
     with pytest.raises(ValueError, match="MAX_UPDATE_CAP"):
         bucket_update_pallas(c, alive, idx, dec)
-    # the ops dispatcher chunks the batch through the kernel
-    new, mn, hist = ops.bucket_update(c, alive, idx, dec, use_pallas=True)
+    with pytest.raises(ValueError, match="MAX_UPDATE_CAP"):
+        ops.bucket_update(c, alive, idx, dec, use_pallas=True)
+    new, mn, hist = apply_decrements(c, alive, idx, dec, "bucket",
+                                     use_kernel=True)
     assert int(np.asarray(new)[0]) == -k
     assert int(mn) == -k
 
 
-def test_bucket_update_chunks_wide_counts_through_kernel():
-    """The ops dispatcher runs the kernel on any count dtype (values
-    below INT32_MAX) and on batches beyond MAX_UPDATE_CAP, chunk by
-    chunk: bitwise-equal to the jnp reference, result in the caller's
-    dtype."""
+@pytest.mark.parametrize("want_hist", [False, True])
+def test_bucket_update_chunks_wide_counts_through_kernel(want_hist):
+    """``apply_decrements`` runs the kernel on any count dtype (values
+    below INT32_MAX) and on batches beyond MAX_UPDATE_CAP: the chunks
+    before the last as int32 scatter-adds, the last through the kernel.
+    Bitwise-equal to the jnp reference of the whole batch, result in
+    the caller's dtype; a non-final batch (``last`` false) scatters
+    all of it and leaves the min and occupancy as placeholders."""
     import jax
 
-    from repro.kernels import ops
+    from repro.core.pipeline import I32_MAX, apply_decrements
 
     rng = np.random.default_rng(3)
     n, k = 700, 2 * MAX_UPDATE_CAP + 5
@@ -186,11 +194,20 @@ def test_bucket_update_chunks_wide_counts_through_kernel():
         idx = jnp.asarray(rng.integers(0, n + 1, k), jnp.int32)
         dec = jnp.where(idx == n, 0, jnp.asarray(rng.integers(0, 1 << 12, k),
                                                  jnp.int32))
-        got = ops.bucket_update(c, alive, idx, dec, use_pallas=True)
         want = ref.bucket_update_ref(c, alive, idx, dec)
-        assert got[0].dtype == jnp.int64
-        for a, b in zip(got, want):
-            assert np.array_equal(np.asarray(a), np.asarray(b))
+        for last in (True, jnp.array(True), jnp.array(False)):
+            got = apply_decrements(c, alive, idx, dec, "bucket",
+                                   use_kernel=True, want_hist=want_hist,
+                                   last=last)
+            assert got[0].dtype == jnp.int64
+            assert np.array_equal(np.asarray(got[0]), np.asarray(want[0]))
+            if last is True or bool(last):
+                assert int(got[1]) == int(want[1])
+                assert np.array_equal(np.asarray(got[2]), np.asarray(
+                    want[2] if want_hist else jnp.zeros(0, jnp.int32)))
+            else:
+                assert int(got[1]) == I32_MAX
+                assert not np.asarray(got[2]).any()
 
 
 def test_bucket_min_all_dead():

@@ -115,12 +115,15 @@ def _range_rounds(mins):
 
 
 # (Chung-Lu graph, peel_mode, tile_budget): about 0.8-2.5 K edges, whose
-# first sub-round alone streams more triples than a tile holds
+# first sub-round alone streams more triples than a tile holds; a
+# 2,048-triple tile is a 6,144-lane update batch, wider than
+# MAX_UPDATE_CAP, so the sub-round's last one is chunked
 WING_CELL_CASES = {
     "cl1100-range": ((200, 150, 1500, 2.1, 1), "range", None),
     "cl1100-exact": ((200, 150, 1500, 2.1, 1), "exact", None),
     "cl800-range-tile128": ((150, 120, 1200, 2.0, 3), "range", 128),
     "cl2500-range": ((400, 300, 3000, 2.2, 4), "range", None),
+    "cl2500-exact-tile2048": ((400, 300, 3000, 2.2, 4), "exact", 2048),
 }
 
 
@@ -150,7 +153,7 @@ def test_wings_cell_call_matches_oracle(case):
                           else _range_rounds(mins))
     (_p, args, kw), = [p for p in programs
                        if p[0].__name__ == "_peel_wings_device"]
-    assert kw["tile_cap"] == (1024 if budget is None else 128)
+    assert kw["tile_cap"] == (budget or 1024)
     first = per_edge_counts(g) <= mins[0]  # the first sub-round's edges
     assert np.asarray(args[10])[first].sum() > kw["tile_cap"]  # work2
 
