@@ -5,16 +5,9 @@ wedge-aggregation methods; reports ρ (peeling complexity) per graph.
 ``BENCH_peeling.json`` trajectory (schema v3) comparing:
 
   - the host round loop vs the device-resident ``engine="device"``
-    while_loop (wall time, round count ρ, blocking host syncs);
-  - the **fused** tile-streamed frontier subtract vs the PR 2
-    **materializing** expansion (``subtract=`` axis), including
-    compiled peak-temp-memory bytes for both device programs per
-    (graph, algo) — the O(tile) vs O(frontier) story in numbers
-    (``peel_wings`` included since the two-level fused recovery
-    dropped its materialized O(Σ deg²) level-1/level-2 buffers);
-  - the Julienne-style **bucketed** decrease-key vs the PR 2
-    scatter + per-round ``bucket_min`` (``decrease_key=`` axis);
-  - the fixed vs **adaptive** capacity schedule (tail-round cost);
+    while_loop (wall time, round count ρ, blocking host syncs), with
+    the device tip and wing programs' compiled peak-temp-memory bytes
+    per (graph, algo) beside the frontier wedge total they stream;
   - **exact vs bucket-range rounds** (``peel_mode=`` axis, schema v3):
     every row records both ρ (bucket rounds under range mode) and the
     re-settle iteration count ``sub_rounds``; the derived
@@ -44,6 +37,7 @@ from repro.core.peel import (
     _peel_wings_device,
     _pow2_pad,
     _stored_wedge_csr,
+    _tip_tile_cap,
     _wing_work_totals,
     peel_tips,
     peel_tips_stored,
@@ -57,76 +51,39 @@ PEEL_GRAPHS = {
     "peel_medium": lambda: powerlaw_bipartite(3_000, 2_500, 18_000, seed=8),
 }
 
-# Off-TPU, decrease_key="scatter" rows run the bucket_min kernel in
-# interpret mode once per round and pay O(frontier cap) redundant lanes
-# on a CPU backend — rows beyond this budget (or with the 32-probe
-# in-loop hash table) would time the interpreter, not the engine. Same
-# policy as bench_counting's pallas rows: skip visibly, never silently.
-INTERPRET_FRONTIER_BUDGET = 1 << 18
-# decrease_key="bucket" rows run no interpret-mode kernel (the
-# dispatcher serves the jnp reference off-TPU), so they are gated only
-# by total expansion work.
-BUCKET_WORK_BUDGET = 1 << 22
-
-# Device-engine variants: (subtract, decrease_key, capacity_schedule).
-# (materialize, scatter, fixed) is the PR 2 baseline; (fused, scatter)
-# isolates the fused-vs-materializing subtract; (fused, bucket) is the
-# PR 4 default; the adaptive row shows the tail-round capacity win.
-DEVICE_VARIANTS = (
-    ("materialize", "scatter", "fixed"),
-    ("fused", "scatter", "fixed"),
-    ("fused", "bucket", "fixed"),
-    ("fused", "bucket", "adaptive"),
-)
+# Off-TPU the device rows run no interpret-mode kernel (the dispatcher
+# serves the jnp reference), so they are gated only by total expansion
+# work; the in-loop hash table is skipped visibly, never silently.
+WORK_BUDGET = 1 << 22
 
 
-def _tip_workloads(g, side: int):
-    """Worst-case expansion totals used for row gating (mirrors the
-    device planner): level-1 (== m) and level-2 (Σ other-side deg²)."""
+def _tip_lvl2(g, side: int) -> int:
+    """Worst-case level-2 expansion total used for row gating (mirrors
+    the device planner): Σ other-side deg²."""
     du, dv = g.degrees()
     other = du if side == 1 else dv
-    lvl2 = int((other.astype(np.int64) ** 2).sum())
-    return int(g.m), lvl2
+    return int((other.astype(np.int64) ** 2).sum())
 
 
-def _device_row_ok(g, side, agg, subtract, decrease_key):
+def _device_row_ok(g, side, agg):
     if jax.default_backend() == "tpu":
         return True, ""
     if agg != "sort":
         return False, "interpret-mode budget (in-loop hash table)"
-    _, lvl2 = _tip_workloads(g, side)
-    if decrease_key == "scatter":
-        if lvl2 > INTERPRET_FRONTIER_BUDGET:
-            return False, f"interpret-mode budget (frontier cap2={lvl2})"
-        return True, ""
-    if lvl2 > BUCKET_WORK_BUDGET:
+    lvl2 = _tip_lvl2(g, side)
+    if lvl2 > WORK_BUDGET:
         return False, f"work budget (lvl2={lvl2})"
     return True, ""
 
 
-def _wings_workloads(g):
-    """Worst-case wing expansion totals — the device planner's own
-    per-edge totals (`peel._wing_work_totals`), summed."""
-    off, nbr, _ = _csr(g)
-    _, _, l1, l2 = _wing_work_totals(g, off, nbr)
-    return int(l1.sum()), int(l2.sum())
-
-
-def _wings_row_ok(g, subtract, decrease_key):
+def _wings_row_ok(g):
+    """Gated by the total streamed triple work: the device planner's
+    own per-edge totals (`peel._wing_work_totals`), summed."""
     if jax.default_backend() == "tpu":
         return True, ""
-    lvl1, lvl2 = _wings_workloads(g)
-    if subtract == "materialize":
-        # the materializing loop re-expands its fixed-capacity level-1
-        # and triple buffers every round, so CPU rows pay cap x rho_e
-        if lvl1 > INTERPRET_FRONTIER_BUDGET:
-            return False, f"interpret-mode budget (level-1 cap1={lvl1})"
-        if lvl2 > INTERPRET_FRONTIER_BUDGET:
-            return False, f"interpret-mode budget (triple cap2={lvl2})"
-        return True, ""
-    # fused rows have no frontier buffers (two-level recovery): gated
-    # only by the total streamed triple work
-    if lvl2 > BUCKET_WORK_BUDGET:
+    off, nbr, _ = _csr(g)
+    lvl2 = int(_wing_work_totals(g, off, nbr)[3].sum())
+    if lvl2 > WORK_BUDGET:
         return False, f"work budget (triple space lvl2={lvl2})"
     return True, ""
 
@@ -167,120 +124,75 @@ def _tip_inputs(g):
 
 
 def _device_temp_bytes(g, side: int, stored: bool) -> dict:
-    """Compiled peak-temp bytes of the device tip program: fused tile
-    subtract vs the PR 2 materializing expansion (same caps planning as
-    ``peel._peel_tips_device_run``)."""
+    """Compiled peak-temp bytes of the device tip program (same tile
+    planning as ``peel._peel_tips_device_run``)."""
     n_side = g.n_u if side == 0 else g.n_v
     base = 0 if side == 0 else g.n_u
     if stored:
         woff, w_u2 = _stored_wedge_csr(g, side)
-        rows = np.diff(woff)
         lvl2 = int(woff[-1])
         cap1 = 128
         off_d = jnp.asarray(woff, jnp.int32)
         nbr_d = jnp.asarray(w_u2 if lvl2 else np.zeros(1), jnp.int32)
-        work1 = jnp.zeros(n_side, jnp.int32)
-        work2 = jnp.asarray(rows.astype(np.int32))
-        max_row = int(rows.max(initial=0))
+        max_row = int(np.diff(woff).max(initial=0))
     else:
         off, nbr, _ = _csr(g)
-        deg = np.diff(off)
         w2 = _level2_totals(off, nbr, base, n_side)
         lvl2 = int(w2.sum())
-        cap1 = _pow2_pad(int(deg[base : base + n_side].sum()))
+        cap1 = _pow2_pad(int(np.diff(off)[base : base + n_side].sum()))
         off_d = jnp.asarray(off, jnp.int32)
         nbr_d = jnp.asarray(nbr, jnp.int32)
-        work1 = jnp.asarray(deg[base : base + n_side].astype(np.int32))
-        work2 = jnp.asarray(w2.astype(np.int32))
         max_row = int(w2.max(initial=0))
-    from repro.core.peel import _DEFAULT_TILE_TARGET
-
-    tile_cap = _pow2_pad(max(min(_DEFAULT_TILE_TARGET, max(lvl2, 1)),
-                             2 * max_row))
+    tile_cap = _tip_tile_cap(None, lvl2, 2 * max_row)
     dtype = jnp.int64 if jax.config.jax_enable_x64 else jnp.int32
-    st = _init_state(jnp.zeros(n_side, dtype), n_side,
-                     decrease_key="bucket", peel_mode="exact",
-                     lvl1=0, lvl2=0)
-    common = dict(
-        aggregation="sort", cap1=cap1, n_side=n_side, stored=stored,
-        hash_bits=None, decrease_key="bucket", use_kernel=False,
-        adaptive=False,
-    )
-    fused = _peel_tips_device.lower(
-        off_d, nbr_d, jnp.int32(base), work1, work2, st,
-        cap2=128, tile_cap=tile_cap, subtract="fused", **common,
-    ).compile().memory_analysis()
-    mat = _peel_tips_device.lower(
-        off_d, nbr_d, jnp.int32(base), work1, work2, st,
-        cap2=_pow2_pad(lvl2), tile_cap=tile_cap, subtract="materialize",
-        **common,
+    st = _init_state(jnp.zeros(n_side, dtype), n_side, peel_mode="exact")
+    mem = _peel_tips_device.lower(
+        off_d, nbr_d, jnp.int32(base), st, aggregation="sort", cap1=cap1,
+        tile_cap=tile_cap, n_side=n_side, stored=stored,
     ).compile().memory_analysis()
     return {
         "frontier_wedges": lvl2,
         "tile_cap": int(tile_cap),
-        "fused_temp_bytes": int(fused.temp_size_in_bytes),
-        "materialized_temp_bytes": int(mat.temp_size_in_bytes),
-        "temp_ratio": (
-            int(mat.temp_size_in_bytes)
-            / max(int(fused.temp_size_in_bytes), 1)
-        ),
+        "temp_bytes": int(mem.temp_size_in_bytes),
     }
 
 
 def _wings_temp_bytes(g) -> dict:
     """Compiled peak-temp bytes of the device wing program: the
-    two-level fused recovery (no materialized buffers) vs the
-    materializing expansion whose level-1/triple capacities scale with
-    O(Σ deg²)-class totals (same planning as
-    ``peel._peel_wings_device_run``)."""
+    two-level fused recovery holds no buffer sized by the O(Σ deg²)
+    triple space (same planning as ``peel._peel_wings_device_run``)."""
     from repro.core.peel import _DEFAULT_TILE_TARGET
 
     off, nbr, uid = _csr(g)
     m = g.m
     eu, ev, l1, l2 = _wing_work_totals(g, off, nbr)
-    lvl1, lvl2 = int(l1.sum()), int(l2.sum())
+    lvl2 = int(l2.sum())
     nbr_ds, uid_ds, degs_ds, cumdeg = degree_sorted_csr(off, nbr, uid)
     tile_cap = _pow2_pad(min(_DEFAULT_TILE_TARGET, max(lvl2, 1)))
     dtype = jnp.int64 if jax.config.jax_enable_x64 else jnp.int32
-    st = _init_state(jnp.zeros(m, dtype), m, decrease_key="bucket",
-                     peel_mode="exact", lvl1=0, lvl2=0)
+    st = _init_state(jnp.zeros(m, dtype), m, peel_mode="exact")
     args = tuple(
         jnp.asarray(a if np.asarray(a).size else np.zeros(1), jnp.int32)
         for a in (off, nbr, uid, eu, ev, nbr_ds, uid_ds, degs_ds, cumdeg,
                   l1, l2)
     )
-    common = dict(
-        aggregation="sort", m=m, hash_bits=None, decrease_key="bucket",
-        use_kernel=False, adaptive=False,
-    )
-    fused = _peel_wings_device.lower(
-        *args, st, cap1=128, cap2=128, tile_cap=tile_cap,
-        subtract="fused", **common,
-    ).compile().memory_analysis()
-    mat = _peel_wings_device.lower(
-        *args, st, cap1=_pow2_pad(lvl1), cap2=_pow2_pad(lvl2),
-        tile_cap=tile_cap, subtract="materialize", **common,
+    mem = _peel_wings_device.lower(
+        *args, st, aggregation="sort", m=m, tile_cap=tile_cap,
     ).compile().memory_analysis()
     return {
         "frontier_wedges": lvl2,
         "tile_cap": int(tile_cap),
-        "fused_temp_bytes": int(fused.temp_size_in_bytes),
-        "materialized_temp_bytes": int(mat.temp_size_in_bytes),
-        "temp_ratio": (
-            int(mat.temp_size_in_bytes)
-            / max(int(fused.temp_size_in_bytes), 1)
-        ),
+        "temp_bytes": int(mem.temp_size_in_bytes),
     }
 
 
 def write_json(path, graphs=("peel_small",), repeats: int = 1) -> dict:
     """Peeling engine trajectory (schema v3): per (graph, algo, engine,
-    aggregation, subtract, decrease_key, schedule, peel_mode) wall
-    time, rounds (bucket rounds under ``peel_mode="range"``),
-    re-settle ``sub_rounds``, and host-sync count; compiled
-    fused-vs-materializing peak-temp bytes per (graph, algo) incl. the
-    wing engine; derived fused-vs-PR2 speedups and the range-mode ρ
-    reduction (with a bitwise-parity check against the exact rows).
+    aggregation, peel_mode) wall time, rounds (bucket rounds under
+    ``peel_mode="range"``), re-settle ``sub_rounds``, and host-sync
+    count; compiled peak-temp bytes of the device programs per (graph,
+    algo); the derived range-mode ρ reduction (with a bitwise-parity
+    check against the exact rows).
     Wall times exclude the butterfly counting pass (counts are
     precomputed once per graph — the decomposition loop is what the
     engines differ on). ``path=None`` builds the payload without
@@ -295,16 +207,13 @@ def write_json(path, graphs=("peel_small",), repeats: int = 1) -> dict:
         "skipped": [],
     }
 
-    def add_row(gname, algo, engine, agg, subtract, decrease_key,
-                schedule, res, syncs, wall, peel_mode="exact"):
+    def add_row(gname, algo, engine, agg, res, syncs, wall,
+                peel_mode="exact"):
         payload["runs"].append({
             "graph": gname,
             "algo": algo,
             "engine": engine,
             "aggregation": agg,
-            "subtract": subtract,
-            "decrease_key": decrease_key,
-            "schedule": schedule,
             "peel_mode": peel_mode,
             "rounds": int(res.rounds),
             "sub_rounds": int(
@@ -315,14 +224,12 @@ def write_json(path, graphs=("peel_small",), repeats: int = 1) -> dict:
             "wall_s": wall,
         })
 
-    def skip(gname, algo, engine, agg, subtract, decrease_key, reason):
+    def skip(gname, algo, engine, agg, reason):
         payload["skipped"].append({
             "graph": gname,
             "algo": algo,
             "engine": engine,
             "aggregation": agg,
-            "subtract": subtract,
-            "decrease_key": decrease_key,
             "reason": reason,
         })
 
@@ -334,23 +241,22 @@ def write_json(path, graphs=("peel_small",), repeats: int = 1) -> dict:
         plus the derived ρ-reduction bookkeeping vs the exact rows."""
         res, syncs = _count_host_syncs(run_host)
         t = _time_warm(run_host, repeats=repeats)
-        add_row(gname, algo, "host", "sort", "fused", "host", "fixed",
-                res, syncs, t, peel_mode="range")
+        add_row(gname, algo, "host", "sort", res, syncs, t,
+                peel_mode="range")
         equal = bool(np.array_equal(res.numbers, ref_res.numbers))
         rng_rounds = int(res.rounds)
         ok, reason = device_ok
         if ok:
             dres, syncs = _count_host_syncs(run_device)
             t = _time_warm(run_device, repeats=repeats)
-            add_row(gname, algo, "device", "sort", "fused", "bucket",
-                    "fixed", dres, syncs, t, peel_mode="range")
+            add_row(gname, algo, "device", "sort", dres, syncs, t,
+                    peel_mode="range")
             equal = equal and bool(
                 np.array_equal(dres.numbers, ref_res.numbers)
             )
             rng_rounds = int(dres.rounds)
         else:
-            skip(gname, algo, "device", "sort", "fused", "bucket",
-                 reason)
+            skip(gname, algo, "device", "sort", reason)
         range_info[f"{gname}/{algo}"] = {
             "exact_rho": int(ref_res.rounds),
             "range_rho": rng_rounds,
@@ -369,42 +275,23 @@ def write_json(path, graphs=("peel_small",), repeats: int = 1) -> dict:
             ("peel_tips", peel_tips),
             ("peel_tips_stored", peel_tips_stored),
         ):
-            ref_res = None  # host (sort, fused) exact run: parity ref
-            # host engine: fused (default) vs materializing subtract
-            for agg in ("sort", "hash"):
-                for subtract in ("fused", "materialize"):
-                    if agg == "hash" and subtract == "materialize":
-                        continue  # matrix corner adds no information
+            ref_res = None  # host sort exact run: parity ref
+            for engine in ("host", "device"):
+                for agg in ("sort", "hash"):
+                    if engine == "device":
+                        ok, reason = _device_row_ok(g, side, agg)
+                        if not ok:
+                            skip(gname, algo, engine, agg, reason)
+                            continue
                     run = lambda: fn(  # noqa: E731
                         g, counts=counts, side=side, aggregation=agg,
-                        engine="host", subtract=subtract,
-                    )
-                    res, syncs = _count_host_syncs(run)
-                    if agg == "sort" and subtract == "fused":
-                        ref_res = res
-                    t = _time_warm(run, repeats=repeats)
-                    add_row(gname, algo, "host", agg, subtract, "host",
-                            "fixed", res, syncs, t)
-            # device engine: the variant matrix
-            for agg in ("sort", "hash"):
-                for subtract, dk, schedule in DEVICE_VARIANTS:
-                    if agg == "hash" and (subtract, dk, schedule) != (
-                            "fused", "bucket", "fixed"):
-                        continue
-                    ok, reason = _device_row_ok(g, side, agg, subtract, dk)
-                    if not ok:
-                        skip(gname, algo, "device", agg, subtract, dk,
-                             reason)
-                        continue
-                    run = lambda: fn(  # noqa: E731
-                        g, counts=counts, side=side, aggregation=agg,
-                        engine="device", subtract=subtract,
-                        decrease_key=dk, capacity_schedule=schedule,
+                        engine=engine,
                     )
                     res, syncs = _count_host_syncs(run)  # also warms jit
+                    if engine == "host" and agg == "sort":
+                        ref_res = res
                     t = _time_warm(run, repeats=repeats)
-                    add_row(gname, algo, "device", agg, subtract, dk,
-                            schedule, res, syncs, t)
+                    add_row(gname, algo, engine, agg, res, syncs, t)
             # peel_mode="range": bucket rounds, bitwise-equal numbers
             range_rows(
                 gname, algo,
@@ -412,7 +299,7 @@ def write_json(path, graphs=("peel_small",), repeats: int = 1) -> dict:
                            peel_mode="range"),
                 lambda: fn(g, counts=counts, side=side, engine="device",
                            peel_mode="range"),
-                _device_row_ok(g, side, "sort", "fused", "bucket"),
+                _device_row_ok(g, side, "sort"),
                 ref_res,
             )
             payload["memory"].append({
@@ -429,29 +316,23 @@ def write_json(path, graphs=("peel_small",), repeats: int = 1) -> dict:
         run = lambda: peel_wings(g, counts=ecounts)  # noqa: E731
         wres, syncs = _count_host_syncs(run)
         t = _time_warm(run, repeats=repeats)
-        add_row(gname, "peel_wings", "host", "sort", "fused", "host",
-                "fixed", wres, syncs, t)
-        for subtract, dk, schedule in DEVICE_VARIANTS:
-            ok, reason = _wings_row_ok(g, subtract, dk)
-            if not ok:
-                skip(gname, "peel_wings", "device", "sort", subtract, dk,
-                     reason)
-                continue
+        add_row(gname, "peel_wings", "host", "sort", wres, syncs, t)
+        ok, reason = _wings_row_ok(g)
+        if ok:
             run = lambda: peel_wings(  # noqa: E731
-                g, counts=ecounts, engine="device", subtract=subtract,
-                decrease_key=dk, capacity_schedule=schedule,
-            )
+                g, counts=ecounts, engine="device")
             res, syncs = _count_host_syncs(run)
             t = _time_warm(run, repeats=repeats)
-            add_row(gname, "peel_wings", "device", "sort", subtract, dk,
-                    schedule, res, syncs, t)
+            add_row(gname, "peel_wings", "device", "sort", res, syncs, t)
+        else:
+            skip(gname, "peel_wings", "device", "sort", reason)
         range_rows(
             gname, "peel_wings",
             lambda: peel_wings(g, counts=ecounts, engine="host",
                                peel_mode="range"),
             lambda: peel_wings(g, counts=ecounts, engine="device",
                                peel_mode="range"),
-            _wings_row_ok(g, "fused", "bucket"),
+            _wings_row_ok(g),
             wres,
         )
         payload["memory"].append({
@@ -460,37 +341,7 @@ def write_json(path, graphs=("peel_small",), repeats: int = 1) -> dict:
             **_wings_temp_bytes(g),
         })
 
-    # derived: the ISSUE 4 acceptance comparisons (device, sort rows)
-    def _wall(gname, algo, subtract, dk, schedule="fixed"):
-        for r in payload["runs"]:
-            if (r["graph"], r["algo"], r["engine"], r["aggregation"],
-                    r["subtract"], r["decrease_key"], r["schedule"],
-                    r["peel_mode"]) == (
-                    gname, algo, "device", "sort", subtract, dk, schedule,
-                    "exact"):
-                return r["wall_s"]
-        return None
-
-    for gname in graphs:
-        for algo in ("peel_tips", "peel_tips_stored", "peel_wings"):
-            pr2 = _wall(gname, algo, "materialize", "scatter")
-            f_sc = _wall(gname, algo, "fused", "scatter")
-            f_bk = _wall(gname, algo, "fused", "bucket")
-            f_ad = _wall(gname, algo, "fused", "bucket", "adaptive")
-            d = {}
-            if pr2 and f_sc:
-                d["fused_vs_materializing_speedup"] = pr2 / f_sc
-            if f_sc and f_bk:
-                d["bucketed_vs_scatter_speedup"] = f_sc / f_bk
-            if pr2 and f_bk:
-                d["fused_default_vs_pr2_speedup"] = pr2 / f_bk
-                d["fused_no_slower_than_pr2"] = f_bk <= pr2
-            if f_bk and f_ad:
-                d["adaptive_vs_fixed_speedup"] = f_bk / f_ad
-            key = f"{gname}/{algo}"
-            d.update(range_info.get(key, {}))
-            if d:
-                payload["derived"][key] = d
+    payload["derived"] = range_info
     if path:
         with open(path, "w") as f:
             json.dump(payload, f, indent=2)
@@ -575,7 +426,6 @@ def main(argv=None):
     for r in payload["runs"]:
         emit(
             f"{r['algo']}/{r['graph']}/{r['aggregation']}/{r['engine']}/"
-            f"{r['subtract']}/{r['decrease_key']}/{r['schedule']}/"
             f"{r['peel_mode']}",
             r["wall_s"] * 1e6,
             f"rho={r['rounds']},sub={r['sub_rounds']},"
@@ -583,8 +433,7 @@ def main(argv=None):
         )
     for s in payload["skipped"]:
         emit(
-            f"{s['algo']}/{s['graph']}/{s['aggregation']}/{s['engine']}/"
-            f"{s['subtract']}/{s['decrease_key']}",
+            f"{s['algo']}/{s['graph']}/{s['aggregation']}/{s['engine']}",
             -1.0,
             f"SKIPPED:{s['reason']}",
         )
@@ -592,9 +441,8 @@ def main(argv=None):
         emit(
             f"{row['algo']}/{row['graph']}/temp_bytes",
             0.0,
-            f"fused={row['fused_temp_bytes']},"
-            f"materialized={row['materialized_temp_bytes']},"
-            f"ratio={row['temp_ratio']:.1f}",
+            f"temp={row['temp_bytes']},"
+            f"frontier_wedges={row['frontier_wedges']}",
         )
     if args.faults and args.json:
         append_resilience_rows(args.json, graphs=tuple(args.graphs))

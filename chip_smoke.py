@@ -260,14 +260,16 @@ def phase_peel(g):
 
 
 def phase_oracle(g):
+    import jax
     import jax.numpy as jnp
     import numpy as np
 
     from repro.core import count_butterflies
     from repro.core.oracle import global_count, per_edge_counts
     from repro.core.oracle import per_vertex_counts
-    from repro.core.peel import peel_tips
+    from repro.core.peel import peel_wings
     from repro.core.pipeline import record_programs
+    from repro.kernels import ops
 
     total = global_count(g)
     pu, pv = per_vertex_counts(g)
@@ -286,15 +288,32 @@ def phase_oracle(g):
               and np.array_equal(r.per_edge, pe),
               f"{name}: differs from core/oracle")
         say(f"{name}: total={total}; oracle parity ok")
-    counts = pu.astype(np.int32)
-    with Phase("oracle/peel_tips/scatter"), record_programs() as p:
-        d = peel_tips(g, counts=counts, side=0, engine="device",
-                      decrease_key="scatter")
-    require_no_descent("oracle/peel_tips/scatter", d.report)
-    require_kernels("oracle/peel_tips/scatter", p, {"bucket_min"})
-    h = peel_tips(g, counts=counts, side=0, engine="host")
-    check(same(d.numbers, h.numbers), "oracle peel_tips: device != host")
-    say("oracle/peel_tips/scatter: parity ok (device == host bitwise)")
+    # the host wing engine's per-round extract-min is the one caller of
+    # the bucket_min kernel: record each call as a program to compile
+    counts = pe.astype(np.int32)
+    real_min = ops.bucket_min
+    mins = []
+
+    def recorded_min(b, alive, use_pallas=False, interpret=None):
+        mins.append((jax.jit(real_min, static_argnames=("use_pallas",)),
+                     (b, alive), {"use_pallas": use_pallas}))
+        return real_min(b, alive, use_pallas=use_pallas, interpret=interpret)
+
+    ops.bucket_min = recorded_min
+    try:
+        with Phase("oracle/peel_wings/host"):
+            h = peel_wings(g, counts=counts, engine="host")
+    finally:
+        ops.bucket_min = real_min
+    require_no_descent("oracle/peel_wings/host", h.report)
+    check(mins and all(kw["use_pallas"] for _f, _a, kw in mins),
+          "oracle/peel_wings/host: extract-min did not take the kernel")
+    require_kernels("oracle/peel_wings/host", mins[:1], {"bucket_min"})
+    d = peel_wings(g, counts=counts, engine="device")
+    require_no_descent("oracle/peel_wings/device", d.report)
+    check(same(d.numbers, h.numbers), "oracle peel_wings: device != host")
+    say(f"oracle/peel_wings/host: {len(mins)} bucket_min calls; parity ok "
+        f"(host == device bitwise)")
 
 
 def phase_service(peel_g, peel_total, side, tips):

@@ -17,8 +17,8 @@ extract-min + batch decrease-key are preserved; Julienne's
 skip-empty-buckets optimization is inherent (min jumps gaps in O(1)
 rounds).
 
-Engine matrix
--------------
+Engines
+-------
 Every decomposition — tips (PEEL-V, Alg. 5), stored-wedge tips
 (WPEEL-V, Alg. 7), and wings (PEEL-E, Alg. 6) — supports
 ``engine="host"|"device"``:
@@ -28,74 +28,50 @@ Every decomposition — tips (PEEL-V, Alg. 5), stored-wedge tips
     numpy prefix-sum wedge expansion, device aggregation/subtraction.
   - **device** — the whole round loop is one jitted
     ``jax.lax.while_loop``; nothing leaves the device until the final
-    ``PeelResult`` fetch (a single ``device_get`` under the fixed
-    capacity schedule). Extract-min is the ``bucket_min`` Pallas
-    kernel, or the min carried out of the previous round's bucketed
-    decrease-key (see below).
+    ``PeelResult`` fetch (one ``device_get`` per decomposition).
 
-and a ``subtract="fused"|"materialize"`` axis:
+Both engines stream each round's frontier wedge space through
+iterating-endpoint-aligned tiles that are generated
+(``wedges.ragged_slots_at`` recovery), aggregated tile-locally through
+the *same* ``count._fused_tile_apply`` machinery as the fused counting
+engine (in-graph hash-overflow sort fallback included), subtracted,
+and discarded. Peak per-round temp is O(tile) — asserted by the
+compiled ``memory_analysis()`` regression in tests — and per-round
+device work tracks the *actual* frontier size. Tile boundaries cut
+only at peeled-vertex boundaries (``wedges.aligned_tile_end``), the
+``plan_wedge_chunks`` invariant, so no endpoint-pair group spans a
+tile and the per-tile C(d, 2) subtractions are exact. WPEEL-V
+recovers its tiles straight from the stored-wedge CSR (no per-round
+frontier buffer); PEEL-V keeps only its level-1 buffer (O(Σ deg_side)
+= O(m)) and tiles the dominant level-2 space; PEEL-E recovers its
+per-butterfly triple space straight from flat ids via the
+degree-sorted CSR (two chained binary searches plus a division —
+``wedges.degree_sorted_csr``).
 
-  - **materialize** (the PR 2 behavior) — expand the round's whole
-    frontier wedge space into fixed-capacity buffers, aggregate once,
-    subtract once. Peak per-round temp is O(frontier capacity).
-  - **fused** (default) — stream the frontier wedge space through
-    iterating-endpoint-aligned tiles that are generated
-    (``wedges.ragged_slots_at`` recovery), aggregated tile-locally
-    through the *same* ``count._fused_tile_apply`` machinery as the
-    fused counting engine (in-graph hash-overflow sort fallback
-    included), subtracted, and discarded. Peak per-round temp is
-    O(tile) — asserted by the compiled ``memory_analysis()``
-    regression in tests — and per-round device work tracks the
-    *actual* frontier size instead of the planned worst-case
-    capacity. Tile boundaries cut only at peeled-vertex boundaries
-    (``wedges.aligned_tile_end``), the ``plan_wedge_chunks``
-    invariant, so no endpoint-pair group spans a tile and the per-tile
-    C(d, 2) subtractions are exact. For WPEEL-V this removes the
-    per-round frontier buffer entirely (tiles are recovered straight
-    from the stored-wedge CSR); PEEL-V keeps only its level-1 buffer
-    (O(Σ deg_side) = O(m)) and tiles the dominant level-2 space;
-    PEEL-E recovers its per-butterfly triple space straight from flat
-    ids via the degree-sorted CSR (two chained binary searches plus a
-    division — ``wedges.degree_sorted_csr``), dropping the materialized
-    O(Σ deg²) level-1/level-2 buffers the PR 4 engine carried.
+The device engine's decrease-key is Julienne-style and batched
+(``pipeline.apply_decrements``): a sub-round's decrements are exact
+int32 scatter-adds into the counts, carried as int32 through its tile
+stream, except its last update chunk of at most ``MAX_UPDATE_CAP``
+lanes, which goes through ``kernels.ops.bucket_update``; that ONE
+kernel pass a sub-round yields the next round's masked min and the
+O(log n) geometric-bucket occupancy of the final counts, so the round
+loop needs no separate extract-min reduction (the carried min seeds
+κ). The Pallas kernel runs compiled on TPU; elsewhere the dispatcher
+serves the jnp reference (off-TPU the per-round kernel interpreter
+would dominate, the same policy as ``peel_wings``'s host extract-min).
 
 Further device-engine knobs:
 
-  - ``decrease_key="bucket"|"scatter"`` — "scatter" is the PR 2
-    one-scatter-per-round subtract plus a separate ``bucket_min``
-    reduction at the top of the next round. "bucket" (default) is the
-    Julienne-style batched decrease-key (``pipeline.apply_decrements``):
-    a sub-round's decrements are exact int32 scatter-adds into the
-    counts, carried as int32 through its tile stream, except its last
-    update chunk of at most ``MAX_UPDATE_CAP`` lanes, which goes through
-    ``kernels.ops.bucket_update``; that ONE kernel pass a sub-round
-    yields the next round's masked min and the O(log n) geometric-bucket
-    occupancy of the final counts — the separate per-round extract-min
-    reduction disappears (the carried min seeds κ). Both produce
-    bitwise-identical numbers (integer scatter sums commute). The
-    Pallas kernel runs compiled on TPU; elsewhere the dispatcher serves
-    the jnp reference (off-TPU the per-round kernel interpreter would
-    dominate, the same policy as ``peel_wings``'s host extract-min).
-  - ``capacity_schedule="fixed"|"adaptive"`` — "fixed" plans every
-    frontier capacity once from round-0 worst-case totals (one
-    ``device_get`` per decomposition). "adaptive" shrinks the planned
-    expansion buffers geometrically as the graph empties: the loop
-    carries exact remaining-work bounds (Σ per-vertex expansion totals
-    over alive), exits when the bound falls to a quarter of a planned
-    capacity, and re-enters with pow2-shrunk buffers — O(log cap)
-    segments, one ``device_get`` each, cutting the O(cap) redundant
-    lanes that dominate tail rounds. Results are bitwise-identical to
-    the fixed schedule (the carried state is exact).
-  - ``tile_budget`` — wedge budget per fused-subtract tile. The
-    default target is deliberately small (1024; the planner floors it
-    by the largest single-vertex expansion so tiles always align):
-    unlike counting, peeling pays the full tile shape every round, so
-    memory-derived budgets would dominate tail rounds.
-  - ``max_frontier`` bounds the materializing/level-1 expansion
-    buffers; a too-small capacity raises an in-graph overflow flag and
-    the caller transparently re-runs the host path — never a silent
-    truncation. Counts at or beyond INT32_MAX also route to the host
-    engine (``bucket_min`` reduces in int32).
+  - ``tile_budget`` — wedge budget per tile. The default target is
+    deliberately small (1024; the planner floors it by the largest
+    single-vertex expansion so tiles always align): unlike counting,
+    peeling pays the full tile shape every round, so memory-derived
+    budgets would dominate tail rounds.
+  - ``max_frontier`` (``peel_tips`` only) bounds PEEL-V's level-1
+    expansion buffer; a too-small capacity raises an in-graph overflow
+    flag and the caller transparently re-runs the host path — never a
+    silent truncation. Counts at or beyond INT32_MAX also route to the
+    host engine (``bucket_update`` reduces in int32).
 
 The hash-aggregation overflow fallback is **in-graph** for both
 engines: ``lax.cond`` re-aggregates the same materialized wedge tile
@@ -111,19 +87,18 @@ Every decomposition and engine supports ``peel_mode="exact"|"range"``:
   - **range** — Julienne/Lakhotia-style bucket-range rounds ("Parallel
     Peeling of Bipartite Networks", Lakhotia et al. 2021): each round
     selects the **lowest non-empty geometric bucket** ``[2^(k-1), 2^k)``
-    and processes it to completion. Under ``decrease_key="bucket"`` the
-    selection consumes the O(log n) occupancy histogram that each
-    sub-round's one ``bucket_update`` pass already produces (previously
-    computed and dead-code-eliminated); under
-    ``"scatter"`` (and on the host engine) the bucket is derived from
-    the masked min's bit length — the two selections provably agree,
-    because the min inhabits the lowest non-empty range. Final
-    tip/wing numbers are **bitwise-identical** to exact peeling: the
-    in-graph *re-settle* iterations within a bucket round replay the
-    exact κ trajectory (peel ``<= κ``, subtract, advance κ) until the
-    masked min leaves the bucket — fall-ins (survivors whose count
-    drops into the active range mid-round) are caught by the same
-    test. ``PeelResult.rounds`` counts bucket rounds — the
+    and processes it to completion. The device engine's selection
+    consumes the O(log n) occupancy histogram that each sub-round's
+    one ``bucket_update`` pass already produces; the host engine
+    derives the bucket from the masked min's bit length — the two
+    selections provably agree, because the min inhabits the lowest
+    non-empty range. Final tip/wing numbers are **bitwise-identical**
+    to exact peeling: the in-graph *re-settle* iterations within a
+    bucket round replay the exact κ trajectory (peel ``<= κ``,
+    subtract, advance κ) until the masked min leaves the bucket —
+    fall-ins (survivors whose count drops into the active range
+    mid-round) are caught by the same test. ``PeelResult.rounds``
+    counts bucket rounds — the
     sync/parallel-round metric that range processing slashes on
     high-ρ graphs — and ``PeelResult.sub_rounds`` keeps the re-settle
     iteration count (== exact mode's ρ) so the trade stays measurable
@@ -132,22 +107,19 @@ Every decomposition and engine supports ``peel_mode="exact"|"range"``:
 Shared round-loop substrate
 ---------------------------
 Both jitted device engines are thin parameterizations of one substrate
-(the tips and wings loops previously each carried their own copy):
+in ``core/pipeline.py``:
 
-  - ``_device_round_loop`` — the ``lax.while_loop`` round skeleton:
-    carried-min/extract-min, κ update, exact-vs-range round
-    accounting, peel-set selection, adaptive remaining-work tracking,
-    and the overflow latch, parameterized by an ``expand`` callable
-    that turns one round's peel set into count decrements.
-  - ``_stream_tiles`` — the fused-subtract tile ``while_loop``:
-    streams a flat per-round id space through fixed-shape tiles
+  - ``device_round_loop`` — the ``lax.while_loop`` round skeleton:
+    carried extract-min, κ update, exact-vs-range round accounting,
+    peel-set selection, and the overflow latch, parameterized by an
+    ``expand`` callable that turns one round's peel set into count
+    decrements.
+  - ``stream_tiles`` — the tile ``while_loop``: streams a flat
+    per-round id space through fixed-shape tiles
     (iterating-endpoint-aligned for the C(d, 2) tip subtract,
     unaligned for the linear wing subtract), parameterized by a
     per-tile recover/subtract callable; re-derives the carried
     (min, occupancy) on zero-frontier rounds.
-  - ``_drive_segments`` — the host-side capacity-segment driver: one
-    ``device_get`` per segment, geometric cap shrinking under the
-    adaptive schedule, ``None`` on overflow (host-engine fallback).
 
 Double-count avoidance (paper §4.3.1/§4.3.2): peeled-set members are
 processed against a virtual rank order (their id); an element of the
@@ -182,12 +154,9 @@ from .pipeline import (
     plan_peel as _plan_peel,
     apply_decrements as _apply_decrements,
     device_round_loop as _device_round_loop,
-    drive_segments as _drive_segments,
-    empty_hist as _empty_hist,
     fetch as _fetch,
     init_loop_state as _init_state,
     launch as _launch,
-    masked_state as _masked_state,
     prefix_offsets as _prefix,
     scope as _scope,
     span as _span,
@@ -198,7 +167,6 @@ from .pipeline import (
 from .wedges import (
     Wedges,
     _lower_bound_ragged,
-    aligned_tile_end,
     degree_sorted_csr,
     expand_ragged,
     greedy_vertex_blocks,
@@ -212,19 +180,13 @@ __all__ = [
     "peel_wings",
     "peel_validator",
     "PEEL_ENGINES",
-    "PEEL_SUBTRACTS",
-    "PEEL_DECREASE_KEYS",
-    "PEEL_SCHEDULES",
     "PEEL_MODES",
 ]
 
 PEEL_ENGINES = ("host", "device")
-PEEL_SUBTRACTS = ("fused", "materialize")
-PEEL_DECREASE_KEYS = ("bucket", "scatter")
-PEEL_SCHEDULES = ("fixed", "adaptive")
 PEEL_MODES = ("exact", "range")
 
-# Default fused-subtract tile target. Unlike counting — which streams
+# Default tile target of the peeling subtract. Unlike counting — which streams
 # the whole wedge space through its tiles ONCE and wants them as large
 # as memory allows (auto_chunk_budget) — peeling pays the full tile
 # shape EVERY round regardless of the actual frontier size, so the
@@ -322,8 +284,7 @@ def _level2_totals(off: np.ndarray, nbr: np.ndarray, base: int,
     """Per-vertex 2-hop expansion totals: w2[u] = Σ_{v in N(u)} deg(v).
 
     The exact per-round frontier bound of PEEL-V's level-2 space —
-    feeds fused-tile alignment floors and the adaptive capacity
-    schedule's remaining-work tracking."""
+    feeds the tile alignment floors and the plan's entity tiles."""
     deg = np.diff(off)
     ids = np.arange(n_side) + base
     d1 = deg[ids]
@@ -340,23 +301,19 @@ def _subtract_tile(
     u2: jax.Array,
     valid: jax.Array,
     b: jax.Array,
-    alive: Optional[jax.Array],
+    apply,
     *,
     aggregation: str,
     n_side: int,
     hash_bits: Optional[int] = None,
-    decrease_key: str = "scatter",
-    use_kernel: bool = False,
-    want_hist: bool = False,
-    last=True,
 ):
     """Aggregate one tile of (u1, u2) frontier wedge pairs and subtract
     C(d, 2) from B[u2] — the peeling side of the shared fused tile
     machinery (``count._fused_tile_apply``: tile-local sort/hash with
-    the in-graph hash-overflow sort fallback). Returns
-    ``(b, min, hist)`` (min/hist meaningful under
-    ``decrease_key="bucket"`` on the sub-round's ``last`` tile only;
-    hist only when ``want_hist``).
+    the in-graph hash-overflow sort fallback). ``apply(b, tgt, dec)``
+    applies the grouped decrements — the device loops' batched
+    decrease-key (``_apply_decrements``) or the host engines' plain
+    scatter — and its result is returned.
     """
     sent = jnp.int32(n_side)
     w = Wedges(
@@ -375,19 +332,23 @@ def _subtract_tile(
         c2 = jnp.where(d % 2 == 0, d // 2 * (d - 1), (d - 1) // 2 * d)
         dec = jnp.where(groups.valid, c2, 0)
         tgt = jnp.where(groups.valid, groups.x2, sent)
-        return _apply_decrements(b, alive, tgt, dec, decrease_key,
-                                 use_kernel, want_hist, last)
+        return apply(b, tgt, dec)
 
     out, _ok = _fused_tile_apply(w, aggregation, consume, "xla", hash_bits)
     return out
 
 
+def _scatter_decrements(b, tgt, dec):
+    """The host engines' subtract: one scatter-add of the decrements."""
+    return b.at[tgt].add(-dec)
+
+
 _subtract_pair_groups = jax.jit(
     lambda u1, u2, valid, b, aggregation, n_pad, hash_bits=None: (
         _subtract_tile(
-            u1, u2, valid, b, None, aggregation=aggregation, n_side=n_pad,
-            hash_bits=hash_bits, decrease_key="scatter", use_kernel=False,
-        )[0]
+            u1, u2, valid, b, _scatter_decrements, aggregation=aggregation,
+            n_side=n_pad, hash_bits=hash_bits,
+        )
     ),
     static_argnames=("aggregation", "n_pad", "hash_bits"),
 )
@@ -402,30 +363,23 @@ def _subtract_triples(idx: jax.Array, valid: jax.Array, b: jax.Array):
 
 
 def _host_subtract_frontier(
-    b_dev, u1_w, u2_w, n_side, aggregation, hash_bits, subtract, tile_cap
+    b_dev, u1_w, u2_w, n_side, aggregation, hash_bits, tile_cap
 ):
     """Host-engine frontier subtract: stream the round's (ascending-u1)
-    wedge pairs to the device in u1-aligned tiles (``subtract="fused"``
-    — O(tile) device temp, one fixed jit shape for the whole
-    decomposition) or as one pow2-padded buffer (``"materialize"`` —
-    the PR 2 behavior, O(frontier) temp)."""
-    if subtract == "materialize":
-        bounds = np.array([0, u1_w.size], dtype=np.int64)
-    else:
-        run_ends = np.flatnonzero(np.diff(u1_w)) + 1
-        row_off = np.concatenate([[0], run_ends, [u1_w.size]])
-        row_lens = np.diff(row_off)
-        vb, _ = greedy_vertex_blocks(
-            row_lens, row_lens.size, target=tile_cap
-        )
-        bounds = row_off[vb]
+    wedge pairs to the device in u1-aligned tiles — O(tile) device
+    temp, one fixed jit shape for the whole decomposition."""
+    run_ends = np.flatnonzero(np.diff(u1_w)) + 1
+    row_off = np.concatenate([[0], run_ends, [u1_w.size]])
+    row_lens = np.diff(row_off)
+    vb, _ = greedy_vertex_blocks(row_lens, row_lens.size, target=tile_cap)
+    bounds = row_off[vb]
     for ws, we in zip(bounds[:-1], bounds[1:]):
         size = int(we - ws)
         if size == 0:
             continue
-        # pad each block to its own pow2 (still <= tile_cap under
-        # "fused"): tail rounds pay their actual size, and the jit
-        # cache stays O(log tile_cap) entries
+        # pad each block to its own pow2 (still <= tile_cap): tail
+        # rounds pay their actual size, and the jit cache stays
+        # O(log tile_cap) entries
         cap = _pow2_pad(size)
         u1p = np.full(cap, n_side, np.int32)
         u2p = np.full(cap, n_side, np.int32)
@@ -445,61 +399,55 @@ def _host_subtract_frontier(
     return b_dev
 
 
-# ---------------------------------------------------------------------------
-# Device round loops: the shared substrate (LoopState / stream_tiles /
-# device_round_loop / drive_segments) lives in core/pipeline.py and is
-# imported above under its pre-pipeline names; the engines below only
-# parameterize it with their expansion callables.
-# ---------------------------------------------------------------------------
+def _tip_tile_cap(tile_budget: Optional[int], total: int,
+                  floor: int) -> int:
+    """Tile shape of the tip engines: the ``tile_budget`` target (at
+    most the whole expansion ``total``), floored by the largest
+    single-vertex expansion so tiles always align."""
+    tb = _DEFAULT_TILE_TARGET if tile_budget is None else int(tile_budget)
+    return _pow2_pad(max(min(tb, max(total, 1)), floor))
 
 
 # ---------------------------------------------------------------------------
-# Device-resident tip engine: the substrate with 2-hop / stored recovery
+# Device-resident tip engine: the pipeline's round-loop substrate with
+# 2-hop / stored recovery
 # ---------------------------------------------------------------------------
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "aggregation", "cap1", "cap2", "tile_cap", "n_side", "stored",
-        "hash_bits", "subtract", "decrease_key", "use_kernel", "adaptive",
-        "peel_mode",
+        "aggregation", "cap1", "tile_cap", "n_side", "stored", "hash_bits",
+        "use_kernel", "peel_mode",
     ),
 )
 def _peel_tips_device(
     off: jax.Array,  # stored: (n_side+1,) wedge CSR | else (n+1,) graph CSR
     nbr: jax.Array,  # stored: (W,) second endpoints | else (2m,) neighbors
     base: jax.Array,  # () int32 global-id offset of the peeled side
-    work1: jax.Array,  # (n_side,) per-vertex level-1 expansion totals
-    work2: jax.Array,  # (n_side,) per-vertex level-2 / stored totals
     state: _LoopState,
     *,
     aggregation: str,
     cap1: int,  # level-1 frontier buffer (2-hop engine only)
-    cap2: int,  # wedge-pair buffer (subtract="materialize" only)
-    tile_cap: int,  # fused-subtract tile (subtract="fused" only)
+    tile_cap: int,
     n_side: int,
     stored: bool,
     hash_bits: Optional[int] = None,
-    subtract: str = "fused",
-    decrease_key: str = "bucket",
     use_kernel: bool = False,
-    adaptive: bool = False,
     peel_mode: str = "exact",
 ):
     """Jitted device round loop (PEEL-V / WPEEL-V): the shared
     ``_device_round_loop`` substrate with the tip decompositions'
-    expand callable. Frontier expansion is either a fixed-capacity
-    ``expand_ragged`` (``subtract="materialize"``) or the
-    ``_stream_tiles`` fused tile stream (tiles recovered via
-    ``ragged_slots_at``, boundaries aligned via ``aligned_tile_end``);
-    the subtraction is the shared hash/sort aggregation (hash overflow
-    handled in-graph). ``overflow`` latches when a round's frontier
-    exceeds a planned capacity; the loop exits immediately and the
+    expand callable. Each round's frontier streams through
+    ``_stream_tiles`` (tiles recovered via ``ragged_slots_at``,
+    boundaries aligned via ``aligned_tile_end``); the subtraction is
+    the shared hash/sort aggregation (hash overflow handled in-graph).
+    ``overflow`` latches when a round's level-1 expansion exceeds
+    ``cap1`` (2-hop engine only); the loop exits immediately and the
     caller re-runs the host path.
     """
     nbr_max = nbr.shape[0] - 1
-    want_hist = peel_mode == "range" and decrease_key == "bucket"
+    want_hist = peel_mode == "range"
 
     def _tiles(b, alive, roff, recover):
         def tile_fn(bt, wid, tvalid, last):
@@ -509,99 +457,59 @@ def _peel_tips_device(
                 tv = tvalid & (u2 >= 0) & (u2 < n_side) & alive[u2c]
             return _subtract_tile(
                 u1.astype(jnp.int32), u2c.astype(jnp.int32), tv, bt,
-                alive, aggregation=aggregation, n_side=n_side,
-                hash_bits=hash_bits, decrease_key=decrease_key,
-                use_kernel=use_kernel, want_hist=want_hist, last=last,
+                lambda c, tgt, dec: _apply_decrements(
+                    c, alive, tgt, dec, use_kernel, want_hist, last),
+                aggregation=aggregation, n_side=n_side, hash_bits=hash_bits,
             )
 
         return _stream_tiles(
             b, alive, roff, tile_fn, tile_cap=tile_cap, aligned=True,
-            decrease_key=decrease_key, want_hist=want_hist,
+            want_hist=want_hist,
         )
 
     @_scope("recover")
     def expand(args):
         b, alive, _alive_prev, peel = args
         if stored:
-            # WPEEL-V: one stored-wedge CSR lookup per peeled vertex
+            # WPEEL-V: tiles recovered straight from the stored-wedge
+            # CSR — no frontier buffer at all
             lens = jnp.where(peel, off[1:] - off[:-1], 0)
-            if subtract == "fused":
-                # zero-materialization: tiles recovered straight
-                # from the wedge CSR — no frontier buffer at all
-                roff = _prefix(lens)
-                starts = off[:-1]
+            roff = _prefix(lens)
+            starts = off[:-1]
 
-                def recover(wid):
-                    seg, pos = ragged_slots_at(roff, starts, wid)
-                    return seg, nbr[jnp.clip(pos, 0, nbr_max)]
+            def recover(wid):
+                seg, pos = ragged_slots_at(roff, starts, wid)
+                return seg, nbr[jnp.clip(pos, 0, nbr_max)]
 
-                b_new, mn2, h2 = _tiles(b, alive, roff, recover)
-                return b_new, jnp.array(False), mn2, h2
-            u1, pos, valid, total = expand_ragged(off[:-1], lens, cap2)
-            u2 = nbr[jnp.clip(pos, 0, nbr_max)]
-            ovf = total > cap2
-        else:
-            # PEEL-V: 2-hop re-enumeration (GET-V-WEDGES). Level 1:
-            # peeled u1 -> centers v; level 2: v -> endpoints u2.
-            ids = jnp.arange(n_side, dtype=jnp.int32) + base
-            lens1 = jnp.where(peel, off[ids + 1] - off[ids], 0)
-            seg1, pos1, valid1, tot1 = expand_ragged(
-                off[ids], lens1, cap1
-            )
-            v = nbr[jnp.clip(pos1, 0, nbr_max)]
-            v = jnp.clip(v, 0, off.shape[0] - 2)
-            lens2 = jnp.where(valid1, off[v + 1] - off[v], 0)
-            if subtract == "fused":
-                # level-1 stays materialized (O(m)); the dominant
-                # level-2 space streams through aligned tiles
-                roff2 = _prefix(lens2)
-                t2 = jnp.zeros((n_side,), jnp.int32).at[
-                    jnp.where(valid1, seg1, jnp.int32(n_side))
-                ].add(lens2.astype(jnp.int32))
-                roff_u = _prefix(t2)
-                starts2 = off[v]
+            b_new, mn2, h2 = _tiles(b, alive, roff, recover)
+            return b_new, jnp.array(False), mn2, h2
+        # PEEL-V: 2-hop re-enumeration (GET-V-WEDGES). Level 1: peeled
+        # u1 -> centers v, materialized (O(m)); level 2: v -> endpoints
+        # u2, the dominant space, streams through aligned tiles
+        ids = jnp.arange(n_side, dtype=jnp.int32) + base
+        lens1 = jnp.where(peel, off[ids + 1] - off[ids], 0)
+        seg1, pos1, valid1, tot1 = expand_ragged(off[ids], lens1, cap1)
+        v = nbr[jnp.clip(pos1, 0, nbr_max)]
+        v = jnp.clip(v, 0, off.shape[0] - 2)
+        lens2 = jnp.where(valid1, off[v + 1] - off[v], 0)
+        roff2 = _prefix(lens2)
+        t2 = jnp.zeros((n_side,), jnp.int32).at[
+            jnp.where(valid1, seg1, jnp.int32(n_side))
+        ].add(lens2.astype(jnp.int32))
+        roff_u = _prefix(t2)
+        starts2 = off[v]
 
-                def recover(wid):
-                    seg2, pos2 = ragged_slots_at(roff2, starts2, wid)
-                    u1 = seg1[jnp.clip(seg2, 0, cap1 - 1)]
-                    u2 = nbr[jnp.clip(pos2, 0, nbr_max)] - base
-                    return u1, u2
-
-                b_new, mn2, h2 = _tiles(b, alive, roff_u, recover)
-                ovf = tot1 > cap1
-                return jnp.where(ovf, b, b_new), ovf, mn2, h2
-            seg2, pos2, valid, tot2 = expand_ragged(off[v], lens2, cap2)
-            u1 = seg1[seg2]
+        def recover(wid):
+            seg2, pos2 = ragged_slots_at(roff2, starts2, wid)
+            u1 = seg1[jnp.clip(seg2, 0, cap1 - 1)]
             u2 = nbr[jnp.clip(pos2, 0, nbr_max)] - base
-            ovf = (tot1 > cap1) | (tot2 > cap2)
-        # materializing subtract: whole frontier, one aggregation
-        u2c = jnp.clip(u2, 0, n_side - 1)
-        valid = valid & (u2 >= 0) & (u2 < n_side) & alive[u2c]
-        b_new, mn2, h2 = _subtract_tile(
-            u1.astype(jnp.int32),
-            u2c.astype(jnp.int32),
-            valid,
-            b,
-            alive,
-            aggregation=aggregation,
-            n_side=n_side,
-            hash_bits=hash_bits,
-            decrease_key=decrease_key,
-            use_kernel=use_kernel,
-            want_hist=want_hist,
-        )
+            return u1, u2
+
+        b_new, mn2, h2 = _tiles(b, alive, roff_u, recover)
+        ovf = tot1 > cap1
         return jnp.where(ovf, b, b_new), ovf, mn2, h2
 
-    shrink_caps = []
-    if subtract == "materialize":
-        shrink_caps.append((cap2, 1))
-    if not stored:
-        shrink_caps.append((cap1, 0))
-    return _device_round_loop(
-        state, expand, work1, work2, decrease_key=decrease_key,
-        peel_mode=peel_mode, adaptive=adaptive,
-        shrink_caps=tuple(shrink_caps),
-    )
+    return _device_round_loop(state, expand, peel_mode=peel_mode)
 
 
 def _peel_tips_device_run(
@@ -613,24 +521,21 @@ def _peel_tips_device_run(
     max_frontier: Optional[int],
     hash_bits: Optional[int],
     csr,
-    subtract: str = "fused",
-    decrease_key: str = "bucket",
-    capacity_schedule: str = "fixed",
     tile_budget: Optional[int] = None,
     w2: Optional[np.ndarray] = None,
     peel_mode: str = "exact",
     budget_shrinks: int = 0,
     note: Optional[list] = None,
 ) -> Optional[PeelResult]:
-    """Capacity-plan, run the device loop, fetch once per segment.
-    Returns None when the device engine does not apply (empty side,
-    counts beyond int32, totals beyond int32 indexing) or the frontier
-    overflowed its ``max_frontier``-bounded buffers — callers fall back
-    to host (the resilience ladder translates the None into the typed
-    taxonomy via ``resilience.require_rung``, appending the reason to
-    ``note``). ``csr`` is the caller-built ``(woff, w_u2)`` wedge CSR
-    (stored) or ``(off, nbr)`` graph CSR, shared with the host loop so
-    a fallback never rebuilds the dominant preprocessing.
+    """Capacity-plan, run the device loop, fetch once. Returns None
+    when the device engine does not apply (empty side, counts beyond
+    int32, totals beyond int32 indexing) or the 2-hop engine's level-1
+    expansion overflowed its ``max_frontier``-bounded buffer — callers
+    fall back to host (the resilience ladder translates the None into
+    the typed taxonomy via ``resilience.require_rung``, appending the
+    reason to ``note``). ``csr`` is the caller-built ``(woff, w_u2)``
+    wedge CSR (stored) or ``(off, nbr)`` graph CSR, shared with the
+    host loop so a fallback never rebuilds the dominant preprocessing.
     ``budget_shrinks`` halves the frontier/tile budgets that many times
     (the ladder's RESOURCE_EXHAUSTED re-entry)."""
     note = [] if note is None else note
@@ -640,7 +545,7 @@ def _peel_tips_device_run(
         note.append("device engine unavailable: empty side or counts "
                     "beyond int32")
         return None
-    with _span("plan"):  # buffer capacities and the tile shape
+    with _span("plan"):  # the level-1 buffer and the tile shape
         budget = _I32_MAX if max_frontier is None else int(max_frontier)
         tb = (_DEFAULT_TILE_TARGET if tile_budget is None
               else int(tile_budget))
@@ -649,87 +554,54 @@ def _peel_tips_device_run(
             tb = max(1, tb >> budget_shrinks)
         if stored:
             woff, w_u2 = csr
-            w_total = int(woff[-1])
-            if w_total >= _I32_MAX:
+            total = int(woff[-1])
+            if total >= _I32_MAX:
                 note.append("device engine unavailable: stored wedge total "
                             "beyond int32 indexing")
                 return None
-            rows = np.diff(woff)
-            work1 = np.zeros(n_side, np.int32)
-            work2 = rows.astype(np.int32)
-            lvl1, lvl2 = 0, w_total
-            max_row = int(rows.max(initial=0))
+            max_row = int(np.diff(woff).max(initial=0))
             cap1 = 128  # unused by the stored loop
-            cap2 = _pow2_pad(min(w_total, budget))
             off_d = jnp.asarray(woff, jnp.int32)
-            nbr_d = jnp.asarray(w_u2 if w_total else np.zeros(1),
-                                jnp.int32)
+            nbr_d = jnp.asarray(w_u2 if total else np.zeros(1), jnp.int32)
         else:
             off, nbr = csr
-            deg = np.diff(off)
-            lvl1 = int(deg[base : base + n_side].sum())  # == m
             if w2 is None:
                 w2 = _level2_totals(off, nbr, base, n_side)
-            lvl2 = int(w2.sum())
-            if lvl2 >= _I32_MAX or 2 * g.m >= _I32_MAX:
+            total = int(w2.sum())
+            if total >= _I32_MAX or 2 * g.m >= _I32_MAX:
                 note.append("device engine unavailable: expansion totals "
                             "beyond int32 indexing")
                 return None
-            work1 = deg[base : base + n_side].astype(np.int32)
-            work2 = w2.astype(np.int32)
             max_row = int(w2.max(initial=0))
+            lvl1 = int(np.diff(off)[base : base + n_side].sum())  # == m
             cap1 = _pow2_pad(min(lvl1, budget))
-            cap2 = _pow2_pad(min(lvl2, budget))
             off_d = jnp.asarray(off, jnp.int32)
             nbr_d = jnp.asarray(nbr if nbr.size else np.zeros(1),
                                 jnp.int32)
-        # fused tiles must fit the largest single-vertex expansion (the
+        # tiles must fit the largest single-vertex expansion (the
         # alignment floor, like plan_wedge_chunks' single-vertex chunks);
         # the 2x headroom keeps greedy tiles at least half full
-        tile_cap = _pow2_pad(max(min(tb, max(lvl2, 1)), 2 * max_row))
-    b0 = jnp.asarray(counts)
+        tile_cap = _tip_tile_cap(tb, total, 2 * max_row)
     # counts below INT32_MAX (guarded above) run the int32 kernel in any
     # count dtype; off the compiled backend the reference serves
     use_kernel = not _kops.interpret_default()
-    state = _init_state(
-        b0, n_side, decrease_key=decrease_key, peel_mode=peel_mode,
-        lvl1=lvl1, lvl2=lvl2,
-    )
-    adaptive = capacity_schedule == "adaptive"
-    caps = {"cap1": cap1, "cap2": cap2}
-
-    def run(st):
-        return _launch(
-            _peel_tips_device,
-            off_d,
-            nbr_d,
-            jnp.int32(base),
-            jnp.asarray(work1),
-            jnp.asarray(work2),
-            st,
-            aggregation=aggregation,
-            cap1=caps["cap1"],
-            cap2=caps["cap2"],
-            tile_cap=tile_cap,
-            n_side=n_side,
-            stored=stored,
-            hash_bits=hash_bits,
-            subtract=subtract,
-            decrease_key=decrease_key,
-            use_kernel=use_kernel,
-            adaptive=adaptive,
-            peel_mode=peel_mode,
-        )
-
-    def update_caps(host):
-        # geometric shrink: re-enter with pow2-tightened static caps
-        if not stored:
-            caps["cap1"] = min(caps["cap1"], _pow2_pad(int(host.rem1)))
-        if subtract == "materialize":
-            caps["cap2"] = min(caps["cap2"], _pow2_pad(int(host.rem2)))
-
-    host = _drive_segments(run, state, adaptive, update_caps)
-    if host is None:
+    state = _init_state(jnp.asarray(counts), n_side, peel_mode=peel_mode)
+    host = _fetch(_launch(
+        _peel_tips_device,
+        off_d,
+        nbr_d,
+        jnp.int32(base),
+        state,
+        aggregation=aggregation,
+        cap1=cap1,
+        tile_cap=tile_cap,
+        n_side=n_side,
+        stored=stored,
+        hash_bits=hash_bits,
+        use_kernel=use_kernel,
+        peel_mode=peel_mode,
+    ))
+    if bool(host.overflow):
         note.append(
             f"bounded frontier buffer overflow (max_frontier budget "
             f"{budget})"
@@ -749,25 +621,10 @@ def _check_engine(engine: str) -> None:
         )
 
 
-def _check_knobs(aggregation: str, subtract: str, decrease_key: str,
-                 capacity_schedule: str, peel_mode: str = "exact") -> None:
+def _check_knobs(aggregation: str, peel_mode: str = "exact") -> None:
     if aggregation not in ("sort", "hash"):
         raise ValueError(
             f"peeling aggregation must be sort|hash, got {aggregation}"
-        )
-    if subtract not in PEEL_SUBTRACTS:
-        raise ValueError(
-            f"subtract must be {'|'.join(PEEL_SUBTRACTS)}, got {subtract}"
-        )
-    if decrease_key not in PEEL_DECREASE_KEYS:
-        raise ValueError(
-            f"decrease_key must be {'|'.join(PEEL_DECREASE_KEYS)}, "
-            f"got {decrease_key}"
-        )
-    if capacity_schedule not in PEEL_SCHEDULES:
-        raise ValueError(
-            f"capacity_schedule must be {'|'.join(PEEL_SCHEDULES)}, "
-            f"got {capacity_schedule}"
         )
     if peel_mode not in PEEL_MODES:
         raise ValueError(
@@ -1013,18 +870,14 @@ def _merge_distributed(report: "_res.ExecutionReport", sp) -> None:
         report.merge_child(child)
 
 
-def _peel_tips_host(g, counts, side, aggregation, hash_bits, subtract,
-                    tile_budget, peel_mode, off, nbr, w2) -> PeelResult:
+def _peel_tips_host(g, counts, side, aggregation, hash_bits, tile_budget,
+                    peel_mode, off, nbr, w2) -> PeelResult:
     """Host tip round loop (PEEL-V's bottom rung): whole-frontier 2-hop
     wedge enumeration with the shared tile subtract."""
     n_side = g.n_u if side == 0 else g.n_v
     base = 0 if side == 0 else g.n_u  # global id offset of peeled side
-    tile_cap = None
-    if subtract == "fused":
-        tb = _DEFAULT_TILE_TARGET if tile_budget is None else int(tile_budget)
-        tile_cap = _pow2_pad(
-            max(min(tb, max(int(w2.sum()), 1)), int(w2.max(initial=0)))
-        )
+    tile_cap = _tip_tile_cap(tile_budget, int(w2.sum()),
+                             int(w2.max(initial=0)))
     alive = np.ones(n_side, dtype=bool)
     tip = np.zeros(n_side, dtype=counts.dtype)
     b_dev = jnp.asarray(counts)
@@ -1056,8 +909,7 @@ def _peel_tips_host(g, counts, side, aggregation, hash_bits, subtract,
         if u1_w.size == 0:
             continue
         b_dev = _host_subtract_frontier(
-            b_dev, u1_w, u2_w, n_side, aggregation, hash_bits, subtract,
-            tile_cap,
+            b_dev, u1_w, u2_w, n_side, aggregation, hash_bits, tile_cap,
         )
     return PeelResult(tip, side, acct.rounds, np.asarray(acct.sizes),
                       sub_rounds=acct.sub_rounds)
@@ -1073,9 +925,6 @@ def peel_tips(
     engine: str = "host",
     max_frontier: Optional[int] = None,
     hash_bits: Optional[int] = None,
-    subtract: str = "fused",
-    decrease_key: str = "bucket",
-    capacity_schedule: str = "fixed",
     tile_budget: Optional[int] = None,
     peel_mode: str = "exact",
     devices=None,
@@ -1089,30 +938,21 @@ def peel_tips(
     Peels the bipartition producing fewer wedges-as-endpoints unless
     ``side`` is forced. ``counts`` are per-vertex butterfly counts for
     the peeled side (computed if omitted). ``engine="device"`` runs the
-    whole round loop on device (see module docstring); ``max_frontier``
-    bounds its materializing/level-1 buffers (overflow falls back to
-    host); ``hash_bits`` overrides the hash-aggregation table size
-    (testing hook for the in-graph overflow fallback).
+    whole round loop on device with one host sync (see module
+    docstring); ``max_frontier`` bounds its level-1 buffer (overflow
+    falls back to host); ``hash_bits`` overrides the hash-aggregation
+    table size (testing hook for the in-graph overflow fallback).
 
-    ``subtract="fused"`` (default) streams each round's frontier wedge
-    space through iterating-endpoint-aligned tiles — O(tile) peak temp
-    instead of O(frontier wedges) — on both engines;
-    ``"materialize"`` restores the PR 2 whole-frontier expansion.
-    ``tile_budget`` sizes the tiles (default: a small 1024 target —
-    peeling pays the tile shape every round — floored by the largest
-    single-vertex expansion). ``decrease_key="bucket"`` (default)
-    applies device-engine updates as int32 scatter-adds closed by one
-    Julienne-style ``bucket_update`` pass a sub-round (its last
-    decrements + next round's extract-min in one sweep); ``"scatter"``
-    keeps the PR 2 scatter + per-round ``bucket_min``.
-    ``capacity_schedule="adaptive"`` shrinks the device engine's
-    planned buffers geometrically as the graph empties
-    (O(log cap) extra host syncs); ``"fixed"`` keeps the one-sync
-    guarantee. ``peel_mode="range"`` switches to bucket-range rounds
-    (process the whole lowest non-empty geometric bucket per round,
-    Lakhotia-style — see module docstring): same numbers, ρ counted in
-    bucket rounds, re-settle iterations in ``sub_rounds``. All knob
-    combinations produce bitwise-identical numbers.
+    Both engines stream each round's frontier wedge space through
+    iterating-endpoint-aligned tiles — O(tile) peak temp instead of
+    O(frontier wedges). ``tile_budget`` sizes the tiles (default: a
+    small 1024 target — peeling pays the tile shape every round —
+    floored by the largest single-vertex expansion).
+    ``peel_mode="range"`` switches to bucket-range rounds (process the
+    whole lowest non-empty geometric bucket per round, Lakhotia-style —
+    see module docstring): same numbers, ρ counted in bucket rounds,
+    re-settle iterations in ``sub_rounds``. Every engine and knob
+    setting produces bitwise-identical numbers.
 
     ``devices=N`` (or ``"auto"`` = every visible jax device) inserts
     the **distributed** rung on top of the ladder: the supervised,
@@ -1141,8 +981,7 @@ def peel_tips(
     checkpoint restores, per-device worker rows, and outcomes.
     """
     _check_engine(engine)
-    _check_knobs(aggregation, subtract, decrease_key, capacity_schedule,
-                 peel_mode)
+    _check_knobs(aggregation, peel_mode)
     policy = _res.resolve_policy(resilience)
     hash_bits = _faults.hash_bits_override("peel_tips", hash_bits)
     side, counts = _side_and_counts(g, counts, side, count_kwargs)
@@ -1161,9 +1000,8 @@ def peel_tips(
         notes: list = []
         res = _peel_tips_device_run(
             g, c, side, aggregation, False, mf, hash_bits,
-            (off, nbr), subtract=subtract, decrease_key=decrease_key,
-            capacity_schedule=capacity_schedule, tile_budget=tile_budget,
-            w2=w2, peel_mode=peel_mode, budget_shrinks=shrinks, note=notes,
+            (off, nbr), tile_budget=tile_budget, w2=w2,
+            peel_mode=peel_mode, budget_shrinks=shrinks, note=notes,
         )
         return _res.require_rung(res, notes)
 
@@ -1171,8 +1009,8 @@ def peel_tips(
         _faults.maybe_oom("peel_tips.host")
         _faults.maybe_slow_rung("peel_tips.host")
         return _peel_tips_host(
-            g, counts, side, aggregation, hash_bits, subtract,
-            tile_budget, peel_mode, off, nbr, w2,
+            g, counts, side, aggregation, hash_bits, tile_budget,
+            peel_mode, off, nbr, w2,
         )
 
     plan = _plan_peel(
@@ -1234,11 +1072,7 @@ def peel_tips_stored(
     aggregation: str = "sort",
     count_kwargs: Optional[dict] = None,
     engine: str = "host",
-    max_frontier: Optional[int] = None,
     hash_bits: Optional[int] = None,
-    subtract: str = "fused",
-    decrease_key: str = "bucket",
-    capacity_schedule: str = "fixed",
     tile_budget: Optional[int] = None,
     peel_mode: str = "exact",
     devices=None,
@@ -1255,17 +1089,14 @@ def peel_tips_stored(
     the paper's W_c store handles the same butterflies from the other
     orientation of its ranked wedge set.
 
-    Knobs as in :func:`peel_tips`. Under ``subtract="fused"`` the
-    device engine recovers each tile straight from the stored-wedge
-    CSR — no per-round frontier buffer exists at all, so
-    ``max_frontier`` (and capacity overflow) only applies to
-    ``subtract="materialize"``. ``devices``/``checkpoint``/
-    ``round_deadline_s`` (the supervised distributed rung) and
-    ``resilience`` as in :func:`peel_tips`.
+    Knobs as in :func:`peel_tips`. The device engine recovers each
+    tile straight from the stored-wedge CSR — no per-round frontier
+    buffer exists at all, so there is no ``max_frontier``.
+    ``devices``/``checkpoint``/``round_deadline_s`` (the supervised
+    distributed rung) and ``resilience`` as in :func:`peel_tips`.
     """
     _check_engine(engine)
-    _check_knobs(aggregation, subtract, decrease_key, capacity_schedule,
-                 peel_mode)
+    _check_knobs(aggregation, peel_mode)
     policy = _res.resolve_policy(resilience)
     hash_bits = _faults.hash_bits_override("peel_tips_stored", hash_bits)
     side, counts = _side_and_counts(g, counts, side, count_kwargs)
@@ -1275,15 +1106,12 @@ def peel_tips_stored(
     def run_device(shrinks: int):
         _faults.maybe_oom("peel_tips_stored.device")
         _faults.maybe_slow_rung("peel_tips_stored.device")
-        mf = _faults.capacity_override("peel_tips_stored.device",
-                                       max_frontier)
         c = _faults.maybe_poison("peel_tips_stored.device", counts)
         notes: list = []
         res = _peel_tips_device_run(
-            g, c, side, aggregation, True, mf, hash_bits,
-            (woff, w_u2), subtract=subtract, decrease_key=decrease_key,
-            capacity_schedule=capacity_schedule, tile_budget=tile_budget,
-            peel_mode=peel_mode, budget_shrinks=shrinks, note=notes,
+            g, c, side, aggregation, True, None, hash_bits,
+            (woff, w_u2), tile_budget=tile_budget, peel_mode=peel_mode,
+            budget_shrinks=shrinks, note=notes,
         )
         return _res.require_rung(res, notes)
 
@@ -1291,8 +1119,8 @@ def peel_tips_stored(
         _faults.maybe_oom("peel_tips_stored.host")
         _faults.maybe_slow_rung("peel_tips_stored.host")
         return _peel_tips_stored_host(
-            counts, side, n_side, aggregation, hash_bits, subtract,
-            tile_budget, peel_mode, woff, w_u2,
+            counts, side, n_side, aggregation, hash_bits, tile_budget,
+            peel_mode, woff, w_u2,
         )
 
     plan = _plan_peel(
@@ -1303,8 +1131,6 @@ def peel_tips_stored(
         n_out=n_side,
         dtype=np.asarray(counts).dtype.name,
         capacity=(
-            ("max_frontier",
-             _I32_MAX if max_frontier is None else int(max_frontier)),
             ("tile_budget",
              _DEFAULT_TILE_TARGET if tile_budget is None
              else int(tile_budget)),
@@ -1348,17 +1174,12 @@ def peel_tips_stored(
 
 
 def _peel_tips_stored_host(counts, side, n_side, aggregation, hash_bits,
-                           subtract, tile_budget, peel_mode, woff,
+                           tile_budget, peel_mode, woff,
                            w_u2) -> PeelResult:
     """Host WPEEL-V round loop (the ladder's bottom rung): per-round
     subtract via stored-wedge index lookups."""
-    tile_cap = None
-    if subtract == "fused":
-        tb = _DEFAULT_TILE_TARGET if tile_budget is None else int(tile_budget)
-        rows = np.diff(woff)
-        tile_cap = _pow2_pad(
-            max(min(tb, max(int(woff[-1]), 1)), int(rows.max(initial=0)))
-        )
+    tile_cap = _tip_tile_cap(tile_budget, int(woff[-1]),
+                             int(np.diff(woff).max(initial=0)))
     alive = np.ones(n_side, dtype=bool)
     tip = np.zeros(n_side, dtype=counts.dtype)
     b_dev = jnp.asarray(counts)
@@ -1386,8 +1207,7 @@ def _peel_tips_stored_host(counts, side, n_side, aggregation, hash_bits,
         if u1_w.size == 0:
             continue
         b_dev = _host_subtract_frontier(
-            b_dev, u1_w, u2_w, n_side, aggregation, hash_bits, subtract,
-            tile_cap,
+            b_dev, u1_w, u2_w, n_side, aggregation, hash_bits, tile_cap,
         )
     return PeelResult(tip, side, acct.rounds, np.asarray(acct.sizes),
                       sub_rounds=acct.sub_rounds)
@@ -1402,12 +1222,11 @@ def _subtract_edge_groups(
     tgt3: jax.Array,
     valid3: jax.Array,
     b: jax.Array,
-    alive: Optional[jax.Array],
+    alive: jax.Array,
     *,
     aggregation: str,
     m: int,
     hash_bits: Optional[int] = None,
-    decrease_key: str = "scatter",
     use_kernel: bool = False,
     want_hist: bool = False,
     last=True,
@@ -1435,8 +1254,8 @@ def _subtract_edge_groups(
     def consume(_wv, groups):
         dec = jnp.where(groups.valid, groups.d.astype(b.dtype), 0)
         tgt = jnp.where(groups.valid, groups.x1, sent)
-        return _apply_decrements(b, alive, tgt, dec, decrease_key,
-                                 use_kernel, want_hist, last)
+        return _apply_decrements(b, alive, tgt, dec, use_kernel, want_hist,
+                                 last)
 
     out, _ok = _fused_tile_apply(w, aggregation, consume, "xla", hash_bits)
     return out
@@ -1445,8 +1264,8 @@ def _subtract_edge_groups(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "aggregation", "cap1", "cap2", "tile_cap", "m", "hash_bits",
-        "subtract", "decrease_key", "use_kernel", "adaptive", "peel_mode",
+        "aggregation", "tile_cap", "m", "hash_bits", "use_kernel",
+        "peel_mode",
     ),
 )
 def _peel_wings_device(
@@ -1459,27 +1278,22 @@ def _peel_wings_device(
     uid_ds: jax.Array,  # (2m,) edge ids matching nbr_ds
     degs_ds: jax.Array,  # (2m,) deg(nbr_ds[p])
     cumdeg: jax.Array,  # (2m,) in-row exclusive prefix of degs_ds
-    work1: jax.Array,  # (m,) per-edge level-1 expansion totals
+    work1: jax.Array,  # (m,) per-edge level-1 totals (not read)
     work2: jax.Array,  # (m,) per-edge triple-space totals
     state: _LoopState,
     *,
     aggregation: str,
-    cap1: int,  # level-1 buffer (subtract="materialize" only)
-    cap2: int,  # triple-space buffer (subtract="materialize" only)
-    tile_cap: int,  # fused-subtract tile (subtract="fused" only)
+    tile_cap: int,
     m: int,
     hash_bits: Optional[int] = None,
-    subtract: str = "fused",
-    decrease_key: str = "bucket",
     use_kernel: bool = False,
-    adaptive: bool = False,
     peel_mode: str = "exact",
 ):
     """Jitted device round loop for wing decomposition (PEEL-E, Alg. 6):
     the shared ``_device_round_loop`` substrate with the wing expand
     callable.
 
-    ``subtract="fused"`` uses the **two-level fused recovery**: the
+    The expand callable is a **two-level fused recovery**: the
     per-butterfly triple space — for each peeled edge a = (u1, v1),
     for each candidate u2 in N(v1), scan the smaller of N(u1)/N(u2)
     for centers v2 — is recovered straight from flat ids with NO
@@ -1497,9 +1311,8 @@ def _peel_wings_device(
     identical; the paper's Σ min(deg(u), deg(u')) work bound per
     peeled edge is preserved. Per-lane edge membership of (other, v2)
     stays the CSR binary search (``wedges._lower_bound_ragged``).
-    ``subtract="materialize"`` keeps the PR 4 fixed-capacity
-    ``expand_ragged`` levels (``cap1``/``cap2``; the only wing path
-    ``max_frontier``/overflow still applies to).
+    ``work1`` is part of the program's signature only: positional
+    readers of the launched arguments find ``work2`` at index 10.
 
     Presence of an edge x w.r.t. the peeled edge a follows the paper's
     id-order tiebreak: alive-before-this-round and (not peeled this
@@ -1507,7 +1320,7 @@ def _peel_wings_device(
     """
     nbr_max = nbr.shape[0] - 1
     deg = off[1:] - off[:-1]
-    want_hist = peel_mode == "range" and decrease_key == "bucket"
+    want_hist = peel_mode == "range"
 
     @_scope("recover")
     def expand(args):
@@ -1517,13 +1330,46 @@ def _peel_wings_device(
             xc = jnp.clip(x, 0, m - 1)
             return alive_prev[xc] & (~peel[xc] | (x > a))
 
-        def _locate_and_subtract(bt, a2, v1_2, b_2, oth, si, kp, pos2,
-                                 tvalid, last=True):
-            """Membership-check one tile of (edge, u2, v2-slot) triples
-            and subtract the located butterflies' edge contributions.
-            ``pos2`` are absolute CSR slots inside N(small); ``last``
-            marks the sub-round's final tile."""
-            pos2c = jnp.clip(pos2, 0, nbr_max)
+        # per-edge triple totals are static (work2), so the round's
+        # flat triple space is one masked prefix
+        roff_tri = _prefix(jnp.where(peel, work2, 0))
+
+        def tile_fn(bt, wid, tvalid, last):
+            a2, tp = ragged_slots_at(roff_tri, jnp.zeros((m,), jnp.int32),
+                                     wid)
+            u1 = eu[a2]
+            v1_2 = ev[a2]
+            d1 = deg[u1]
+            rs = off[v1_2]
+            re = off[v1_2 + 1]
+            # split N(v1) (degree-sorted) at deg(u2) >= deg(u1)
+            q = _lower_bound_ragged(degs_ds, rs, re, d1)
+            re1 = jnp.clip(re - 1, 0, nbr_max)
+            head_tri = jnp.where(
+                q < re,
+                cumdeg[jnp.clip(q, 0, nbr_max)],
+                cumdeg[re1] + degs_ds[re1],
+            )
+            in_head = tp < head_tri
+            # head: ragged inner sizes — binary search the in-row
+            # neighbor-degree prefix (cumdeg[rs] == 0)
+            p_head = _lower_bound_ragged(cumdeg, rs, q, tp + 1) - 1
+            # tail: deg(u1)-sized blocks — pure arithmetic
+            r_tail = tp - head_tri
+            d1s = jnp.maximum(d1, 1)
+            j_tail = r_tail // d1s
+            p1 = jnp.clip(jnp.where(in_head, p_head, q + j_tail), 0, nbr_max)
+            i = jnp.where(in_head, tp - cumdeg[p1], r_tail - j_tail * d1s)
+            u2 = nbr_ds[p1]
+            b_2 = uid_ds[p1]
+            kp = tvalid & (u2 != u1) & present(b_2, a2)
+            si = d1 <= deg[u2]
+            small = jnp.where(si, u1, u2)
+            oth = jnp.where(si, u2, u1)
+            pos2c = jnp.clip(
+                off[small] + jnp.clip(i, 0, jnp.maximum(deg[small] - 1, 0)),
+                0, nbr_max,
+            )
             v2 = nbr[pos2c]
             e_small = uid[pos2c]
             with _scope("member"):
@@ -1539,8 +1385,7 @@ def _peel_wings_device(
             c_edge = jnp.where(si, e_small, e_other)
             d_edge = jnp.where(si, e_other, e_small)
             ok = (
-                tvalid
-                & kp
+                kp
                 & hit
                 & (v2 != v1_2)
                 & present(c_edge, a2)
@@ -1551,98 +1396,16 @@ def _peel_wings_device(
             return _subtract_edge_groups(
                 tgt3.astype(jnp.int32), ok3, bt, alive,
                 aggregation=aggregation, m=m, hash_bits=hash_bits,
-                decrease_key=decrease_key, use_kernel=use_kernel,
-                want_hist=want_hist, last=last,
+                use_kernel=use_kernel, want_hist=want_hist, last=last,
             )
 
-        if subtract == "fused":
-            # two-level fused recovery: per-edge triple totals are
-            # static (work2), so the round's flat triple space is one
-            # masked prefix — no level-1/level-2 buffers exist at all
-            roff_tri = _prefix(jnp.where(peel, work2, 0))
-
-            def tile_fn(bt, wid, tvalid, last):
-                a2, tp = ragged_slots_at(
-                    roff_tri, jnp.zeros((m,), jnp.int32), wid
-                )
-                u1 = eu[a2]
-                v1_2 = ev[a2]
-                d1 = deg[u1]
-                rs = off[v1_2]
-                re = off[v1_2 + 1]
-                # split N(v1) (degree-sorted) at deg(u2) >= deg(u1)
-                q = _lower_bound_ragged(degs_ds, rs, re, d1)
-                re1 = jnp.clip(re - 1, 0, nbr_max)
-                head_tri = jnp.where(
-                    q < re,
-                    cumdeg[jnp.clip(q, 0, nbr_max)],
-                    cumdeg[re1] + degs_ds[re1],
-                )
-                in_head = tp < head_tri
-                # head: ragged inner sizes — binary search the in-row
-                # neighbor-degree prefix (cumdeg[rs] == 0)
-                p_head = _lower_bound_ragged(cumdeg, rs, q, tp + 1) - 1
-                # tail: deg(u1)-sized blocks — pure arithmetic
-                r_tail = tp - head_tri
-                d1s = jnp.maximum(d1, 1)
-                j_tail = r_tail // d1s
-                p1 = jnp.clip(
-                    jnp.where(in_head, p_head, q + j_tail), 0, nbr_max
-                )
-                i = jnp.where(
-                    in_head, tp - cumdeg[p1], r_tail - j_tail * d1s
-                )
-                u2 = nbr_ds[p1]
-                b_2 = uid_ds[p1]
-                kp = tvalid & (u2 != u1) & present(b_2, a2)
-                si = d1 <= deg[u2]
-                small = jnp.where(si, u1, u2)
-                oth = jnp.where(si, u2, u1)
-                pos2 = off[small] + jnp.clip(i, 0, jnp.maximum(deg[small] - 1, 0))
-                return _locate_and_subtract(
-                    bt, a2, v1_2, b_2, oth, si, kp, pos2, tvalid, last
-                )
-
-            b_new, mn2, h2 = _stream_tiles(
-                b, alive, roff_tri, tile_fn, tile_cap=tile_cap,
-                aligned=False, decrease_key=decrease_key,
-                want_hist=want_hist,
-            )
-            return b_new, jnp.array(False), mn2, h2
-
-        # materialize: the PR 2/4 fixed-capacity expansion levels
-        # level 1: peeled a=(u1,v1) -> u2 in N(v1)
-        lens1 = jnp.where(peel, deg[ev], 0)
-        seg1, pos1, valid1, tot1 = expand_ragged(off[ev], lens1, cap1)
-        pos1c = jnp.clip(pos1, 0, nbr_max)
-        a1 = jnp.clip(seg1, 0, m - 1)
-        u2 = nbr[pos1c]
-        b_edge = uid[pos1c]
-        u1 = eu[a1]
-        v1 = ev[a1]
-        keep1 = valid1 & (u2 != u1) & present(b_edge, a1)
-        # level 2 plan: scan the smaller of N(u1), N(u2)
-        s_is_u1 = deg[u1] <= deg[u2]
-        small = jnp.where(s_is_u1, u1, u2)
-        other = jnp.where(s_is_u1, u2, u1)
-        lens2 = jnp.where(keep1, deg[small], 0)
-        seg2, pos2, valid2, tot2 = expand_ragged(off[small], lens2, cap2)
-        s2 = jnp.clip(seg2, 0, cap1 - 1)
-        b_new, mn2, h2 = _locate_and_subtract(
-            b, a1[s2], v1[s2], b_edge[s2], other[s2], s_is_u1[s2],
-            keep1[s2], pos2, valid2,
+        b_new, mn2, h2 = _stream_tiles(
+            b, alive, roff_tri, tile_fn, tile_cap=tile_cap, aligned=False,
+            want_hist=want_hist,
         )
-        ovf = (tot1 > cap1) | (tot2 > cap2)
-        return jnp.where(ovf, b, b_new), ovf, mn2, h2
+        return b_new, jnp.array(False), mn2, h2
 
-    shrink_caps = []
-    if subtract == "materialize":
-        shrink_caps += [(cap1, 0), (cap2, 1)]
-    return _device_round_loop(
-        state, expand, work1, work2, decrease_key=decrease_key,
-        peel_mode=peel_mode, adaptive=adaptive,
-        shrink_caps=tuple(shrink_caps),
-    )
+    return _device_round_loop(state, expand, peel_mode=peel_mode)
 
 
 @_traced("preprocess")
@@ -1673,29 +1436,22 @@ def _peel_wings_device_run(
     g: BipartiteGraph,
     counts: np.ndarray,
     aggregation: str,
-    max_frontier: Optional[int],
     hash_bits: Optional[int],
     csr,
-    subtract: str = "fused",
-    decrease_key: str = "bucket",
-    capacity_schedule: str = "fixed",
     tile_budget: Optional[int] = None,
     peel_mode: str = "exact",
     budget_shrinks: int = 0,
     note: Optional[list] = None,
     w_totals=None,
 ) -> Optional[PeelResult]:
-    """Capacity-plan and run the device wing loop; one ``device_get``
-    per segment (one total under the fixed schedule). Returns None when
-    the device engine does not apply (no edges, counts or expansion
-    totals beyond int32) or a bounded buffer overflowed — callers fall
+    """Plan and run the device wing loop; one ``device_get`` per
+    decomposition. Returns None when the device engine does not apply
+    (no edges, counts or expansion totals beyond int32) — callers fall
     back to the host loop, reusing ``csr`` (the resilience ladder
     translates the None into the typed taxonomy, appending the reason
     to ``note``; ``budget_shrinks`` is its RESOURCE_EXHAUSTED re-entry
-    knob). ``subtract="fused"`` has no frontier buffers (the two-level
-    fused recovery inverts flat triple ids directly), so
-    ``max_frontier`` only bounds the materializing path's
-    ``cap1``/``cap2``."""
+    knob, halving the tile budget). The two-level fused recovery
+    inverts flat triple ids directly, so no buffer can overflow."""
     note = [] if note is None else note
     off, nbr, uid = csr
     m = g.m
@@ -1706,49 +1462,30 @@ def _peel_wings_device_run(
     if 2 * m >= _I32_MAX:
         note.append("device engine unavailable: edge slots beyond int32")
         return None
-    with _span("plan"):  # the degree-sorted CSR, capacities, the seed state
+    with _span("plan"):  # the degree-sorted CSR, the tile, the seed state
         eu, ev, l1, l2 = (
             _wing_work_totals(g, off, nbr) if w_totals is None else w_totals
         )
-        lvl1 = int(l1.sum())
         lvl2 = int(l2.sum())
-        if lvl1 >= _I32_MAX or lvl2 >= _I32_MAX:
+        if lvl2 >= _I32_MAX:
             note.append("device engine unavailable: expansion totals beyond "
                         "int32 indexing")
             return None
-        if subtract == "fused":
-            # the fused recovery reads in-row neighbor-degree prefixes;
-            # every row total must stay int32-addressable (the materialize
-            # path never touches these arrays, so it skips the build and
-            # the guard)
-            nbr_ds, uid_ds, degs_ds, cumdeg = degree_sorted_csr(off, nbr, uid)
-            if cumdeg.size and int(
-                (cumdeg + degs_ds).max(initial=0)
-            ) >= _I32_MAX:
-                note.append("device engine unavailable: degree-sorted "
-                            "prefixes beyond int32 indexing")
-                return None
-        else:
-            nbr_ds = uid_ds = degs_ds = cumdeg = np.zeros(0, np.int64)
-        budget = _I32_MAX if max_frontier is None else int(max_frontier)
+        # the recovery reads in-row neighbor-degree prefixes; every row
+        # total must stay int32-addressable
+        nbr_ds, uid_ds, degs_ds, cumdeg = degree_sorted_csr(off, nbr, uid)
+        if cumdeg.size and int((cumdeg + degs_ds).max(initial=0)) >= _I32_MAX:
+            note.append("device engine unavailable: degree-sorted "
+                        "prefixes beyond int32 indexing")
+            return None
         tb = _DEFAULT_TILE_TARGET if tile_budget is None else int(tile_budget)
         if budget_shrinks:
-            budget = max(128, budget >> budget_shrinks)
             tb = max(1, tb >> budget_shrinks)
-        if subtract == "materialize":
-            cap1 = _pow2_pad(min(lvl1, budget))
-            cap2 = _pow2_pad(min(lvl2, budget))
-        else:
-            cap1 = cap2 = 128  # unused: the fused path has no buffers
         tile_cap = _pow2_pad(min(tb, max(lvl2, 1)))
-        b0 = jnp.asarray(counts)
         # counts below INT32_MAX (guarded above) run the int32 kernel in any
         # count dtype; off the compiled backend the reference serves
         use_kernel = not _kops.interpret_default()
-        state = _init_state(
-            b0, m, decrease_key=decrease_key, peel_mode=peel_mode,
-            lvl1=lvl1, lvl2=lvl2,
-        )
+        state = _init_state(jnp.asarray(counts), m, peel_mode=peel_mode)
         args = (
             jnp.asarray(off, jnp.int32),
             jnp.asarray(nbr if nbr.size else np.zeros(1), jnp.int32),
@@ -1762,39 +1499,17 @@ def _peel_wings_device_run(
             jnp.asarray(l1.astype(np.int32)),
             jnp.asarray(l2.astype(np.int32)),
         )
-    adaptive = capacity_schedule == "adaptive"
-    caps = {"cap1": cap1, "cap2": cap2}
-
-    def run(st):
-        return _launch(
-            _peel_wings_device,
-            *args,
-            st,
-            aggregation=aggregation,
-            cap1=caps["cap1"],
-            cap2=caps["cap2"],
-            tile_cap=tile_cap,
-            m=m,
-            hash_bits=hash_bits,
-            subtract=subtract,
-            decrease_key=decrease_key,
-            use_kernel=use_kernel,
-            adaptive=adaptive,
-            peel_mode=peel_mode,
-        )
-
-    def update_caps(host):
-        if subtract == "materialize":
-            caps["cap1"] = min(caps["cap1"], _pow2_pad(int(host.rem1)))
-            caps["cap2"] = min(caps["cap2"], _pow2_pad(int(host.rem2)))
-
-    host = _drive_segments(run, state, adaptive, update_caps)
-    if host is None:
-        note.append(
-            f"bounded frontier buffer overflow (max_frontier budget "
-            f"{budget})"
-        )
-        return None
+    host = _fetch(_launch(
+        _peel_wings_device,
+        *args,
+        state,
+        aggregation=aggregation,
+        tile_cap=tile_cap,
+        m=m,
+        hash_bits=hash_bits,
+        use_kernel=use_kernel,
+        peel_mode=peel_mode,
+    ))
     rounds = int(host.rounds)
     return PeelResult(
         host.out, None, rounds, host.sizes[:rounds].astype(np.int64),
@@ -1809,11 +1524,7 @@ def peel_wings(
     count_kwargs: Optional[dict] = None,
     engine: str = "host",
     aggregation: str = "sort",
-    max_frontier: Optional[int] = None,
     hash_bits: Optional[int] = None,
-    subtract: str = "fused",
-    decrease_key: str = "bucket",
-    capacity_schedule: str = "fixed",
     tile_budget: Optional[int] = None,
     peel_mode: str = "exact",
     devices=None,
@@ -1835,25 +1546,22 @@ def peel_wings(
     decomposition as one jitted ``lax.while_loop`` — a third in-graph
     expansion level enumerates the per-butterfly triples and an
     in-graph CSR binary search replaces the composite-key membership
-    probe — with one ``device_get`` per decomposition (fixed
-    schedule). ``aggregation``/``hash_bits`` select the device
-    engine's grouped edge subtract strategy (the host engine's raw
-    triple scatter is bitwise-equivalent); ``subtract``/
-    ``decrease_key``/``capacity_schedule``/``tile_budget``/
-    ``max_frontier``/``peel_mode`` as in :func:`peel_tips`. The fused
-    axis recovers the per-butterfly triple space straight from flat
-    ids via the degree-sorted CSR (``wedges.degree_sorted_csr``) — no
-    materialized level-1/level-2 buffers, so ``max_frontier`` (and
-    capacity overflow) only applies to ``subtract="materialize"``.
-    Counts at or beyond INT32_MAX, expansion totals beyond int32, or a
-    bounded-buffer overflow transparently fall back to the host loop.
+    probe — with one ``device_get`` per decomposition.
+    ``aggregation``/``hash_bits`` select the device engine's grouped
+    edge subtract strategy (the host engine's raw triple scatter is
+    bitwise-equivalent); ``tile_budget``/``peel_mode`` as in
+    :func:`peel_tips`. The device engine recovers the per-butterfly
+    triple space straight from flat ids via the degree-sorted CSR
+    (``wedges.degree_sorted_csr``) — no level-1/level-2 buffers, so
+    there is no ``max_frontier``. Counts at or beyond INT32_MAX or
+    expansion totals beyond int32 transparently fall back to the host
+    loop.
     ``devices``/``checkpoint``/``round_deadline_s`` (the supervised
     distributed rung, fanning the per-edge triple space out along edge
     tiles) and ``resilience`` as in :func:`peel_tips`.
     """
     _check_engine(engine)
-    _check_knobs(aggregation, subtract, decrease_key, capacity_schedule,
-                 peel_mode)
+    _check_knobs(aggregation, peel_mode)
     policy = _res.resolve_policy(resilience)
     hash_bits = _faults.hash_bits_override("peel_wings", hash_bits)
     if counts is None:
@@ -1871,15 +1579,12 @@ def peel_wings(
     def run_device(shrinks: int):
         _faults.maybe_oom("peel_wings.device")
         _faults.maybe_slow_rung("peel_wings.device")
-        mf = _faults.capacity_override("peel_wings.device", max_frontier)
         c = _faults.maybe_poison("peel_wings.device", counts)
         notes: list = []
         res = _peel_wings_device_run(
-            g, c, aggregation, mf, hash_bits,
-            (off, nbr, uid), subtract=subtract, decrease_key=decrease_key,
-            capacity_schedule=capacity_schedule, tile_budget=tile_budget,
-            peel_mode=peel_mode, budget_shrinks=shrinks, note=notes,
-            w_totals=w_totals,
+            g, c, aggregation, hash_bits, (off, nbr, uid),
+            tile_budget=tile_budget, peel_mode=peel_mode,
+            budget_shrinks=shrinks, note=notes, w_totals=w_totals,
         )
         return _res.require_rung(res, notes)
 
@@ -1896,8 +1601,6 @@ def peel_wings(
         n_out=g.m,
         dtype=np.asarray(counts).dtype.name,
         capacity=(
-            ("max_frontier",
-             _I32_MAX if max_frontier is None else int(max_frontier)),
             ("tile_budget",
              _DEFAULT_TILE_TARGET if tile_budget is None
              else int(tile_budget)),

@@ -18,8 +18,8 @@ This module makes that explicit as a three-stage architecture:
   **execute** — ONE shared tile-loop executor family subsumes the
   engines' former private copies: :func:`run_count_tiles` (counting's
   streaming fori_loop), :func:`stream_tiles` (peeling's fused-subtract
-  while_loop), :func:`device_round_loop` (the peeling round skeleton),
-  and :func:`drive_segments` (the host-side capacity-segment driver).
+  while_loop) and :func:`device_round_loop` (the peeling round
+  skeleton).
   Kernels are dispatched ONLY through ``kernels/ops.py`` — this module
   never imports a concrete kernel, and ``count.py`` / ``peel.py``
   never reach past this module's public surface (both enforced by
@@ -144,7 +144,6 @@ __all__ = [
     "init_loop_state",
     "stream_tiles",
     "device_round_loop",
-    "drive_segments",
     # report
     "execute_ladder",
 ]
@@ -1259,11 +1258,9 @@ class LoopState(NamedTuple):
     subr: jax.Array  # () int32 re-settle iterations (== rounds, exact)
     sizes: jax.Array  # (n_out,) int32 peeled per round
     overflow: jax.Array  # () bool capacity latch
-    mn: jax.Array  # () int32 carried masked min (decrease_key="bucket")
+    mn: jax.Array  # () int32 carried masked min
     hist: jax.Array  # (NUM_BUCKETS,) carried occupancy, or (0,) unused
     hi: jax.Array  # () int32 active bucket's exclusive upper bound
-    rem1: jax.Array  # () int32 remaining level-1 work (adaptive)
-    rem2: jax.Array  # () int32 remaining level-2 work (adaptive)
 
 
 def prefix_offsets(lens: jax.Array) -> jax.Array:
@@ -1292,27 +1289,24 @@ def masked_state(b: jax.Array, alive: jax.Array, want_hist: bool):
     return _kops.bucket_min(b, alive, use_pallas=False), empty_hist(False)
 
 
-def apply_decrements(b, alive, tgt, dec, decrease_key, use_kernel,
-                     want_hist=False, last=True):
-    """Apply one aggregated update batch to the count array.
+def apply_decrements(b, alive, tgt, dec, use_kernel, want_hist=False,
+                     last=True):
+    """Apply one aggregated update batch to the count array: the
+    Julienne-style batched decrease-key of the device round loops.
 
-    ``"scatter"``: the one-scatter subtract (min placeholder — the
-    round loop runs its own ``bucket_min``). ``"bucket"``: the
-    Julienne-style batched decrease-key — decrements, the next round's
-    masked min, and (when ``want_hist``, i.e. range mode) the
-    geometric-bucket occupancy. Only the pass the round loop consumes
-    runs ``kernels.ops.bucket_update``: ``last`` (a traced bool, or
-    True for a sub-round's only batch) marks the sub-round's final
-    batch, whose last ``MAX_UPDATE_CAP``-lane chunk goes through the
-    kernel, so its min and occupancy describe the sub-round's final
-    counts. Every other batch, and every earlier chunk of the final
-    one, is an exact int32 scatter-add (the counts stay below
-    INT32_MAX: the device loops guard it). Returns ``(new_counts, min,
-    hist)``; min and hist are placeholders unless ``last`` (hist
-    zero-length unless ``want_hist`` — see :func:`empty_hist`).
+    Returns the decremented counts, the next round's masked min, and
+    (when ``want_hist``, i.e. range mode) the geometric-bucket
+    occupancy. Only the pass the round loop consumes runs
+    ``kernels.ops.bucket_update``: ``last`` (a traced bool, or True for
+    a sub-round's only batch) marks the sub-round's final batch, whose
+    last ``MAX_UPDATE_CAP``-lane chunk goes through the kernel, so its
+    min and occupancy describe the sub-round's final counts. Every
+    other batch, and every earlier chunk of the final one, is an exact
+    int32 scatter-add (the counts stay below INT32_MAX: the device
+    loops guard it). Returns ``(new_counts, min, hist)``; min and hist
+    are placeholders unless ``last`` (hist zero-length unless
+    ``want_hist`` — see :func:`empty_hist`).
     """
-    if decrease_key != "bucket":
-        return b.at[tgt].add(-dec), jnp.int32(I32_MAX), empty_hist(want_hist)
     with scope("bucket_update"):
         tgt = tgt.astype(jnp.int32)
         dec = dec.astype(jnp.int32)
@@ -1344,16 +1338,12 @@ def apply_decrements(b, alive, tgt, dec, decrease_key, use_kernel,
         return nb.astype(b.dtype), mn, hist
 
 
-def init_loop_state(b0: jax.Array, n_out: int, *, decrease_key: str,
-                    peel_mode: str, lvl1: int, lvl2: int) -> LoopState:
+def init_loop_state(b0: jax.Array, n_out: int, *,
+                    peel_mode: str) -> LoopState:
     """Round-0 carry for :func:`device_round_loop` (shared by the run
     wrappers, the benchmarks' memory-analysis probes, and tests)."""
     alive0 = jnp.ones((n_out,), jnp.bool_)
-    want_hist = peel_mode == "range" and decrease_key == "bucket"
-    if decrease_key == "bucket":
-        mn0, hist0 = masked_state(b0, alive0, want_hist)
-    else:
-        mn0, hist0 = jnp.int32(I32_MAX), empty_hist(False)
+    mn0, hist0 = masked_state(b0, alive0, peel_mode == "range")
     return LoopState(
         b=b0,
         alive=alive0,
@@ -1366,13 +1356,11 @@ def init_loop_state(b0: jax.Array, n_out: int, *, decrease_key: str,
         mn=mn0,
         hist=hist0,
         hi=jnp.int32(0),
-        rem1=jnp.int32(min(lvl1, I32_MAX - 1)),
-        rem2=jnp.int32(min(lvl2, I32_MAX - 1)),
     )
 
 
 def stream_tiles(b, alive, roff, tile_fn, *, tile_cap: int, aligned: bool,
-                 decrease_key: str, want_hist: bool):
+                 want_hist: bool):
     """Stream the flat per-round id space ``[0, roff[-1])`` through
     fixed-shape tiles — the fused-subtract while_loop shared by every
     decomposition. ``tile_fn(b, wid, tvalid, last) -> (b, mn, hist)``
@@ -1382,16 +1370,14 @@ def stream_tiles(b, alive, roff, tile_fn, *, tile_cap: int, aligned: bool,
     boundaries at segment boundaries (``aligned_tile_end`` — required
     when the consumer's per-group C(d, 2) must not split); unaligned
     tiles advance by the full ``tile_cap`` (linear subtracts split
-    exactly). Under ``decrease_key="bucket"`` the stream carries the
-    counts as int32 and casts them back once at its end. Returns
-    ``(b, mn, hist)`` with the zero-frontier carried state re-derived
-    via :func:`masked_state`.
+    exactly). The stream carries the counts as int32 and casts them
+    back once at its end. Returns ``(b, mn, hist)`` with the
+    zero-frontier carried state re-derived via :func:`masked_state`.
     """
     total = roff[-1]
     dtype = b.dtype
-    if decrease_key == "bucket":
-        with scope("bucket_update"):
-            b = b.astype(jnp.int32)
+    with scope("bucket_update"):
+        b = b.astype(jnp.int32)
 
     @scope("recover")
     def tcond(c):
@@ -1412,74 +1398,53 @@ def stream_tiles(b, alive, roff, tile_fn, *, tile_cap: int, aligned: bool,
         tcond, tbody,
         (b, jnp.int32(0), jnp.int32(I32_MAX), empty_hist(want_hist)),
     )
-    if decrease_key == "bucket":
-        with scope("bucket_update"):
-            b = b.astype(dtype)
-        # zero-tile rounds still need the post-peel carried state
-        with scope("select"):
-            mn, hist = jax.lax.cond(
-                total > 0,
-                lambda _: (mn, hist),
-                lambda _: masked_state(b, alive, want_hist),
-                None,
-            )
+    with scope("bucket_update"):
+        b = b.astype(dtype)
+    # zero-tile rounds still need the post-peel carried state
+    with scope("select"):
+        mn, hist = jax.lax.cond(
+            total > 0,
+            lambda _: (mn, hist),
+            lambda _: masked_state(b, alive, want_hist),
+            None,
+        )
     return b, mn, hist
 
 
-def device_round_loop(state: LoopState, expand, work1, work2, *,
-                      decrease_key: str, peel_mode: str, adaptive: bool,
-                      shrink_caps: tuple):
+def device_round_loop(state: LoopState, expand, *, peel_mode: str):
     """The jitted round-loop skeleton shared by the tips and wings
-    device engines: extract-min (carried or ``bucket_min``), κ update,
-    exact-vs-range round accounting, peel-set selection/assignment,
-    adaptive remaining-work tracking, and the overflow latch.
+    device engines: extract-min (the min carried out of the previous
+    sub-round's ``bucket_update`` pass), κ update, exact-vs-range round
+    accounting, peel-set selection/assignment, and the overflow latch.
 
     ``expand((b, alive, alive_prev, peel)) -> (b, ovf, mn, hist)``
     turns one round's peel set into count decrements (the only part
-    the decompositions differ on). ``shrink_caps`` is a static tuple
-    of ``(planned_cap, rem_slot)`` pairs driving the adaptive
-    early-exit (slot 0 = rem1, 1 = rem2).
+    the decompositions differ on).
 
     Range mode (``peel_mode="range"``): a new bucket round starts
     whenever the masked min has left the active range ``[.., hi)``;
-    the next range is the lowest non-empty geometric bucket — read
-    from the carried ``bucket_update`` occupancy histogram under
-    ``decrease_key="bucket"``, from the min's bit length otherwise
-    (identical by construction). Iterations *within* a bucket round
-    are the in-graph re-settle: they replay the exact κ trajectory,
-    so the assigned numbers are bitwise-identical to exact mode —
-    only the round accounting (``rounds``, ``sizes``) is per bucket.
+    the next range is the lowest non-empty geometric bucket, read from
+    the carried ``bucket_update`` occupancy histogram. Iterations
+    *within* a bucket round are the in-graph re-settle: they replay
+    the exact κ trajectory, so the assigned numbers are
+    bitwise-identical to exact mode — only the round accounting
+    (``rounds``, ``sizes``) is per bucket.
     """
     dtype = state.b.dtype
-    want_hist = peel_mode == "range" and decrease_key == "bucket"
+    want_hist = peel_mode == "range"
 
     @scope("select")
     def cond(st):
-        go = jnp.any(st.alive) & ~st.overflow
-        if adaptive:
-            shrink = jnp.array(False)
-            rems = (st.rem1, st.rem2)
-            for cap, slot in shrink_caps:
-                if cap > 128:
-                    shrink = shrink | (rems[slot] * 4 <= cap)
-            go = go & ~shrink
-        return go
+        return jnp.any(st.alive) & ~st.overflow
 
     def body(st):
         with scope("select"):  # extract-min, κ, accounting, peel set
-            if decrease_key == "bucket":
-                mn = st.mn
-            else:
-                mn = _kops.bucket_min(st.b, st.alive, use_pallas=True)
+            mn = st.mn
             kappa = jnp.maximum(st.kappa, mn)
             rounds, hi = st.rounds, st.hi
             if peel_mode == "range":
                 new_bucket = mn >= hi
-                k_sel = (
-                    _kops.lowest_nonempty_bucket(st.hist)
-                    if want_hist
-                    else _kops.bit_length(mn)
-                )
+                k_sel = _kops.lowest_nonempty_bucket(st.hist)
                 hi = jnp.where(new_bucket, _kops.bucket_upper_bound(k_sel),
                                hi)
                 rounds = rounds + new_bucket.astype(jnp.int32)
@@ -1494,12 +1459,6 @@ def device_round_loop(state: LoopState, expand, work1, work2, *,
             # scatter into the int32 sizes buffer would downcast-warn
             sizes = st.sizes.at[rounds - 1].add(
                 jnp.sum(peel, dtype=jnp.int32))
-            rem1, rem2 = st.rem1, st.rem2
-            if adaptive:
-                rem1 = rem1 - jnp.sum(jnp.where(peel, work1, 0),
-                                      dtype=jnp.int32)
-                rem2 = rem2 - jnp.sum(jnp.where(peel, work2, 0),
-                                      dtype=jnp.int32)
             any_alive = jnp.any(alive)
 
         def _last_round(args):
@@ -1515,28 +1474,10 @@ def device_round_loop(state: LoopState, expand, work1, work2, *,
             overflow = st.overflow | ovf_i
         return LoopState(
             b, alive, out, kappa, rounds, subr, sizes,
-            overflow, mn_next, hist_next, hi, rem1, rem2,
+            overflow, mn_next, hist_next, hi,
         )
 
     return jax.lax.while_loop(cond, body, state)
-
-
-def drive_segments(run, state: LoopState, adaptive: bool, update_caps):
-    """Host-side capacity-segment driver shared by the run wrappers:
-    invoke the jitted loop, fetch the carry (the per-segment host sync
-    — the only one of the whole decomposition under the fixed
-    schedule), and under the adaptive schedule let ``update_caps``
-    pow2-shrink the planned buffers before re-entering. Returns the
-    final host-side :class:`LoopState`, or None when the in-graph
-    overflow latch fired (callers fall back to the host engine)."""
-    while True:
-        host = fetch(run(state))
-        if bool(host.overflow):
-            return None
-        if not adaptive or not host.alive.any():
-            return host
-        update_caps(host)
-        state = LoopState(*(jnp.asarray(x) for x in host))
 
 
 # ---------------------------------------------------------------------------

@@ -2,12 +2,10 @@
 
 ParButterfly's bounded-space machinery (bounded-probe hash tables,
 fixed-capacity frontier buffers, Σ min(deg u, deg u')-bounded wedge
-tiles) makes overflow a first-class runtime event. PRs 1-5 handled it
-with three separately-invented mechanisms — the in-graph hash-overflow
-sort fallback, the ``max_frontier`` overflow latch -> host-engine
-fallback, and the adaptive capacity re-entry segments. This module
-replaces the *call-site* halves of those mechanisms with one policy
-object:
+tiles) makes overflow a first-class runtime event, handled by the
+in-graph hash-overflow sort fallback and the ``max_frontier`` overflow
+latch -> host-engine fallback. This module replaces the *call-site*
+halves of those mechanisms with one policy object:
 
   - A **degradation ladder** of :class:`Rung` objects, tried in order:
     ``fused_pallas -> fused -> xla`` for counting, ``device -> host``

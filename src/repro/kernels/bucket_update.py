@@ -36,8 +36,8 @@ one-hot panel ``(RC, TN)`` needs no in-kernel reshape.
 
 Dispatched via ``ops.bucket_update`` with the same backend-aware
 interpret default as every kernel here (compiled on TPU, interpreted in
-CI). The device peeling engines (``core.peel`` ``decrease_key=
-"bucket"``) run it once per sub-round that streams any update, on the
+CI). The device peeling engines (``core.peel`` ``engine="device"``)
+run it once per sub-round that streams any update, on the
 last chunk of its last tile: the round loop reads only that pass's
 min and occupancy, so every earlier tile and chunk is an exact int32
 scatter-add into the counts. Off-TPU they route that pass to the

@@ -8,14 +8,12 @@ on by ``repro.core.count``/``repro.core.aggregate`` when called with
 interpret mode, TPU runs it compiled, and both match the pure-jnp
 reference path in ``ref`` bit-for-bit on the integer outputs.
 
-``bucket_min`` is additionally the per-round extract-min of the peeling
-engines (``repro.core.peel``): the ``engine="device"`` tip loop calls
-it inside a jitted ``lax.while_loop`` with ``use_pallas=True`` (one
-reduction per round, no host sync — CI exercises the kernel in
-interpret mode, TPU runs compiled Mosaic), while the host
-``peel_wings`` loop routes its round minimum through it with the
-Pallas path only on the compiled backend (off-TPU the per-round
-interpreter overhead dwarfs the reduction, so it serves the XLA ref).
+``bucket_min`` is additionally the per-round extract-min of the host
+``peel_wings`` loop (``repro.core.peel``), which takes the Pallas path
+only on the compiled backend (off-TPU the per-round interpreter
+overhead dwarfs the reduction, so it serves the XLA ref). The device
+peeling loops need no separate extract-min: their min comes out of
+each sub-round's ``bucket_update`` pass.
 """
 from __future__ import annotations
 

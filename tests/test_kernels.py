@@ -168,8 +168,7 @@ def test_bucket_update_rejects_oversized_batch():
         bucket_update_pallas(c, alive, idx, dec)
     with pytest.raises(ValueError, match="MAX_UPDATE_CAP"):
         ops.bucket_update(c, alive, idx, dec, use_pallas=True)
-    new, mn, hist = apply_decrements(c, alive, idx, dec, "bucket",
-                                     use_kernel=True)
+    new, mn, hist = apply_decrements(c, alive, idx, dec, use_kernel=True)
     assert int(np.asarray(new)[0]) == -k
     assert int(mn) == -k
 
@@ -196,9 +195,8 @@ def test_bucket_update_chunks_wide_counts_through_kernel(want_hist):
                                                  jnp.int32))
         want = ref.bucket_update_ref(c, alive, idx, dec)
         for last in (True, jnp.array(True), jnp.array(False)):
-            got = apply_decrements(c, alive, idx, dec, "bucket",
-                                   use_kernel=True, want_hist=want_hist,
-                                   last=last)
+            got = apply_decrements(c, alive, idx, dec, use_kernel=True,
+                                   want_hist=want_hist, last=last)
             assert got[0].dtype == jnp.int64
             assert np.array_equal(np.asarray(got[0]), np.asarray(want[0]))
             if last is True or bool(last):

@@ -1,6 +1,8 @@
 """Tip/wing decomposition vs a recompute-from-scratch oracle, the
 device-resident peeling engine parity suite (engine="device" vs host vs
 oracle), and the host Fibonacci heap (paper §5) unit tests."""
+import inspect
+
 import jax
 import numpy as np
 import pytest
@@ -56,6 +58,16 @@ def oracle_wing(g):
         wing[peel] = kappa
         alive[peel] = False
     return wing
+
+
+def bicliques():
+    """K(15,15) + K(20,20): the first peel round releases all 15
+    vertices of degree 15 at once (225 level-1 slots)."""
+    a = np.stack([np.repeat(np.arange(15), 15),
+                  np.tile(np.arange(15), 15)], axis=1)
+    b = np.stack([np.repeat(np.arange(20), 20) + 15,
+                  np.tile(np.arange(20), 20) + 15], axis=1)
+    return BipartiteGraph(35, 35, np.concatenate([a, b]))
 
 
 @pytest.mark.parametrize("seed", range(4))
@@ -189,40 +201,50 @@ def test_device_engine_stored_parity(side):
         assert np.array_equal(d.numbers, oracle_tip(g, side))
 
 
-def test_device_engine_no_per_round_sync(monkeypatch):
+@pytest.mark.parametrize("peel_mode", ["exact", "range"])
+@pytest.mark.parametrize("decomp", ["tips", "stored", "wings"])
+def test_device_engine_no_per_round_sync(decomp, peel_mode, monkeypatch):
     """The device round loop never host-syncs: with counts precomputed,
-    the whole decomposition performs exactly one jax.device_get (the
-    final PeelResult fetch), regardless of round count."""
+    every decomposition performs exactly one jax.device_get (the final
+    PeelResult fetch), regardless of round count, in both peel modes."""
     from repro.core import count_butterflies
 
     g = rand_graph(12, 9, 40, 3)
-    counts = count_butterflies(g, mode="vertex").per_u
+    if decomp == "wings":
+        counts = count_butterflies(g, mode="edge").per_edge
+        want = wing_numbers(g)[0]
+        run = lambda **kw: peel_wings(g, counts=counts, **kw)  # noqa: E731
+    else:
+        counts = count_butterflies(g, mode="vertex").per_u
+        want = oracle_tip(g, 0)
+        fn = peel_tips if decomp == "tips" else peel_tips_stored
+        run = lambda **kw: fn(g, counts=counts, side=0, **kw)  # noqa: E731
     calls = []
     orig = jax.device_get
     monkeypatch.setattr(
         jax, "device_get", lambda x: (calls.append(1), orig(x))[1]
     )
-    d = peel_tips(g, counts=counts, side=0, engine="device")
+    d = run(engine="device", peel_mode=peel_mode)
     assert len(calls) == 1
+    assert d.report.final_rung == "device"
     assert d.rounds >= 2  # the loop really ran multiple rounds
+    assert np.array_equal(d.numbers, want)
 
 
 def test_device_engine_frontier_overflow_falls_back():
-    """A deliberately tiny max_frontier overflows the fixed-capacity
-    frontier buffers; the engine must fall back to the host path (never
-    silently truncate) and still match the oracle. The graph is big
-    enough that some round's frontier exceeds the 128-slot floor, so
-    the in-graph overflow latch genuinely fires (device run -> None).
-    The materializing subtract trips it on both algorithms; the fused
-    subtract has no level-2/stored frontier buffer left to overflow, so
-    WPEEL-V fused stays on device even under max_frontier=1 (asserted),
-    and PEEL-V fused only latches when a round's *level-1* expansion
-    exceeds the cap (forced with a disjoint-biclique graph whose first
-    peel round releases 15 vertices of degree 15 at once)."""
+    """A deliberately tiny max_frontier overflows PEEL-V's level-1
+    frontier buffer; the engine must fall back to the host path (never
+    silently truncate) and still match the oracle. The latch fires only
+    when a round's level-1 expansion exceeds the 128-slot floor (forced
+    with a disjoint-biclique graph whose first peel round releases 15
+    vertices of degree 15 at once). WPEEL-V has no frontier buffer: its
+    device engine stays on device."""
     import repro.core.peel as peel_mod
 
     g = rand_graph(30, 20, 300, 0)
     want = oracle_tip(g, 0)
+    g2 = bicliques()
+    want2 = oracle_tip(g2, 0)
     device_returns = []
     orig = peel_mod._peel_tips_device_run
 
@@ -233,41 +255,18 @@ def test_device_engine_frontier_overflow_falls_back():
 
     peel_mod._peel_tips_device_run = spy
     try:
-        dm = peel_tips(
-            g, side=0, engine="device", max_frontier=1,
-            subtract="materialize",
-        )
-        ds = peel_tips_stored(
-            g, side=0, engine="device", max_frontier=1,
-            subtract="materialize",
-        )
-        # WPEEL-V fused has no frontier buffer: the cap cannot overflow
-        dsf = peel_tips_stored(g, side=0, engine="device", max_frontier=1)
+        ds = peel_tips_stored(g, side=0, engine="device")
         # sanity: without the cap, the device engine handles this graph
         full = peel_tips(g, side=0, engine="device")
-    finally:
-        peel_mod._peel_tips_device_run = orig
-    # the capped materializing runs overflowed -> host fallback
-    assert device_returns[0] is None and device_returns[1] is None
-    assert device_returns[2] is not None  # stored fused stays on device
-    assert device_returns[3] is not None
-    for r in (dm, ds, dsf, full):
-        assert np.array_equal(r.numbers, want)
-
-    # fused PEEL-V level-1 latch: K(15,15) peels in one >128-slot round
-    a = np.stack([np.repeat(np.arange(15), 15),
-                  np.tile(np.arange(15), 15)], axis=1)
-    b = np.stack([np.repeat(np.arange(20), 20) + 15,
-                  np.tile(np.arange(20), 20) + 15], axis=1)
-    g2 = BipartiteGraph(35, 35, np.concatenate([a, b]))
-    want2 = oracle_tip(g2, 0)
-    device_returns.clear()
-    peel_mod._peel_tips_device_run = spy
-    try:
         d2 = peel_tips(g2, side=0, engine="device", max_frontier=1)
     finally:
         peel_mod._peel_tips_device_run = orig
-    assert device_returns[0] is None  # level-1 overflow -> host fallback
+    assert device_returns[0] is not None  # stored stays on device
+    assert device_returns[1] is not None
+    for r in (ds, full):
+        assert np.array_equal(r.numbers, want)
+    assert device_returns[2] is None  # level-1 overflow -> host fallback
+    assert d2.report.final_rung == "host"
     assert np.array_equal(d2.numbers, want2)
 
 
@@ -310,45 +309,39 @@ def test_tip_monotone_under_kappa():
     assert (np.diff([0] + sorted(r.numbers.tolist())) >= 0).all()
 
 
-# -- fused subtract / bucketed decrease-key / adaptive schedule (PR 4) --
+# -- the tile stream and its batched decrease-key ----------------------
 
 
-@pytest.mark.parametrize("subtract", ["fused", "materialize"])
-@pytest.mark.parametrize("decrease_key", ["bucket", "scatter"])
-def test_subtract_decrease_key_matrix_bitwise(subtract, decrease_key):
-    """Every (subtract, decrease_key) combination — on both engines and
-    both tip algorithms — produces bitwise-identical numbers, rounds,
-    and round sizes (integer scatter sums commute, tiles never split a
-    group)."""
-    g = rand_graph(12, 9, 40, 3)
-    base = peel_tips(g, side=0, subtract="materialize",
-                     decrease_key="scatter")
+@pytest.mark.parametrize("shape", [(12, 9, 40, 3), (14, 11, 60, 5)])
+@pytest.mark.parametrize("side", [0, 1])
+def test_tip_engines_bitwise(side, shape):
+    """Both engines of both tip algorithms produce bitwise-identical
+    numbers, rounds, and round sizes, equal to the recompute oracle
+    (integer scatter sums commute, tiles never split a group)."""
+    g = rand_graph(*shape)
+    want = oracle_tip(g, side)
+    base = peel_tips(g, side=side)
     for engine in ("host", "device"):
-        r = peel_tips(g, side=0, engine=engine, subtract=subtract,
-                      decrease_key=decrease_key)
-        rs = peel_tips_stored(g, side=0, engine=engine, subtract=subtract,
-                              decrease_key=decrease_key)
-        for got in (r, rs):
-            assert np.array_equal(got.numbers, base.numbers)
+        for fn in (peel_tips, peel_tips_stored):
+            got = fn(g, side=side, engine=engine)
+            assert got.report.final_rung == engine, (fn, engine)
+            assert np.array_equal(got.numbers, want), (fn, engine)
             assert got.rounds == base.rounds
             assert np.array_equal(got.round_sizes, base.round_sizes)
-    assert np.array_equal(base.numbers, oracle_tip(g, 0))
 
 
 def test_fused_subtract_forced_multi_tile():
-    """A tiny tile_budget forces the fused subtract through many tiles
-    per round (tile_cap collapses to the single-vertex alignment
-    floor); results stay bitwise-equal on both engines."""
+    """A tiny tile_budget forces the subtract through many tiles per
+    round (tile_cap collapses to the single-vertex alignment floor);
+    results stay bitwise-equal on both engines."""
     g = rand_graph(14, 11, 60, 5)
-    want = peel_tips(g, side=0, subtract="materialize")
+    want = oracle_tip(g, 0)
     for engine in ("host", "device"):
-        got = peel_tips(g, side=0, engine=engine, subtract="fused",
-                        tile_budget=1)
-        assert np.array_equal(got.numbers, want.numbers), engine
-        gs = peel_tips_stored(g, side=0, engine=engine, subtract="fused",
-                              tile_budget=1)
-        assert np.array_equal(gs.numbers, want.numbers), engine
-    wd = peel_wings(g, engine="device", subtract="fused", tile_budget=1)
+        got = peel_tips(g, side=0, engine=engine, tile_budget=1)
+        assert np.array_equal(got.numbers, want), engine
+        gs = peel_tips_stored(g, side=0, engine=engine, tile_budget=1)
+        assert np.array_equal(gs.numbers, want), engine
+    wd = peel_wings(g, engine="device", tile_budget=1)
     assert np.array_equal(wd.numbers, peel_wings(g).numbers)
 
 
@@ -359,32 +352,8 @@ def test_fused_subtract_hash_overflow_in_tile():
     want = oracle_tip(g, 0)
     for engine in ("host", "device"):
         got = peel_tips(g, side=0, aggregation="hash", engine=engine,
-                        subtract="fused", hash_bits=2)
+                        hash_bits=2)
         assert np.array_equal(got.numbers, want), engine
-
-
-def test_adaptive_capacity_schedule_parity_and_segments(monkeypatch):
-    """capacity_schedule="adaptive" shrinks the device engine's planned
-    buffers as the graph empties: results stay bitwise-identical to the
-    fixed schedule, and the decomposition genuinely re-enters with
-    smaller caps (more than one device_get, still O(log cap) many)."""
-    g = rand_graph(30, 20, 300, 0)
-    want = peel_tips(g, side=0)
-    calls = []
-    orig = jax.device_get
-    monkeypatch.setattr(
-        jax, "device_get", lambda x: (calls.append(1), orig(x))[1]
-    )
-    for subtract in ("fused", "materialize"):
-        calls.clear()
-        got = peel_tips(
-            g, side=0, engine="device", subtract=subtract,
-            capacity_schedule="adaptive", counts=_tip_counts(g, 0),
-        )
-        assert np.array_equal(got.numbers, want.numbers), subtract
-        assert got.rounds == want.rounds
-        n_segments = len(calls)
-        assert 1 < n_segments <= 20, (subtract, n_segments)
 
 
 def _tip_counts(g, side):
@@ -395,11 +364,11 @@ def _tip_counts(g, side):
 
 
 def test_fused_peel_subtract_temp_memory_is_o_tile():
-    """The acceptance-criterion regression: the fused peeling
-    subtract's compiled temp footprint must NOT scale with the frontier
-    wedge total, while the materializing (PR 2) path's does. Two graphs
-    with ~9x stored-wedge totals; the fused tile budget held fixed
-    across both (the shared alignment floor)."""
+    """The peeling subtract's compiled temp footprint must NOT scale
+    with the frontier wedge total: two graphs with ~9x stored-wedge
+    totals, the tile budget held fixed across both (the shared
+    alignment floor)."""
+    import jax.numpy as jnp
     import repro.core.peel as pm
 
     graphs = {
@@ -415,48 +384,20 @@ def test_fused_peel_subtract_temp_memory_is_o_tile():
         tile_cap = max(tile_cap, pm._pow2_pad(2 * int(rows.max(initial=0))))
     stats = {}
     for name, (g, woff, w_u2) in plans.items():
-        import jax.numpy as jnp
-
         n_side = g.n_u
-        w_total = int(woff[-1])
-        off_d = jnp.asarray(woff, jnp.int32)
-        nbr_d = jnp.asarray(w_u2, jnp.int32)
-        work1 = jnp.zeros(n_side, jnp.int32)
-        work2 = jnp.asarray(np.diff(woff).astype(np.int32))
-        st = pm._init_state(
-            jnp.zeros(n_side, jnp.int32), n_side, decrease_key="bucket",
-            peel_mode="exact", lvl1=0, lvl2=0,
-        )
-        common = dict(
-            aggregation="hash", cap1=128, n_side=n_side, stored=True,
-            hash_bits=None, decrease_key="bucket", use_kernel=False,
-            adaptive=False,
-        )
-        fused = pm._peel_tips_device.lower(
-            off_d, nbr_d, jnp.int32(0), work1, work2, st,
-            cap2=128, tile_cap=tile_cap, subtract="fused", **common,
+        st = pm._init_state(jnp.zeros(n_side, jnp.int32), n_side,
+                            peel_mode="exact")
+        mem = pm._peel_tips_device.lower(
+            jnp.asarray(woff, jnp.int32), jnp.asarray(w_u2, jnp.int32),
+            jnp.int32(0), st, aggregation="hash", cap1=128,
+            tile_cap=tile_cap, n_side=n_side, stored=True,
         ).compile().memory_analysis()
-        mat = pm._peel_tips_device.lower(
-            off_d, nbr_d, jnp.int32(0), work1, work2, st,
-            cap2=pm._pow2_pad(w_total), tile_cap=tile_cap,
-            subtract="materialize", **common,
-        ).compile().memory_analysis()
-        stats[name] = dict(
-            wedges=w_total,
-            fused_temp=int(fused.temp_size_in_bytes),
-            mat_temp=int(mat.temp_size_in_bytes),
-        )
+        stats[name] = dict(wedges=int(woff[-1]),
+                           temp=int(mem.temp_size_in_bytes))
     ratio_w = stats["big"]["wedges"] / max(stats["small"]["wedges"], 1)
     assert ratio_w >= 8, stats  # the experiment is meaningful
-    ratio_fused = stats["big"]["fused_temp"] / max(
-        stats["small"]["fused_temp"], 1
-    )
-    ratio_mat = stats["big"]["mat_temp"] / max(stats["small"]["mat_temp"], 1)
-    # fused: O(tile) — flat in the frontier wedge total;
-    # materializing: O(frontier) — tracks the wedge ratio
-    assert ratio_fused < 2.0, stats
-    assert ratio_mat > ratio_w / 2, stats
-    assert stats["big"]["fused_temp"] < stats["big"]["mat_temp"], stats
+    ratio = stats["big"]["temp"] / max(stats["small"]["temp"], 1)
+    assert ratio < 2.0, stats  # O(tile): flat in the frontier wedge total
 
 
 # -- device wing engine (PEEL-E) ----------------------------------------
@@ -488,57 +429,29 @@ def test_wings_device_hash_overflow_in_graph():
 
 
 def test_wings_device_matrix_bitwise():
-    """subtract × decrease_key on the device wing engine all match the
-    host engine bitwise."""
+    """The device wing engine matches the host engine bitwise."""
     g = rand_graph(9, 8, 28, 2)
     h = peel_wings(g)
-    for subtract in ("fused", "materialize"):
-        for dk in ("bucket", "scatter"):
-            d = peel_wings(g, engine="device", subtract=subtract,
-                           decrease_key=dk)
-            assert np.array_equal(h.numbers, d.numbers), (subtract, dk)
-            assert h.rounds == d.rounds
-
-
-def test_wings_device_no_per_round_sync(monkeypatch):
-    """The device wing round loop never host-syncs: with counts
-    precomputed, the whole decomposition performs exactly one
-    jax.device_get (the final PeelResult fetch)."""
-    from repro.core import count_butterflies
-
-    g = rand_graph(12, 9, 40, 3)
-    counts = count_butterflies(g, mode="edge").per_edge
-    calls = []
-    orig = jax.device_get
-    monkeypatch.setattr(
-        jax, "device_get", lambda x: (calls.append(1), orig(x))[1]
-    )
-    d = peel_wings(g, counts=counts, engine="device")
-    assert len(calls) == 1
-    assert d.rounds >= 2  # the loop really ran multiple rounds
-    assert np.array_equal(d.numbers, wing_numbers(g)[0])
-
-
-def test_wings_adaptive_schedule_parity():
-    """Adaptive capacity schedule on the wing engine stays bitwise."""
-    g = rand_graph(20, 15, 120, 4)
-    h = peel_wings(g)
-    d = peel_wings(g, engine="device", capacity_schedule="adaptive")
+    d = peel_wings(g, engine="device")
+    assert d.report.final_rung == "device"
     assert np.array_equal(h.numbers, d.numbers)
     assert h.rounds == d.rounds
-    assert np.array_equal(d.numbers, wing_numbers(g)[0])
+    assert np.array_equal(h.round_sizes, d.round_sizes)
 
 
 def test_peel_knob_validation():
     g = rand_graph(6, 5, 12, 0)
-    with pytest.raises(ValueError, match="subtract"):
-        peel_tips(g, subtract="banana")
-    with pytest.raises(ValueError, match="decrease_key"):
-        peel_tips_stored(g, decrease_key="fibheap")
-    with pytest.raises(ValueError, match="capacity_schedule"):
-        peel_wings(g, capacity_schedule="sometimes")
     with pytest.raises(ValueError, match="aggregation"):
         peel_tips(g, aggregation="histogram")
+    with pytest.raises(ValueError, match="aggregation"):
+        peel_wings(g, aggregation="batch")
+    # one device engine: no option selects another, and only PEEL-V
+    # has a frontier buffer to bound
+    for fn in (peel_tips, peel_tips_stored, peel_wings):
+        params = inspect.signature(fn).parameters
+        for gone in ("subtract", "decrease_key", "capacity_schedule"):
+            assert gone not in params, (fn.__name__, gone)
+        assert ("max_frontier" in params) == (fn is peel_tips)
 
 
 # -- Fibonacci heap (paper §5) ------------------------------------------
@@ -586,17 +499,23 @@ def test_bucket_structure():
 # -- bucket-range multi-bucket peeling (peel_mode="range", PR 5) --------
 
 
-@pytest.mark.parametrize("subtract", ["fused", "materialize"])
-@pytest.mark.parametrize("decrease_key", ["bucket", "scatter"])
-def test_range_mode_matrix_bitwise(subtract, decrease_key):
+RANGE_GRAPHS = {
+    "rand12": lambda: rand_graph(12, 9, 40, 3),
+    "rand14": lambda: rand_graph(14, 11, 60, 5),
+    "rand20": lambda: rand_graph(20, 15, 120, 4),
+    "bicliques": bicliques,
+}
+
+
+@pytest.mark.parametrize("graph", sorted(RANGE_GRAPHS))
+def test_range_mode_matrix_bitwise(graph):
     """peel_mode="range" produces bitwise-identical numbers to exact
-    peeling across the full engine x subtract x decrease_key matrix on
-    all three decompositions; rho (bucket rounds) never exceeds exact
-    mode's, sub_rounds equals exact mode's rho (the re-settle replays
-    the same trajectory), and bucket selection agrees between the
-    device engine (consumed occupancy histogram) and the host engine
-    (bit length of the min)."""
-    g = rand_graph(12, 9, 40, 3)
+    peeling on both engines of all three decompositions; rho (bucket
+    rounds) never exceeds exact mode's, sub_rounds equals exact mode's
+    rho (the re-settle replays the same trajectory), and bucket
+    selection agrees between the device engine (consumed occupancy
+    histogram) and the host engine (bit length of the min)."""
+    g = RANGE_GRAPHS[graph]()
     runs = (
         ("tips", lambda **kw: peel_tips(g, side=0, **kw)),
         ("stored", lambda **kw: peel_tips_stored(g, side=0, **kw)),
@@ -606,8 +525,7 @@ def test_range_mode_matrix_bitwise(subtract, decrease_key):
         exact = fn()
         host_range = None
         for engine in ("host", "device"):
-            r = fn(engine=engine, subtract=subtract,
-                   decrease_key=decrease_key, peel_mode="range")
+            r = fn(engine=engine, peel_mode="range")
             assert np.array_equal(r.numbers, exact.numbers), (name, engine)
             assert r.rounds <= exact.rounds, (name, engine)
             assert r.sub_rounds == exact.rounds, (name, engine)
@@ -663,11 +581,9 @@ def test_range_mode_single_sync_and_validation(monkeypatch):
 
 
 def test_wings_fused_recovery_temp_memory_drops_buffers():
-    """The PEEL-E tentpole regression: with the two-level fused
-    recovery the compiled wing program's temp footprint must NOT scale
-    with the O(sum deg^2)-class level-1/triple totals, while the
-    materializing path's still does. Same edge count, ~10x denser
-    triple space."""
+    """With the two-level fused recovery the compiled wing program's
+    temp footprint must NOT scale with the O(sum deg^2)-class triple
+    totals: same edge count, ~10x denser triple space."""
     import jax.numpy as jnp
     import repro.core.peel as pm
     from repro.core.wedges import degree_sorted_csr
@@ -681,40 +597,19 @@ def test_wings_fused_recovery_temp_memory_drops_buffers():
         off, nbr, uid = pm._csr(g)
         m = g.m
         eu, ev, l1, l2 = pm._wing_work_totals(g, off, nbr)
-        lvl1, lvl2 = int(l1.sum()), int(l2.sum())
         nbr_ds, uid_ds, degs_ds, cumdeg = degree_sorted_csr(off, nbr, uid)
         args = tuple(
             jnp.asarray(a, jnp.int32)
             for a in (off, nbr, uid, eu, ev, nbr_ds, uid_ds, degs_ds,
                       cumdeg, l1, l2)
         )
-        st = pm._init_state(jnp.zeros(m, jnp.int32), m,
-                            decrease_key="bucket", peel_mode="exact",
-                            lvl1=0, lvl2=0)
-        common = dict(
-            aggregation="sort", m=m, tile_cap=1024, hash_bits=None,
-            decrease_key="bucket", use_kernel=False, adaptive=False,
-        )
-        fused = pm._peel_wings_device.lower(
-            *args, st, cap1=128, cap2=128, subtract="fused", **common,
+        st = pm._init_state(jnp.zeros(m, jnp.int32), m, peel_mode="exact")
+        mem = pm._peel_wings_device.lower(
+            *args, st, aggregation="sort", m=m, tile_cap=1024,
         ).compile().memory_analysis()
-        mat = pm._peel_wings_device.lower(
-            *args, st, cap1=pm._pow2_pad(lvl1), cap2=pm._pow2_pad(lvl2),
-            subtract="materialize", **common,
-        ).compile().memory_analysis()
-        stats[name] = dict(
-            lvl2=lvl2,
-            fused_temp=int(fused.temp_size_in_bytes),
-            mat_temp=int(mat.temp_size_in_bytes),
-        )
+        stats[name] = dict(lvl2=int(l2.sum()),
+                           temp=int(mem.temp_size_in_bytes))
     ratio_work = stats["dense"]["lvl2"] / max(stats["sparse"]["lvl2"], 1)
     assert ratio_work >= 8, stats  # the experiment is meaningful
-    ratio_fused = stats["dense"]["fused_temp"] / max(
-        stats["sparse"]["fused_temp"], 1
-    )
-    ratio_mat = stats["dense"]["mat_temp"] / max(
-        stats["sparse"]["mat_temp"], 1
-    )
-    assert ratio_fused < 2.0, stats  # O(tile): flat in the triple space
-    assert ratio_mat > ratio_work / 2, stats  # O(frontier): tracks it
-    assert stats["dense"]["fused_temp"] < stats["dense"]["mat_temp"], stats
+    ratio = stats["dense"]["temp"] / max(stats["sparse"]["temp"], 1)
+    assert ratio < 2.0, stats  # O(tile): flat in the triple space

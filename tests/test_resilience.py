@@ -57,6 +57,13 @@ def rand_graph(nu, nv, m, seed):
 
 
 GRAPH = rand_graph(30, 20, 260, 7)
+# K(15,15) + K(20,20): the first tip round peels 225 level-1 slots, more
+# than the 128-slot floor of a tiny frontier budget
+BICLIQUES = BipartiteGraph(35, 35, np.concatenate([
+    np.stack([np.repeat(np.arange(15), 15), np.tile(np.arange(15), 15)], 1),
+    np.stack([np.repeat(np.arange(20), 20), np.tile(np.arange(20), 20)], 1)
+    + 15,
+]))
 
 
 # ---------------------------------------------------------------------------
@@ -119,15 +126,38 @@ def test_chaos_hash_overflow_parity(name):
 
 
 @pytest.mark.parametrize("name", sorted(WORKLOADS))
-def test_chaos_capacity_overflow_descends_with_parity(name):
-    """A forced tiny capacity budget trips the overflow latch / tile
-    bound: the ladder must descend to the next rung and stay bitwise."""
-    run, dev_site, _all = WORKLOADS[name]
-    kw = {} if name == "count" else {"subtract": "materialize"}
-    clean, _ = run(GRAPH, **kw)
-    with faults.inject("capacity_overflow", site=dev_site, budget=1):
-        got, report = run(GRAPH, **kw)
+def test_chaos_capacity_overflow_descends_with_parity(name, monkeypatch):
+    """A forced tiny capacity budget trips a bound the workload has: the
+    ladder that owns the bound must descend to the next rung and stay
+    bitwise. Counting trips its kernel tile bound and ``peel_tips`` its
+    level-1 frontier latch; the stored and wing device engines have no
+    buffer that can overflow, so the fault trips the kernel tile bound
+    of the ``fused_pallas`` count each of them runs first, and that
+    count's report records the descent."""
+    import repro.core.peel as pm
+
+    run, site, _all = WORKLOADS[name]
+    g, kw = (BICLIQUES if name == "peel_tips" else GRAPH), {}
+    count_reports = []
+    if name in ("peel_tips_stored", "peel_wings"):
+        site, kw = "count.fused_pallas", {"count_kwargs": {
+            "engine": "fused_pallas"}}
+        real = pm.count_butterflies
+
+        def spy(*a, **k):
+            r = real(*a, **k)
+            count_reports.append(r.report)
+            return r
+
+        monkeypatch.setattr(pm, "count_butterflies", spy)
+    clean, _ = run(g, **kw)
+    with faults.inject("capacity_overflow", site=site, budget=1) as f:
+        got, report = run(g, **kw)
+    assert f.fired > 0
     assert np.array_equal(got, clean)
+    if count_reports:
+        assert not report.degraded, report.summary()  # the peel itself
+        report = count_reports[-1]
     assert report.degraded, report.summary()
     assert report.attempts[0].outcome == "capacity-overflow"
 
@@ -367,13 +397,14 @@ def test_resilience_false_disables_report_and_validation():
     assert r.report is None
     p = peel_wings(GRAPH, resilience=False)
     assert p.report is None
-    # descent is engine semantics, not a policy extra: a capped device
-    # run still falls back to host with the policy disabled
+    # descent is engine semantics, not a policy extra: a level-1
+    # overflow still falls back to host with the policy disabled
     capped = peel_tips(
-        GRAPH, side=0, engine="device", max_frontier=1,
-        subtract="materialize", resilience=False,
+        BICLIQUES, side=0, engine="device", max_frontier=1,
+        resilience=False,
     )
-    want = peel_tips(GRAPH, side=0)
+    assert capped.report is None
+    want = peel_tips(BICLIQUES, side=0)
     assert np.array_equal(capped.numbers, want.numbers)
 
 
