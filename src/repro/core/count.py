@@ -379,7 +379,7 @@ def _plan_from_knobs(
     engine: str,
     max_chunk,
     hash_bits: Optional[int],
-    wv_slots: Optional[np.ndarray] = None,
+    wv_slots: np.ndarray,
 ) -> Optional["_pipeline.WedgePlan"]:
     """Resolve this module's knob surface into a pipeline counting plan
     — the one place the budget/clamp rules live. Returns None for knob
@@ -388,8 +388,6 @@ def _plan_from_knobs(
     if aggregation in ("batch", "batch_wa"):
         return None  # batch fuses its own accumulation: no tile plan
     budget = _resolve_chunk_budget(max_chunk)
-    if wv_slots is None:
-        wv_slots = host_wedge_counts(rg, direction)
     if engine in ("fused", "fused_pallas"):
         if budget is None:
             budget = auto_chunk_budget()
@@ -421,6 +419,7 @@ def count_from_ranked(
     engine: str = "xla",
     max_chunk=None,
     hash_bits: Optional[int] = None,
+    wv_slots: Optional[np.ndarray] = None,
 ):
     """Count butterflies on a preprocessed graph. Returns rank-space
     device arrays (a scalar for global mode; a (total, per-vertex,
@@ -436,7 +435,10 @@ def count_from_ranked(
     tile/stream budget: an int, ``"auto"`` (derived from device memory
     stats), or None (materialize for xla/pallas; auto for the fused
     engines). ``hash_bits`` overrides the hash-table size (testing
-    hook for the in-graph overflow fallback).
+    hook for the in-graph overflow fallback). ``wv_slots`` takes the
+    per-slot wedge counts of ``rg`` in this call's direction
+    (:func:`~repro.core.wedges.host_wedge_counts`) when the caller has
+    them; None computes them here.
     """
     if engine not in ENGINES:
         raise ValueError(f"engine must be {'|'.join(ENGINES)}, got {engine}")
@@ -457,8 +459,9 @@ def count_from_ranked(
         # to sort — bitwise-identical, both strategies are exact
         aggregation = "sort"
     dg = device_graph(rg)
-    with _pipeline.span("plan"):
-        wv_slots = host_wedge_counts(rg, direction)
+    if wv_slots is None:
+        with _pipeline.span("plan"):
+            wv_slots = host_wedge_counts(rg, direction)
     if aggregation in ("batch", "batch_wa"):
         if engine != "xla":
             raise ValueError(
@@ -635,8 +638,9 @@ def count_butterflies(
     :class:`~repro.core.resilience.ExecutionReport` in ``.report``,
     whose ``.plan`` records the requested engine's tile plan summary
     (tile count, per-tile strategy mix, capacity segments).
-    Preprocessing is shared across rungs, so a fallback never repays
-    the O(m log m) ranking. The worst-case accumulator preflight
+    Preprocessing and the per-slot wedge counts are shared across
+    rungs, so a fallback never repays the O(m log m) ranking. The
+    worst-case accumulator preflight
     (:meth:`BipartiteGraph.accumulator_preflight`) raises
     :class:`~repro.core.resilience.AccumulatorOverflowRisk` up front
     when even two-limb int32 accumulation could silently wrap.
@@ -649,6 +653,10 @@ def count_butterflies(
     ladder = COUNT_LADDERS.get(engine, (engine,))
     if aggregation in ("batch", "batch_wa"):
         ladder = (engine,)  # batch fuses its own accumulation: one rung
+    direction = "high" if cache_opt else "low"
+    # once a job: every rung and the report plan share rg and direction
+    with _pipeline.span("plan"):
+        wv_slots = host_wedge_counts(rg, direction)
 
     def _make_rung(eng):
         def run(shrinks):
@@ -667,6 +675,7 @@ def count_butterflies(
                 batch_rows=batch_rows,
                 engine=eng,
                 max_chunk=mc,
+                wv_slots=wv_slots,
             )
             return _pipeline.fetch(out)
 
@@ -680,11 +689,12 @@ def count_butterflies(
             rg,
             aggregation=aggregation,
             mode=mode,
-            direction="high" if cache_opt else "low",
+            direction=direction,
             dtype=(count_dtype or jnp.int32),
             engine=engine,
             max_chunk=max_chunk,
             hash_bits=None,
+            wv_slots=wv_slots,
         )
     except _res.ResilienceError:
         plan = None

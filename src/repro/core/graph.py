@@ -223,6 +223,10 @@ class RankedGraph:
       edge_src: (e_pad,) int32 source of each directed edge slot.
       undirected_id: (e_pad,) int32 undirected edge id in [0, m) for real
          slots, ``m`` sentinel for padding.
+      twin: (e_pad,) int32 slot of the reversed edge: real slot ``k``
+         holds ``(s -> d)`` and ``twin[k]`` holds ``(d -> s)``, so
+         ``twin[k]`` is where ``s`` sits in ``N(d)``. Host-only (no
+         device program takes it); ``e_pad`` sentinel for padding.
       side_of: (n_pad,) int8: 0 if the vertex came from U, 1 from V,
          -1 padding.
       orig_id: (n_pad,) int32 original vertex id *within its side*.
@@ -237,6 +241,7 @@ class RankedGraph:
     neighbors: np.ndarray
     edge_src: np.ndarray
     undirected_id: np.ndarray
+    twin: np.ndarray
     side_of: np.ndarray
     orig_id: np.ndarray
     rank_of_u: np.ndarray
@@ -297,17 +302,27 @@ def preprocess(
     gv = g.edges[:, 1] + g.n_u
     ru, rv = rank[gu], rank[gv]
 
-    # Directed edges (both directions), relabeled to ranks.
+    # Directed edges, relabeled to ranks: input slots j and j + m are
+    # the two directions of undirected edge j.
     src = np.concatenate([ru, rv])
     dst = np.concatenate([rv, ru])
-    uid = np.concatenate([np.arange(m), np.arange(m)]).astype(np.int64)
 
     n_pad = _round_up(max(n, 1), pad_vertices)
     e_pad = _round_up(max(2 * m, 1), pad_edges)
 
-    # CSR sorted by (src, dst) ascending.
-    perm = np.lexsort((dst, src))
-    src, dst, uid = src[perm], dst[perm], uid[perm]
+    # CSR sorted by (src, dst) ascending, in lexsort((dst, src))'s order:
+    # the sort is stable, so repeated keys (duplicate edges kept under
+    # on_duplicate="assume_unique") keep their input order.
+    perm = np.argsort(src * n + dst, kind="stable")
+    src, dst = src[perm], dst[perm]
+    first = perm < m
+    uid = np.where(first, perm, perm - m)
+    # twin: the CSR slot of each slot's reversed edge, via the inverse
+    # permutation
+    inv = np.empty(2 * m, dtype=np.int64)
+    inv[perm] = np.arange(2 * m)
+    twin = np.full(e_pad, e_pad, dtype=np.int32)
+    twin[: 2 * m] = inv[np.where(first, perm + m, perm - m)]
     deg = np.bincount(src, minlength=n_pad)
     offsets = np.zeros(n_pad + 1, dtype=np.int64)
     np.cumsum(deg, out=offsets[1:])
@@ -335,6 +350,7 @@ def preprocess(
         neighbors=neighbors,
         edge_src=edge_src,
         undirected_id=undirected_id,
+        twin=twin,
         side_of=side_of,
         orig_id=orig_id,
         rank_of_u=rank[: g.n_u].astype(np.int32),
